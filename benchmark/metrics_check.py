@@ -6,7 +6,7 @@ channels: regex over four INFO lines vs in-process counters and the
 per-digest stage-trace table.  Agreement within tolerance is the check
 that neither channel silently lost data — round 5 published a number a
 flooded queue had quietly corrupted, and nothing cross-checked it
-(VERDICT.md §1).  Disagreement beyond tolerance hard-fails the run (an
+(the r05 review, §1).  Disagreement beyond tolerance hard-fails the run (an
 error entry, which every harness treats as fatal).
 
 The same per-digest trace join also yields the per-stage pipeline latency

@@ -19,7 +19,7 @@ an unchanged oracle to diff against.  Consumers:
   (multi-leader burst, gc-window wrap, checkpoint restore, fuzz) through
   both implementations and asserts byte-identical commit sequences;
 - bench_consensus.py's commit-burst phase uses it as the "before" arm of
-  the indexed-walk speedup table (artifacts/consensus_bench_r09.json).
+  the indexed-walk speedup table.
 
 Do not optimize this file.  Its only job is to stay what it was.
 """

@@ -136,28 +136,36 @@ class CpuBackend:
 
 _backend = CpuBackend()
 
-# The batched JAX verifier runs on whatever platform JAX has — a real
-# TPU or the jax-cpu mesh (the A/B fallback arm) — so "jax" is the
-# honest spelling; "tpu" is kept as the historical alias.
+# Two names for the batched JAX verifier (ops/ed25519.py).  "tpu" means
+# the chip: selecting it on a host whose JAX has no TPU is a boot error,
+# never a silent jax-cpu run logged as "tpu".  "jax" runs on whatever
+# platform JAX has — the CPU tests and the A/B arms say that.
 _BATCHED_NAMES = ("tpu", "jax")
 
 
 def set_backend(name: str, strict: Optional[bool] = None) -> None:
-    """Select the verification backend: "cpu", or "jax"/"tpu" (the
-    batched device verifier).
+    """Select the verification backend: "cpu", "tpu" (the batched
+    verifier, on a TPU or not at all) or "jax" (the batched verifier on
+    any JAX platform).
 
     A jax/tpu request whose import fails is a BOOT error, not a
     first-burst error: with ``strict`` (default: the
     NARWHAL_CRYPTO_BACKEND_STRICT flag, on) the import failure raises
     here, at selection time; with strict off it logs the import error
     and falls back to the cpu backend — an explicit, logged downgrade.
+    A "tpu" request that finds another platform always raises.
+
+    Selecting jax/tpu initialises JAX's backend: the process holds the
+    chip from here on, so a parent that spawns chip users never calls
+    this with a batched name.
     """
     global _backend
     if name == "cpu":
         _backend = CpuBackend()
     elif name in _BATCHED_NAMES:
         try:
-            from ..ops.ed25519 import TpuBackend  # deferred: JAX import is heavy
+            # deferred: JAX import is heavy
+            from ..ops.ed25519 import TpuBackend, device_identity
         except ImportError as e:
             if strict is None:
                 strict = env_flag("NARWHAL_CRYPTO_BACKEND_STRICT")
@@ -174,9 +182,32 @@ def set_backend(name: str, strict: Optional[bool] = None) -> None:
             )
             _backend = CpuBackend()
             return
-        _backend = TpuBackend()
+        if name == "tpu":
+            found = device_identity()
+            if found["platform"] != "tpu":
+                raise RuntimeError(
+                    "crypto backend 'tpu' requested but JAX's default "
+                    f"device is on platform {found['platform']!r} "
+                    f"({found['kind']} x{found['count']}) — no TPU here; "
+                    "say 'jax' to run the batched verifier on whatever "
+                    "platform JAX has"
+                )
+        _backend = TpuBackend(name)
     else:
         raise ValueError(f"unknown crypto backend {name!r}")
+
+
+def describe_backend() -> str:
+    """The live backend for the boot log: its name, and for the batched
+    verifier the platform, device kind and device count it runs on."""
+    if _backend.name not in _BATCHED_NAMES:
+        return _backend.name
+    from ..ops import device_identity
+
+    found = device_identity()
+    return "{} on platform {} ({} x{})".format(
+        _backend.name, found["platform"], found["kind"], found["count"]
+    )
 
 
 def set_backend_from_env(cli_choice: Optional[str] = None) -> str:

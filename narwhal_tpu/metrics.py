@@ -3,7 +3,7 @@
 The reference ships no metrics at all — every number in its paper tables is
 scraped from four log lines (benchmark/benchmark/logs.py), and this repo
 inherited that: round 5's mis-measurement (32.6k tx/s at 3 s latency because
-queues silently flooded, VERDICT.md §1) had to be reconstructed from log
+queues silently flooded, the r05 review, §1) had to be reconstructed from log
 archaeology.  This module is the first-class replacement: a dependency-free,
 near-zero-overhead per-process registry that every layer (worker, network,
 primary, consensus, store) writes into, plus

@@ -28,31 +28,6 @@ log = logging.getLogger("narwhal.node")
 CHANNEL_CAPACITY = 1_000
 
 
-def derive_max_claims(committee: Committee) -> int:
-    """Largest claim batch a Core burst can produce: the max messages
-    per verify dispatch (DRAIN_LIMIT, or NARWHAL_VERIFY_BATCH_MAX when
-    the accumulation window coalesces several drains into one dispatch),
-    each a certificate carrying its header claim plus one quorum of vote
-    claims.  Worst case is the LARGEST vote set that can form a quorum
-    (smallest stakes first), not the smallest.  Shared between node boot
-    and the bench harness's device pre-warm step so both compile exactly
-    the same pad shapes."""
-    from ..primary.core import Core
-    from ..utils.env import env_float, env_int
-
-    max_items = Core.DRAIN_LIMIT
-    if env_float("NARWHAL_VERIFY_BATCH_WINDOW_MS") > 0:
-        max_items = max(max_items, env_int("NARWHAL_VERIFY_BATCH_MAX"))
-    stakes = sorted(a.stake for a in committee.authorities.values())
-    acc, worst_votes = 0, 0
-    for s in stakes:
-        acc += s
-        worst_votes += 1
-        if acc >= committee.quorum_threshold():
-            break
-    return max_items * (worst_votes + 1)
-
-
 class PrimaryNode:
     def __init__(self) -> None:
         self.primary: Optional[Primary] = None
@@ -111,19 +86,17 @@ async def spawn_primary_node(
     loop = asyncio.get_running_loop()
     node.store = Store(store_path) if store is None else store
 
-    # If the TPU verify backend is selected, compile/cache-load the kernel
-    # for the live burst shapes BEFORE joining the committee: the first
-    # device call can cost tens of seconds of XLA compile, which must not
-    # land on the first certificate's critical path.
+    # If the batched verify backend is selected, build (compile or load
+    # from the persistent cache) the kernel for every rung of its pad
+    # ladder BEFORE joining the committee: one shape costs minutes to
+    # compile for the chip, which must not land on the first
+    # certificate's critical path.  The harness waits for the ready line.
     from ..crypto import backend as crypto_backend
 
     backend = crypto_backend.get_backend()
     if hasattr(backend, "warmup"):
-        # Warm every pad shape up to the worst-case burst so no live burst
-        # hits XLA compile (sizing rationale in derive_max_claims).
         log.info("Warming up %s verify backend...", backend.name)
-        backend.warmup(max_claims=derive_max_claims(committee))
-        log.info("Verify backend %s ready", backend.name)
+        log.info("Verify backend %s ready: %s", backend.name, backend.warmup())
 
     # One capacity for all three channels: the env knob (declared
     # NARWHAL_CHANNEL_CAPACITY, sweepable by the knee matrix) unless the

@@ -29,14 +29,15 @@ adversarial inputs (tests/test_ed25519.py).
 from __future__ import annotations
 
 import hashlib
-from typing import List, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-from ..utils.env import env_flag, env_str
+from ..utils.env import env_flag
+from . import compile_stats, device_identity
 from . import field25519 as F
 
 P = F.P
@@ -374,12 +375,10 @@ def prepare_batch(
 # -- multi-device mesh (stretch, NARWHAL_VERIFY_MESH) -------------------------
 #
 # The kernel is elementwise over the batch axis, so sharding is trivial:
-# a 1-D Mesh over every visible device, shard_map splitting the batch
-# (pad shapes are powers of two ≥ 16 and device counts are powers of two
-# on every real topology, so the split is always even — a non-dividing
-# count falls back to the single-device kernel rather than re-padding).
-# Throughput then scales with chips, not cores (SNIPPETS.md [1-3], the
-# t5x/Tenstorrent mesh exemplars).
+# a 1-D Mesh over every visible device, shard_map splitting the batch.
+# Every rung of the pad ladder is multiplied by the device count, so each
+# shard always holds exactly one single-device rung of rows.  Off by
+# default and never run on chips; ROADMAP D1 decides its fate.
 
 _mesh_kernel_cache: dict = {}
 
@@ -390,18 +389,19 @@ def _mesh_verify_kernel(n_dev: int):
     fn = _mesh_kernel_cache.get(n_dev)
     if fn is None:
         from jax.sharding import Mesh, PartitionSpec as P_
-        try:  # moved out of experimental in newer JAX
-            from jax.experimental.shard_map import shard_map
-        except ImportError:  # pragma: no cover - version skew
-            from jax.shard_map import shard_map
+
         mesh = Mesh(np.array(jax.devices()), ("batch",))
         spec = P_("batch")
         fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 _verify_kernel.__wrapped__,  # the un-jitted kernel
                 mesh=mesh,
                 in_specs=(spec,) * 9,
                 out_specs=spec,
+                # No collectives and every value is per-row, so there is
+                # no replication to check; the checker would only make
+                # the field code cast its scan carries to "varying".
+                check_vma=False,
             )
         )
         _mesh_kernel_cache[n_dev] = fn
@@ -413,52 +413,120 @@ def mesh_devices() -> int:
     the NARWHAL_VERIFY_MESH flag is on and JAX sees several devices."""
     if not env_flag("NARWHAL_VERIFY_MESH"):
         return 1
-    try:
-        return len(jax.devices())
-    except RuntimeError:  # no backend initialized / unreachable
-        return 1
+    return len(jax.devices())
 
 
-def verify_batch_arrays(messages, keys, sigs) -> np.ndarray:
-    """Bool mask for a batch of (message, key, signature) triples.  The
-    batch is padded to a power of two ≥ 16 so XLA compiles a small set of
-    shapes (cached across calls).  With NARWHAL_VERIFY_MESH and several
-    visible devices, the padded batch is sharded across the device mesh
-    (pad floor raised to 16 × devices so every shard keeps a lane-filling
-    row count)."""
+# -- the pad ladder -----------------------------------------------------------
+#
+# XLA compiles one program per padded batch shape, and one shape costs
+# about 150 s to build for a v5e (30 s of Python tracing and lowering in
+# every process, 110-145 s of compile when the persistent cache misses;
+# PERF.md).  So the shapes are a short fixed ladder, not every power of
+# two up to the committee's worst burst: a batch pads to the smallest rung
+# that holds it, and a batch above the top rung is split into top-rung
+# chunks.  The pad policy and the warm-up read the SAME ladder, so no live
+# burst — however large a late joiner's catch-up makes it — can reach a
+# shape that was not built before the node joined.
+#
+# The chip's rungs come from one reading of the kernel on a v5e (ms per
+# call, prepared arrays in, mask fetched; PERF.md, PR 22): 16 -> 37.5, 64
+# -> 17.1, 128 -> 17.4, 256 -> 20.4, 512 -> 26.5, 2048 -> 53.9.  The
+# 64-step ladder costs ~17 ms whatever it holds, and 16 rows is the
+# SLOWEST small shape, so the bottom rung is 128: the widest shape still
+# at the floor.  Top rung 512: one DRAIN_LIMIT burst of quorum-carrying
+# certificates at N=4 (128 x (3 + 1) claims) in a single dispatch.
+# ROADMAP S5 re-chooses both from the batch-size histograms of the
+# benchmark's cells.
+#
+# Off the chip (jax-cpu: the tests and the A/B arms, never a speed) one
+# shape takes ~90 s to build and a call costs ~0.2 s at 16 rows against
+# ~2.8 s at 512, so the ladder there is one small rung and bursts above
+# it exercise the split.  The platform is what JAX reports, not a knob.
+
+CHIP_RUNGS = (128, 512)
+CPU_RUNGS = (16,)
+
+
+def dispatch_plan() -> Tuple[Callable, Tuple[int, ...]]:
+    """(kernel, pad ladder) for the platform JAX runs on: the ladder
+    ascending, each rung scaled by the mesh's device count.  A backend
+    resolves this once, at construction."""
+    on_chip = jax.devices()[0].platform == "tpu"
+    n_dev = mesh_devices()
+    kernel = _mesh_verify_kernel(n_dev) if n_dev > 1 else _verify_kernel
+    base = CHIP_RUNGS if on_chip else CPU_RUNGS
+    return kernel, tuple(r * n_dev for r in base)
+
+
+def chunk_plan(n: int, ladder: Sequence[int]) -> List[Tuple[int, int, int]]:
+    """(lo, hi, pad) per dispatch for a batch of ``n``: top-rung chunks,
+    each padded to the smallest rung that holds it."""
+    top = ladder[-1]
+    plan = []
+    for lo in range(0, n, top):
+        hi = min(lo + top, n)
+        plan.append((lo, hi, next(r for r in ladder if r >= hi - lo)))
+    return plan
+
+
+def verify_batch_arrays(
+    messages,
+    keys,
+    sigs,
+    dispatched: Optional[dict] = None,
+    plan: Optional[Tuple[Callable, Sequence[int]]] = None,
+) -> np.ndarray:
+    """Bool mask for a batch of (message, key, signature) triples, padded
+    and chunked by the pad ladder above (``plan``: a ``dispatch_plan()``
+    the caller resolved once; default: resolved here).  Chunks are
+    dispatched back to back and fetched afterwards, so host prep of chunk
+    k+1 overlaps the device's work on chunk k.  With NARWHAL_VERIFY_MESH
+    and several visible devices each padded chunk is sharded across the
+    device mesh.  ``dispatched`` (padded shape -> count) is incremented
+    per dispatch."""
     n = len(messages)
     if n == 0:
         return np.zeros(0, dtype=bool)
-    n_dev = mesh_devices()
-    floor = 16 * n_dev if n_dev > 1 else 16
-    pad = floor
-    while pad < n:
-        pad <<= 1
-    args = prepare_batch(messages, keys, sigs, pad)
-    if n_dev > 1 and pad % n_dev == 0:
-        kernel = _mesh_verify_kernel(n_dev)
-    else:
-        kernel = _verify_kernel
-    mask = np.asarray(kernel(*(jnp.asarray(a) for a in args)))
-    return mask[:n]
+    kernel, ladder = plan or dispatch_plan()
+    pending = []
+    for lo, hi, pad in chunk_plan(n, ladder):
+        args = prepare_batch(messages[lo:hi], keys[lo:hi], sigs[lo:hi], pad)
+        pending.append((kernel(*(jnp.asarray(a) for a in args)), hi - lo))
+        if dispatched is not None:
+            dispatched[pad] = dispatched.get(pad, 0) + 1
+    return np.concatenate([np.asarray(out)[:m] for out, m in pending])
 
 
 class TpuBackend:
     """crypto.backend-compatible verification backend (see
-    narwhal_tpu/crypto/backend.py)."""
+    narwhal_tpu/crypto/backend.py).  ``name`` is the name it was selected
+    under: "tpu" (the chip, checked at selection) or "jax" (whatever
+    platform JAX has — the CPU tests and A/B arms)."""
 
-    name = "tpu"
+    # A dispatch is one device round trip on the dispatch thread, ~20 ms
+    # on a v5e whatever it holds: the Core drives such a backend through
+    # its pipelined verify stage (primary/core.py) instead of awaiting
+    # each drained burst inline.
+    dispatches_off_loop = True
 
-    def __init__(self) -> None:
+    def __init__(self, name: str = "jax") -> None:
         # One dedicated dispatch thread: keeps device calls ordered, and
         # run_in_executor from the event loop never blocks it for the
         # device round trip (host prep + dispatch + result sync all happen
         # on this thread; numpy/hashlib/JAX release the GIL for the bulk).
         from concurrent.futures import ThreadPoolExecutor
 
+        self.name = name
+        # Kernel and pad ladder, resolved once: live dispatch and warm-up
+        # read the same one.
+        self._plan = dispatch_plan()
+        self.rungs: Tuple[int, ...] = self._plan[1]
         self._executor = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="tpu-verify"
         )
+        self._programs_at_ready: Optional[int] = None
+        # Live dispatches per padded shape: which rungs the node used.
+        self._dispatched: dict = {}
 
     def verify(self, message: bytes, key, sig) -> bool:
         return bool(self.verify_batch_mask([message], [key], [sig])[0])
@@ -466,7 +534,11 @@ class TpuBackend:
     def verify_batch_mask(
         self, messages: Sequence[bytes], keys, sigs
     ) -> List[bool]:
-        return list(verify_batch_arrays(messages, keys, sigs))
+        return list(
+            verify_batch_arrays(
+                messages, keys, sigs, self._dispatched, self._plan
+            )
+        )
 
     async def averify_batch_mask(
         self, messages: Sequence[bytes], keys, sigs
@@ -494,35 +566,56 @@ class TpuBackend:
             self._executor, timed
         )
 
-    def warmup(
-        self, shapes: Sequence[int] = None, max_claims: int = None
-    ) -> None:
-        """Compile (or load from the persistent cache) the kernel for the
-        padded batch shapes a live node will hit, so the first real burst
-        doesn't pay tens of seconds of XLA compile on the critical path.
-
-        ``max_claims`` is the largest claim batch the node can produce —
-        Core.DRAIN_LIMIT items × one quorum (2f+1) of vote claims each; the
-        caller (node boot) derives it from the committee so every power-of-
-        two pad shape up to it is compiled before the node joins.  Explicit
-        ``shapes`` or NARWHAL_TPU_WARMUP_SHAPES="16,64,256" override."""
-        if shapes is None:
-            env = env_str("NARWHAL_TPU_WARMUP_SHAPES")
-            if env:
-                shapes = [int(s) for s in env.split(",") if s]
-            else:
-                top = 64 if max_claims is None else max(16, max_claims)
-                shapes, pad = [], 16
-                while True:
-                    shapes.append(pad)
-                    if pad >= top:
-                        break
-                    pad <<= 1
+    def warmup(self) -> str:
+        """Build (compile, or load from the persistent cache) the kernel
+        for every rung of the pad ladder, so no live burst pays minutes
+        of XLA compile on the critical path.  Returns a one-line account
+        for the caller's ready log; the counts it states are also in the
+        `crypto.verify.device` snapshot detail."""
+        from .. import metrics
         from ..crypto import KeyPair
         from ..crypto.digest import Digest
 
         kp = KeyPair.generate()
         msg = bytes(Digest(b"\x05" * 32))
         sig = kp.sign(Digest(msg))
-        for n in shapes:
-            verify_batch_arrays([msg] * n, [kp.name] * n, [sig] * n)
+        ladder = self.rungs
+        for n in ladder:
+            if not all(
+                verify_batch_arrays(
+                    [msg] * n, [kp.name] * n, [sig] * n, plan=self._plan
+                )
+            ):
+                raise RuntimeError(
+                    f"verify kernel rejected a valid signature at rung {n}"
+                )
+        stats = compile_stats()
+        self._programs_at_ready = stats["programs_built"]
+        metrics.detail_fn("crypto.verify.device", self.device_report)
+        return (
+            "rungs {}, {} programs built in {:.1f} s (trace {:.1f} s), "
+            "persistent cache {} hits / {} misses".format(
+                ",".join(map(str, ladder)),
+                stats["programs_built"],
+                stats["trace_seconds"] + stats["lower_seconds"]
+                + stats["build_seconds"],
+                stats["trace_seconds"],
+                stats["cache_hits"],
+                stats["cache_misses"],
+            )
+        )
+
+    def device_report(self) -> dict:
+        """Which device verified, with which shapes, and whether anything
+        was built after warm-up (``programs_built`` above
+        ``programs_at_ready`` means a live burst paid for a compile)."""
+        return {
+            **device_identity(),
+            "rungs": list(self.rungs),
+            "dispatched": {
+                # dict(): one atomic copy — the dispatch thread may insert
+                str(k): v for k, v in sorted(dict(self._dispatched).items())
+            },
+            "programs_at_ready": self._programs_at_ready,
+            **compile_stats(),
+        }

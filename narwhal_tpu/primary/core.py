@@ -32,20 +32,22 @@ header→vote→cert round-trip is pipelined here:
   address lists and the per-author primary address map are computed once
   at init instead of per header/vote/certificate.
 
-Verify-batch window (ISSUE r19, ROADMAP item 1).  With
-``NARWHAL_VERIFY_BATCH_WINDOW_MS > 0`` the peer-message arm of the main
-loop stops verifying inline: drained bursts are forwarded to a
-pipelined ``_verify_loop`` task that coalesces cross-message-type
-signature claims (headers, votes, certificates) from several drains —
-up to ``NARWHAL_VERIFY_BATCH_MAX`` messages or the window, whichever
-closes first — into ONE backend dispatch, then replays in arrival
-order.  The device round trip runs off the event loop (the backend's
-dispatch thread), and run() keeps servicing the proposer/waiter sources
-and draining the network throughout, so consecutive rounds pipeline
-behind the verify instead of stalling — and the arrivals during a
-dispatch deepen the next batch.  The window is the knob that turns the
-r12 mean burst of 3.6 claims into device-sized batches for the
-``jax``/``tpu`` backend (crypto/backend.py).
+Pipelined verify stage (ISSUE r19, ROADMAP item 1; PR 22).  With
+``NARWHAL_VERIFY_BATCH_WINDOW_MS > 0``, or whenever the live backend
+dispatches off the event loop (the batched ``jax``/``tpu`` verifier,
+crypto/backend.py), the peer-message arm of the main loop stops
+verifying inline: drained bursts are forwarded to a ``_verify_loop``
+task that coalesces cross-message-type signature claims (headers, votes,
+certificates) from several drains — everything queued, plus what arrives
+within the window, up to ``NARWHAL_VERIFY_BATCH_MAX`` messages — into
+ONE backend dispatch, then replays in arrival order.  The device round
+trip runs off the event loop (the backend's dispatch thread), and run()
+keeps servicing the proposer/waiter sources and draining the network
+throughout, so the node's own header is never held behind its peers'
+verifies, consecutive rounds pipeline behind the verify instead of
+stalling, and the arrivals during a dispatch deepen the next batch.  The
+window is the knob that turns the r12 mean burst of 3.6 claims into
+device-sized batches; at 0 the stage waits for nothing.
 """
 
 from __future__ import annotations
@@ -153,19 +155,34 @@ class Core:
         if fast_path is None:
             fast_path = env_flag("NARWHAL_VOTE_FAST_PATH")
         self.fast_path = fast_path
-        # Verify-batch accumulation window (ROADMAP item 1): >0 routes
-        # drained peer messages through a pipelined verify task that
-        # coalesces claims from MULTIPLE bursts (headers, votes, certs
-        # alike) arriving within the window into one backend dispatch —
-        # the knob that turns the r12 mean batch of 3.6 into device-
-        # sized batches.  0 (default) keeps the pre-r19 inline behavior:
-        # one averify per drained burst, replay before the next drain.
+        # Pipelined verify stage: peer messages go to a verify task that
+        # coalesces the claims of everything queued (headers, votes,
+        # certs alike) into one backend dispatch while run() keeps
+        # servicing the proposer and the waiters.  It is on when
+        # (a) the accumulation window is > 0 (ROADMAP item 1: the task
+        # additionally WAITS that long for more claims — the knob that
+        # turns the r12 mean batch of 3.6 into device-sized batches), or
+        # (b) the live backend dispatches off the event loop (the
+        # batched device verifier: one round trip costs ~20 ms on a v5e
+        # whatever it holds).  Awaited inline, (b) put four serialised
+        # dispatches a round in front of the node's OWN header, which
+        # then reached its peers 44 ms late and was certified 90 ms
+        # after it was minted; pipelined, 0.4 ms and 42 ms (PERF.md, PR
+        # 22).  With window 0 the stage waits for nothing: a dispatch
+        # covers what queued while the previous one was in flight.
+        # Otherwise (cpu backends, window 0): one averify per drained
+        # burst, inline, replay before the next drain.
         if verify_window_ms is None:
             verify_window_ms = env_float("NARWHAL_VERIFY_BATCH_WINDOW_MS")
         self.verify_window_s = max(0.0, float(verify_window_ms) / 1000.0)
         if verify_batch_max is None:
             verify_batch_max = env_int("NARWHAL_VERIFY_BATCH_MAX")
         self.verify_batch_max = max(1, int(verify_batch_max))
+        from ..crypto import backend as crypto_backend
+
+        pipelined = self.verify_window_s > 0 or getattr(
+            crypto_backend.get_backend(), "dispatches_off_loop", False
+        )
         # Bounded hand-off into the verify pipeline: run() blocks on put
         # when the pipeline is behind, so rx_primaries (and through it
         # the network receiver) keeps its backpressure.
@@ -174,7 +191,7 @@ class Core:
                 max(256, 2 * self.verify_batch_max),
                 channel="primary.verify_window",
             )
-            if self.verify_window_s > 0
+            if pipelined
             else None
         )
 
@@ -674,7 +691,7 @@ class Core:
                 if kind == "header":
                     self.sanitize_header(item[1], sig_ok)
                     self._note_header_seen(item[1])
-                    # lint: allow-interleave(window mode runs _handle from two roots — run() for waiter/proposer sources, _verify_loop for peer messages — over the per-round maps and aggregators: every decision+record pair (vote-once via last_voted/voted_ids, equivocation counting, aggregator append) happens in one sync block BEFORE any yield, the aggregators dedupe by authority, and sanitize_* re-checks round state at replay time, so a cross-root suspension can reorder processing but never tear an invariant)
+                    # lint: allow-interleave(the pipelined stage runs _handle from two roots — run() for waiter/proposer sources, _verify_loop for peer messages — over the per-round maps and aggregators: every decision+record pair (vote-once via last_voted/voted_ids, equivocation counting, aggregator append) happens in one sync block BEFORE any yield, the aggregators dedupe by authority, and sanitize_* re-checks round state at replay time, so a cross-root suspension can reorder processing but never tear an invariant)
                     await self.process_header(item[1])
                 elif kind == "vote":
                     if sig_ok is not False:  # exclude known-forged votes
@@ -837,7 +854,7 @@ class Core:
                         h.update(bytes(vn))
                     h.update(bytes(item[1].agg))
                 dedup_key = h.digest()
-            # lint: allow-interleave(_handle_primaries_burst is single-flight by mode exclusivity: with the window off _verify_loop is never spawned and only run() calls it; with the window on run() forwards peer messages instead of handling them, so only _verify_loop calls it — the cache read→await→insert window is therefore never concurrent with another burst's insert)
+            # lint: allow-interleave(_handle_primaries_burst is single-flight by mode exclusivity: with the pipeline off _verify_loop is never spawned and only run() calls it; with it on run() forwards peer messages instead of handling them, so only _verify_loop calls it — the cache read→await→insert window is therefore never concurrent with another burst's insert)
             seen = dedup_key is not None and dedup_key in self._verified_recent
             if seen:
                 self._m_verify_cache_hits.inc()
@@ -879,14 +896,15 @@ class Core:
             await self._handle("primaries", item, sig_ok)
 
     async def _verify_loop(self) -> None:
-        """Pipelined verify stage (active when the batch window is on):
+        """Pipelined verify stage (see __init__ for when it is on):
         collect peer messages forwarded by run() until the window
-        closes or the batch cap is hit, then one backend dispatch +
-        in-order replay.  While a dispatch's device round trip is in
-        flight (off the event loop), run() keeps draining the next
-        bursts into the queue — so round N+1's network/proposer work
-        pipelines behind round N's verify instead of stalling, and the
-        backlog naturally deepens the next batch."""
+        closes (at window 0: whatever is queued) or the batch cap is
+        hit, then one backend dispatch + in-order replay.  While a
+        dispatch's device round trip is in flight (off the event loop),
+        run() keeps draining the next bursts into the queue — so round
+        N+1's network/proposer work pipelines behind round N's verify
+        instead of stalling, and the backlog naturally deepens the next
+        batch."""
         queue = self._verify_q
         loop = asyncio.get_running_loop()
         while True:
@@ -985,7 +1003,7 @@ class Core:
                     )
                     if name == "primaries":
                         if self._verify_q is not None:
-                            # Window mode: hand the burst to the verify
+                            # Pipelined: hand the burst to the verify
                             # pipeline and return to draining — the
                             # proposer/waiter sources stay serviced
                             # while the batch accumulates/verifies.
@@ -993,7 +1011,7 @@ class Core:
                                 burst, verify_task
                             )
                         else:
-                            # lint: allow-interleave(mode exclusivity: this arm only runs with the window OFF, where _verify_loop was never spawned — the "other root" the static merge sees cannot exist at runtime; the shared epilogue below is additionally subset-safe/monotonic as pragma'd in _verify_loop)
+                            # lint: allow-interleave(mode exclusivity: this arm only runs with the pipeline OFF, where _verify_loop was never spawned — the "other root" the static merge sees cannot exist at runtime; the shared epilogue below is additionally subset-safe/monotonic as pragma'd in _verify_loop)
                             await self._handle_primaries_burst(burst)
                     else:
                         for item in burst:

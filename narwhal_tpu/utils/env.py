@@ -316,9 +316,10 @@ _VARS = [
         "NARWHAL_CRYPTO_BACKEND", "str", "cpu",
         "Signature-verification backend selected at node boot (equivalent "
         "of `node run --crypto-backend`): `cpu` (serial OpenSSL / "
-        "pure-Python fallback) or `jax`/`tpu` (the vmapped batched "
-        "verifier in ops/ed25519.py — `jax` runs on whatever platform "
-        "JAX has, incl. jax-cpu for the A/B fallback arm).",
+        "pure-Python fallback), `tpu` (the vmapped batched verifier in "
+        "ops/ed25519.py on a TPU — a boot error when JAX finds none) or "
+        "`jax` (the same verifier on whatever platform JAX has, incl. "
+        "jax-cpu for tests and the A/B fallback arm).",
     ),
     EnvVar(
         "NARWHAL_CRYPTO_BACKEND_STRICT", "flag", True,
@@ -333,8 +334,11 @@ _VARS = [
         "claims from multiple drained bursts (headers, votes, certs) "
         "arriving within this many ms into ONE backend dispatch, run in "
         "a pipelined verify task so proposer/waiter work keeps flowing "
-        "during the device round trip. 0 (default) = verify each "
-        "drained burst inline (the pre-r19 behavior).",
+        "during the device round trip. 0 (default): the cpu backend "
+        "verifies each drained burst inline; the batched `jax`/`tpu` "
+        "backend still runs the pipelined task (its dispatch is off the "
+        "event loop), which then waits for nothing and dispatches what "
+        "queued while the previous dispatch was in flight.",
     ),
     EnvVar(
         "NARWHAL_VERIFY_BATCH_MAX", "int", 256,
@@ -354,17 +358,6 @@ _VARS = [
         "NARWHAL_FIELD_DTYPE", "str", "int32",
         "Lane dtype of `ops/field25519` (`int32` or `float32`); read at "
         "import.",
-    ),
-    EnvVar(
-        "NARWHAL_TPU_WARMUP_SHAPES", "str", None,
-        "Extra comma-separated claim counts to pre-compile into the "
-        "verify kernel's warmup sweep.",
-    ),
-    EnvVar(
-        "NARWHAL_JAX_CACHE", "str", None,
-        "Persistent XLA compilation-cache directory shared across node "
-        "processes.",
-        shown_default="~/.cache/narwhal_tpu_jax",
     ),
     # -- deterministic simulation (narwhal_tpu/sim) ---------------------------
     EnvVar(

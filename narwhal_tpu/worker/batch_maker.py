@@ -227,7 +227,7 @@ class BatchMaker:
             # (TCP flow control), drain asynchronously.  Counted + a
             # rate-limited warning: a flooded committee must be VISIBLE
             # (round 5 published 3 s latencies because this path was
-            # silent, VERDICT.md §1), but one line per parked batch would
+            # silent, the r05 review, §1), but one line per parked batch would
             # melt the log under exactly the load that triggers it.
             self._m_overflow.inc()
             self._overflow_events += 1

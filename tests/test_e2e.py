@@ -428,29 +428,27 @@ def test_commit_with_crash_fault(run):
     run(go())
 
 
-def test_prewarm_cli(tmp_path, monkeypatch):
-    """`node prewarm` compiles the verify kernel (and optionally the
-    consensus kernel) for a committee's shapes and exits 0 — the step the
-    bench harness runs before spawning TPU-flagged nodes so their boot
-    warmup is a cache load (never a multi-minute compile that outlives
-    the boot deadline).  Runs on the CPU jax backend here; the shape
-    override keeps the compile small."""
+def test_prewarm_cli(tmp_path):
+    """`node prewarm --crypto-backend jax` builds the verify kernel for
+    every rung of the pad ladder (and optionally the consensus kernel)
+    and exits 0 — the step the bench harness runs before spawning
+    SEVERAL device-backed nodes so their boot warmup is a cache load.
+    Runs on the CPU jax backend here, on the tests' one-rung ladder."""
     from narwhal_tpu.node.main import main as node_main
     from tests.common import committee
 
     c = committee(base_port=15200)
     path = str(tmp_path / "committee.json")
     c.export(path)
-    monkeypatch.setenv("NARWHAL_TPU_WARMUP_SHAPES", "16")
     from narwhal_tpu.crypto import backend as crypto_backend
 
     try:
         rc = node_main(
-            ["prewarm", "--committee", path, "--experimental-consensus-kernel",
-             "--gc-depth", "4"]
+            ["prewarm", "--committee", path, "--crypto-backend", "jax",
+             "--experimental-consensus-kernel", "--gc-depth", "4"]
         )
     finally:
-        # prewarm selects the tpu backend process-globally; put the
+        # prewarm selects the jax backend process-globally; put the
         # default back so later tests in this session see cpu.
         crypto_backend.set_backend("cpu")
     assert rc == 0

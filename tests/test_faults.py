@@ -316,6 +316,10 @@ def test_shaped_writer_delays_frames_in_order():
                         got_two.set()
             except (asyncio.IncompleteReadError, ConnectionError):
                 pass
+            finally:
+                # Python >= 3.12: Server.wait_closed() waits for every
+                # accepted connection, so the handler closes its side.
+                writer.close()
 
         server = await asyncio.start_server(on_conn, "127.0.0.1", 0)
         port = server.sockets[0].getsockname()[1]
@@ -347,6 +351,10 @@ def test_shaped_writer_loss_surfaces_as_connection_reset():
                     await read_frame(reader)
             except (asyncio.IncompleteReadError, ConnectionError):
                 pass
+            finally:
+                # Python >= 3.12: Server.wait_closed() waits for every
+                # accepted connection, so the handler closes its side.
+                writer.close()
 
         server = await asyncio.start_server(on_conn, "127.0.0.1", 0)
         port = server.sockets[0].getsockname()[1]
@@ -372,6 +380,10 @@ def test_partition_cuts_established_connection():
                     await read_frame(reader)
             except (asyncio.IncompleteReadError, ConnectionError):
                 pass
+            finally:
+                # Python >= 3.12: Server.wait_closed() waits for every
+                # accepted connection, so the handler closes its side.
+                writer.close()
 
         server = await asyncio.start_server(on_conn, "127.0.0.1", 0)
         port = server.sockets[0].getsockname()[1]

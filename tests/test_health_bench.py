@@ -13,6 +13,8 @@ import os
 import shutil
 import sys
 
+import pytest
+
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.local_bench import run_bench  # noqa: E402
@@ -351,3 +353,73 @@ def test_clean_local_bench_has_timeline_and_no_firing_rules(tmp_path):
     cpu_pids = {ev["pid"] for ev in cpu_slices}
     for i in range(4):
         assert names[f"primary-{i}"] in cpu_pids, f"primary-{i} has no cpu"
+
+
+# -- a failed device phase fails the run (ISSUE 22) ---------------------------
+#
+# Here, not in a file of their own: run_bench kills stale nodes of this
+# checkout, so every test that calls it must share one xdist worker.
+
+
+@pytest.mark.parametrize(
+    "backend, tpu_primaries, error, message",
+    [
+        # Several primaries on `tpu`: they would all open the one default
+        # chip, so the harness refuses before it starts anything.
+        ("tpu", None, ValueError, "assigns no chips to processes"),
+        # Several device-backed primaries: the prewarm child runs first,
+        # and fails when JAX cannot bring its platform up.
+        ("jax", None, RuntimeError, "device prewarm exited"),
+        # One: it is its own prewarm, started first — and dies at boot
+        # (`tpu` on a CPU-only JAX).
+        ("tpu", 1, RuntimeError, "device-backed primaries never booted"),
+    ],
+)
+def test_failed_device_bring_up_fails_run_bench(
+    tmp_path, monkeypatch, backend, tpu_primaries, error, message
+):
+    """A device path that cannot come up must stop the run, not carry on
+    and measure a committee without it; nothing is left running and no
+    CPU node was ever started."""
+    if backend == "jax":
+        monkeypatch.setenv("JAX_PLATFORMS", "no-such-platform")
+    workdir = tmp_path / "bench"
+    with pytest.raises(error, match=message):
+        run_bench(
+            nodes=4, workers=1, rate=1_000, duration=1, base_port=7700,
+            workdir=str(workdir), quiet=True,
+            crypto_backend=backend, tpu_primaries=tpu_primaries,
+        )
+    logs = sorted(p.name for p in workdir.glob("*.log"))
+    assert logs == ([] if tpu_primaries is None else ["primary-0.log"])
+    if tpu_primaries:
+        assert "no TPU here" in (workdir / "primary-0.log").read_text()
+
+
+_SILENT = (
+    "health check FAILED at quiesce: primary-0 /healthz returned 503 with "
+    "firing rule(s): peer_vote_silence[127.0.0.1:7015]"
+)
+
+
+@pytest.mark.parametrize(
+    "error, down, expected",
+    [
+        # The validator that was never started is silent: expected.
+        (_SILENT, {"127.0.0.1:7015"}, True),
+        # The same rule about a validator that is up is a failure.
+        (_SILENT, {"127.0.0.1:7020"}, False),
+        # Any other rule firing alongside it is a failure.
+        (_SILENT + ", stale_replay[127.0.0.1:7015]", {"127.0.0.1:7015"}, False),
+        # An error that names no rule is a failure.
+        ("ERROR in primary-2.log: boom", {"127.0.0.1:7015"}, False),
+    ],
+)
+def test_chip_smoke_excuses_only_the_silence_of_a_down_validator(
+    error, down, expected
+):
+    """chip_smoke's crash-fault run tolerates exactly one thing: its live
+    validators reporting that the one never started does not vote."""
+    import chip_smoke
+
+    assert chip_smoke.silence_of_the_down(error, down) is expected

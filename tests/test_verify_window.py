@@ -272,6 +272,84 @@ def test_crashed_verify_loop_wakes_idle_run():
     run(go())
 
 
+class _SlowOffLoopBackend:
+    """Stands in for the batched device verifier: a dispatch is off the
+    event loop and stays in flight until the test releases it."""
+
+    name = "slow-off-loop"
+    dispatches_off_loop = True
+
+    def __init__(self):
+        self.in_flight = asyncio.Event()
+        self.release = asyncio.Event()
+        self.batches = []
+
+    async def averify_batch_mask_timed(self, messages, keys, sigs):
+        self.batches.append(len(messages))
+        self.in_flight.set()
+        await self.release.wait()
+        return cb.CpuBackend().verify_batch_mask(messages, keys, sigs), 0.0
+
+
+def test_off_loop_backend_never_holds_the_own_header_behind_a_verify(
+    monkeypatch,
+):
+    """A backend whose dispatch is off the event loop is driven through
+    the pipelined stage at the DEFAULT window (0): while peers'
+    certificates are in flight on the device the node's own header is
+    still processed and broadcast (PR 22: awaited inline, it reached the
+    peers 44 ms late on the chip), and what arrived during the dispatch
+    shares the next one."""
+
+    async def go():
+        backend = _SlowOffLoopBackend()
+        monkeypatch.setattr(cb, "_backend", backend)
+        c = committee()
+        me = keys()[0]
+        core, store, qs = make_core(c, me)
+        assert core._verify_q is not None and core.verify_window_s == 0.0
+        sent = []
+        monkeypatch.setattr(
+            core, "_broadcast_own_header",
+            lambda header: sent.append(header.id) or [],
+        )
+        quorum = c.quorum_threshold()
+        certs = [
+            make_certificate(make_header(kp, c=c)) for kp in keys()[1:4]
+        ]
+        certs0 = cnt("primary.certificates_processed")
+        task = asyncio.get_running_loop().create_task(core.run())
+        try:
+            qs["primaries"].put_nowait(("certificate", certs[0]))
+            await asyncio.wait_for(backend.in_flight.wait(), 5)
+            # In flight, not answered: the own header must not wait.
+            own = make_header(me, c=c)
+            qs["proposer_in"].put_nowait(own)
+            for kp_cert in certs[1:]:
+                qs["primaries"].put_nowait(("certificate", kp_cert))
+            for _ in range(200):
+                if sent:
+                    break
+                await asyncio.sleep(0.01)
+            assert sent == [own.id]
+            assert cnt("primary.certificates_processed") == certs0
+            backend.release.set()
+            for _ in range(500):
+                if cnt("primary.certificates_processed") - certs0 >= 3:
+                    break
+                await asyncio.sleep(0.01)
+            assert cnt("primary.certificates_processed") - certs0 == 3
+            # One dispatch for the first arrival, ONE for both that
+            # queued behind it.
+            assert backend.batches == [quorum + 1, 2 * (quorum + 1)]
+        finally:
+            task.cancel()
+            await asyncio.gather(task, return_exceptions=True)
+            core.network.close()
+
+    run(go())
+
+
 # -- backend selection ergonomics (ISSUE 14 satellite) ------------------------
 
 
@@ -291,11 +369,11 @@ def test_set_backend_fallback_only_when_explicitly_allowed(monkeypatch):
     logged cpu fallback; the default (strict) raises."""
     monkeypatch.setitem(sys.modules, "narwhal_tpu.ops.ed25519", None)
     monkeypatch.setenv("NARWHAL_CRYPTO_BACKEND_STRICT", "0")
-    cb.set_backend("tpu")
+    cb.set_backend("jax")
     assert cb.get_backend().name == "cpu"
     monkeypatch.setenv("NARWHAL_CRYPTO_BACKEND_STRICT", "1")
     with pytest.raises(RuntimeError):
-        cb.set_backend("tpu")
+        cb.set_backend("jax")
 
 
 def test_set_backend_from_env_precedence(monkeypatch):
