@@ -1,0 +1,124 @@
+"""Every data file loads, every name it points to exists, and
+BENCHMARK.json keeps to the contract's characters and limits."""
+
+import glob
+import json
+import os
+import re
+
+import pytest
+
+import readers
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+CHIPBENCH = os.path.dirname(HERE)
+REPO = os.path.dirname(CHIPBENCH)
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+def load(path):
+    with open(path) as f:
+        return json.load(f)
+
+
+BENCH = load(os.path.join(REPO, "BENCHMARK.json"))
+
+
+def files(sub):
+    return sorted(glob.glob(os.path.join(CHIPBENCH, sub, "*.json")))
+
+
+@pytest.mark.parametrize("path", files("workloads"), ids=os.path.basename)
+def test_workload_file(path):
+    w = load(path)
+    assert set(w) == {"config", "rate", "tx_size", "arrival", "clients", "chips",
+                      "forged_per_s", "why", "who"}
+    cfg = load(os.path.join(CHIPBENCH, "configs", w["config"] + ".json"))
+    assert w["clients"] == (cfg["nodes"] - cfg["faults"]) * cfg["workers"]
+    assert w["chips"] in (1, 4) and w["arrival"] == "steady"
+
+
+@pytest.mark.parametrize("path", files("configs"), ids=os.path.basename)
+def test_config_file(path):
+    c = load(path)
+    assert c["name"] + ".json" == os.path.basename(path)
+    assert len(c["dead_key_ranks"]) == c["faults"]
+    assert c["nodes"] - c["faults"] == 2 * c["nodes"] // 3 + 1  # exactly a quorum
+    assert set(c["parameters"]) == {
+        "header_size", "max_header_delay", "min_header_delay", "header_linger",
+        "gc_depth", "sync_retry_delay", "sync_retry_nodes", "batch_size",
+        "max_batch_delay"}
+    for key in ("source", "guarantees", "assumed", "reduced", "message_delay"):
+        assert key in c
+
+
+@pytest.mark.parametrize("path", files("layer_metrics"), ids=os.path.basename)
+def test_layer_metric_file(path):
+    spec = load(path)
+    assert callable(readers.load(spec["kind"]))
+
+
+def test_benchmark_json():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert BENCH["paths"] == ["chipbench"]
+    assert 1 <= BENCH["run_seconds"] <= 51
+    names = set()
+    for group in ("configs", "workloads", "end_to_end", "per_layer"):
+        for entry in BENCH[group]:
+            assert NAME.match(entry["name"]), entry["name"]
+            assert entry["name"] not in names
+            names.add(entry["name"])
+    for c in BENCH["configs"]:
+        cfg = load(os.path.join(REPO, c["file"]))
+        assert cfg["source"] == c["source"] and cfg["reduced"] == c["reduced"]
+        assert all(NAME.match(k) for k in c["reduced"])
+        assert 1 <= len(c["why"]) <= 200 and 1 <= len(c["source"]) <= 200
+    cells = set()
+    for w in BENCH["workloads"]:
+        wl = load(os.path.join(CHIPBENCH, "workloads", w["name"] + ".json"))
+        assert wl["config"] == w["config"] and wl["chips"] == w["chips"]
+        assert w["name"] == f"{w['config']}.{w['traffic']}"
+        assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
+        cells.add(w["name"])
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25
+    for m in BENCH["end_to_end"]:
+        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+        assert 0.01 <= m["bound"] <= 0.25
+        assert m["source"] in ("host_clock", "device_trace")
+    for m in BENCH["per_layer"]:
+        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+        assert m["moves"] in e2e and set(m["workloads"]) <= cells
+        assert m["source"] in ("device_trace", "program_span", "program_counter",
+                               "host_clock")
+        assert os.path.exists(
+            os.path.join(CHIPBENCH, "layer_metrics", m["name"] + ".json"))
+    assert len(json.dumps(BENCH)) < 64 * 1024
+
+
+def test_what_a_checkout_keeps_outside_itself(monkeypatch):
+    """Ports and tmpfs stores are named from the checkout's path; what a
+    killed run of this checkout left in /dev/shm is swept at the next
+    start, another checkout's is not touched."""
+    import committee
+
+    cfg = load(files("configs")[0])
+    here = committee.port_base(cfg)
+    assert cfg["base_port"] <= here and here + 128 <= 32768
+    monkeypatch.setattr(committee, "REPO", "/some/other/checkout")
+    there_tag = committee.checkout_tag()
+    assert committee.port_base(cfg) != here
+    monkeypatch.undo()
+    if not os.access("/dev/shm", os.W_OK):
+        pytest.skip("no writable /dev/shm")
+    mine = f"/dev/shm/chipbench-{committee.checkout_tag()}-stale-test"
+    theirs = f"/dev/shm/chipbench-{there_tag}-stale-test"
+    os.makedirs(mine, exist_ok=True)
+    os.makedirs(theirs, exist_ok=True)
+    try:
+        committee.sweep_stale_stores()
+        assert not os.path.exists(mine) and os.path.exists(theirs)
+    finally:
+        os.rmdir(theirs)
