@@ -3,9 +3,9 @@
 The "always-on" in the sampling profiler's charter is only honest if the
 committee pays ~nothing for it, so this driver measures exactly that the
 way PRs 2/7 measured their instrument overhead: N interleaved pairs of
-identical local_bench runs — the ON arm with the defaults
-(NARWHAL_PROFILE_HZ≈67, flight recorder enabled), the OFF arm with both
-stubbed (NARWHAL_PROFILE_HZ=0, NARWHAL_FLIGHT=0) — alternating arms so
+identical local_bench runs — the ON arm with the sampler at 67 Hz (it
+is opt-in since PR 26) and the flight recorder's default, the OFF arm
+with both stubbed (NARWHAL_PROFILE_HZ=0, NARWHAL_FLIGHT=0) — alternating arms so
 host drift hits both equally, medians compared against the ≤5% committee
 TPS acceptance gate.
 
@@ -34,17 +34,14 @@ sys.path.insert(0, REPO)
 from benchmark.local_bench import run_bench  # noqa: E402
 
 _OFF_ENV = {"NARWHAL_PROFILE_HZ": "0", "NARWHAL_FLIGHT": "0"}
+_ON_ENV = {"NARWHAL_PROFILE_HZ": "67", "NARWHAL_FLIGHT": "1"}
 
 
 def _one_run(arm: str, idx: int, args) -> dict:
     """One bench run under the arm's env; returns the headline numbers
     (+ the aggregated profiler table on ON arms)."""
     saved = {k: os.environ.get(k) for k in _OFF_ENV}
-    if arm == "off":
-        os.environ.update(_OFF_ENV)
-    else:
-        for k in _OFF_ENV:
-            os.environ.pop(k, None)
+    os.environ.update(_OFF_ENV if arm == "off" else _ON_ENV)
     workdir = os.path.join(REPO, ".bench_ab", f"{arm}-{idx}")
     try:
         result = run_bench(
@@ -195,7 +192,7 @@ def main() -> int:
         "config": {
             "pairs": args.pairs, "nodes": args.nodes, "rate": args.rate,
             "tx_size": args.tx_size, "duration": args.duration,
-            "on_env": "defaults (NARWHAL_PROFILE_HZ=67, NARWHAL_FLIGHT=1)",
+            "on_env": _ON_ENV,
             "off_env": _OFF_ENV,
         },
         "runs": runs,

@@ -14,6 +14,8 @@ import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from .. import metrics
+from ..utils.clock import wall_now
+from ..utils.devtrace import current_burst
 from ..utils.env import env_flag, env_str
 from .aggregate import AggregateSignature, verify_halfagg
 from .digest import Digest
@@ -328,7 +330,14 @@ async def averify_batch_mask(
     """Async verify_batch_mask: the TPU backend runs the device round trip
     in an executor thread so the node's event loop (networking, proposer
     timers, waiters) keeps running during the dispatch+sync — without this,
-    every Core burst would stall the whole primary for the device latency."""
+    every Core burst would stall the whole primary for the device latency.
+
+    Inside ``devtrace.burst(key)`` the call stamps the caller's entry of
+    the verify-stage trace (metrics.VERIFY_STAGES): ``submitted`` and
+    ``resumed`` here, on the loop, and a backend with a dispatch thread
+    hands back its own stamps (``prepare``, ``enqueued``, ``fetched`` and
+    the extras) as a third element of its result, which are marked here
+    too — the table is written from the loop only."""
     if not (len(messages) == len(keys) == len(sigs)):
         raise ValueError("verify_batch: length mismatch")
     if not messages:
@@ -354,11 +363,22 @@ async def averify_batch_mask(
             await asyncio.sleep(0)
         return mask
     ops, secs, sizes, dev = _verify_instruments(site)
+    verify_trace = metrics.verify_trace()
+    trace_key = current_burst()
+    if trace_key is not None:
+        verify_trace.mark(trace_key, "submitted", claims=len(messages))
     t0 = time.perf_counter()
     try:
-        mask, compute_s = await _backend.averify_batch_mask_timed(
+        mask, compute_s, *thread = await _backend.averify_batch_mask_timed(
             messages, keys, sigs
         )
+        if trace_key is not None:
+            resumed = wall_now()
+            stamps = dict(thread[0]) if thread else {}
+            for stage in ("prepare", "enqueued", "fetched"):
+                if stage in stamps:
+                    verify_trace.mark(trace_key, stage, stamps.pop(stage))
+            verify_trace.mark(trace_key, "resumed", resumed, **stamps)
         # Backend-side compute only (host prep + dispatch + result sync)
         # vs the wall observation below, which additionally carries the
         # event-loop yields / executor-queue wait across the await.

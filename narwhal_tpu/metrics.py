@@ -133,6 +133,41 @@ ROUND_STAGES: Tuple[str, ...] = (
     "round_advance",
 )
 
+# Verify-stage sub-stages, in causal order: one entry per burst the
+# primary's verify stage takes (key = the decimal burst sequence number),
+# stamped where the work happens so that the host's share of a dispatch
+# and the stage's busy time are READ, not worked out from histograms
+# filled around the whole call:
+#
+#   collected  Core closed the batch (_verify_loop; inline: burst entry)
+#   submitted  claims extracted, batch about to go to the backend (loop)
+#   prepare    the backend's dispatch thread picked it up
+#   enqueued   last chunk's kernel call returned: host preparation,
+#              transfer in and launch are done
+#   fetched    last chunk's mask is on the host: the device is done
+#   resumed    the await returned on the event loop
+#   replayed   in-order replay, log flush and GC sweep done: the stage is
+#              free for the next burst
+#
+# The three middle stamps are taken ON the dispatch thread, come back with
+# the result and are marked by the seam (crypto/backend.py), so the table
+# is written from the loop only.  A backend without a dispatch thread
+# marks the loop stages alone, and a burst whose claims were all stale or
+# cached carries ``collected`` and ``replayed`` only.  Extras per entry:
+# ``items``, ``claims``, ``round`` (highest round among the items: the
+# span that caused the dispatch), and from the dispatch thread ``pad``,
+# ``chunks``, ``cpu_s`` (its ``time.thread_time()`` across prepare ->
+# fetched: wall far above CPU + device time says it was off the cores).
+VERIFY_STAGES: Tuple[str, ...] = (
+    "collected",
+    "submitted",
+    "prepare",
+    "enqueued",
+    "fetched",
+    "resumed",
+    "replayed",
+)
+
 
 class Counter:
     """Monotone counter.  ``inc`` is the hot-path primitive: one add."""
@@ -247,10 +282,11 @@ class Histogram:
 
 class TraceTable:
     """Bounded key → {stage: timestamp} table (plus per-key extras like
-    the sealed byte count).  Two instances exist per registry: the
-    per-digest pipeline trace (``stages=STAGES``, keys are digest hex)
-    and the per-round cadence trace (``stages=ROUND_STAGES``, keys are
-    decimal round numbers).
+    the sealed byte count).  Three instances exist per registry: the
+    per-digest pipeline trace (``stages=STAGES``, keys are digest hex),
+    the per-round cadence trace (``stages=ROUND_STAGES``, keys are
+    decimal round numbers) and the verify-stage trace
+    (``stages=VERIFY_STAGES``, keys are decimal burst numbers).
 
     ``mark`` keeps the FIRST timestamp per (key, stage) — matching the
     log parser's earliest-across-nodes convention — and evicts the oldest
@@ -651,6 +687,16 @@ class Registry:
             if enabled
             else _NULL  # type: ignore
         )
+        # Verify-stage trace (VERIFY_STAGES): one entry per burst of the
+        # primary's verify stage, ~37 a second on a device-backed
+        # primary, so 8,192 holds a run's ~5,000 with room.  ~1 MB of
+        # JSON when full: it rides the final flush and an explicit
+        # scrape, never the periodic rewrites (see snapshot()).
+        self.verify_trace: TraceTable = (
+            TraceTable(8192, stages=VERIFY_STAGES)
+            if enabled
+            else _NULL  # type: ignore
+        )
         # Attached HealthMonitor (node/main.py wires one per process);
         # snapshots then carry a `health` section and the MetricsServer
         # answers /healthz from it.
@@ -664,6 +710,10 @@ class Registry:
         if enabled:
             self.gauge_fn(
                 "metrics.trace_evictions", lambda: self.trace.evictions
+            )
+            self.gauge_fn(
+                "metrics.verify_trace_evictions",
+                lambda: self.verify_trace.evictions,
             )
 
     def counter(self, name: str) -> Counter:
@@ -751,6 +801,8 @@ class Registry:
             self.trace.evictions = 0
             self.round_trace.entries.clear()
             self.round_trace.evictions = 0
+            self.verify_trace.entries.clear()
+            self.verify_trace.evictions = 0
         self.wire.reset()
         self.flight.reset()
         # A monitor attached by a previous test would otherwise keep
@@ -759,7 +811,11 @@ class Registry:
 
     # -- export --------------------------------------------------------------
 
-    def snapshot(self, include_trace: bool = True) -> dict:
+    def snapshot(
+        self,
+        include_trace: bool = True,
+        include_verify_trace: Optional[bool] = None,
+    ) -> dict:
         """One JSON-serializable dict of everything, callback gauges
         evaluated now.  A failing callback is reported in-band (under
         ``errors``) instead of killing the snapshot loop.
@@ -768,7 +824,12 @@ class Registry:
         the serialized size (hundreds of kB on a bench run, ~12 ms of
         json.dumps on a slow core), and the periodic writer skips it on
         most rewrites to keep the 1 Hz snapshot cost off the committee's
-        shared core."""
+        shared core.  ``include_verify_trace`` (default: follows
+        ``include_trace``) gates the verify-stage table apart: the
+        periodic writer never carries it, not even on the rewrites that
+        carry the stage trace."""
+        if include_verify_trace is None:
+            include_verify_trace = include_trace
         errors: List[str] = []
 
         def call(name, fn):
@@ -811,6 +872,11 @@ class Registry:
             "round_trace": (
                 dict(self.round_trace.entries)
                 if self.enabled and include_trace
+                else {}
+            ),
+            "verify_trace": (
+                dict(self.verify_trace.entries)
+                if self.enabled and include_verify_trace
                 else {}
             ),
         }
@@ -1553,6 +1619,10 @@ def round_trace() -> TraceTable:
     return _REGISTRY.round_trace  # type: ignore[return-value]
 
 
+def verify_trace() -> TraceTable:
+    return _REGISTRY.verify_trace  # type: ignore[return-value]
+
+
 def wire() -> WireLedger:
     return _REGISTRY.wire
 
@@ -1706,6 +1776,10 @@ class SnapshotWriter:
     included only every ``trace_every``-th rewrite (staleness bounded at
     ``trace_every × interval_s`` for a SIGKILLed node) and in the final
     cancellation flush, which is what the bench cross-validation reads.
+    The verify-stage table (~1 MB when full) rides the final flush ONLY.
+    Every rewrite is timed into ``runtime.snapshot_write_seconds`` — it
+    runs ON the event loop, so the loop-stall record (analysis/
+    watchdog.py) can say whether a stall held one.
     """
 
     def __init__(
@@ -1720,17 +1794,26 @@ class SnapshotWriter:
         self.interval_s = interval_s
         self.trace_every = max(1, trace_every)
         self._ticks = 0
+        self._m_write_s = reg.histogram("runtime.snapshot_write_seconds")
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
 
-    def write_once(self, include_trace: bool = True) -> None:
+    def write_once(
+        self,
+        include_trace: bool = True,
+        include_verify_trace: Optional[bool] = None,
+    ) -> None:
+        t0 = time.perf_counter()
         # Serialize to one string first: json.dump streams thousands of
         # tiny f.write chunks (measured ~2× the dumps+single-write cost
         # with a loaded trace table).
-        body = json.dumps(self.registry.snapshot(include_trace))
+        body = json.dumps(
+            self.registry.snapshot(include_trace, include_verify_trace)
+        )
         tmp = self.path + ".tmp"
         with open(tmp, "w") as f:
             f.write(body)
         os.replace(tmp, self.path)
+        self._m_write_s.observe(time.perf_counter() - t0)
 
     async def run(self) -> None:
         try:
@@ -1739,7 +1822,8 @@ class SnapshotWriter:
                 self._ticks += 1
                 try:
                     self.write_once(
-                        include_trace=(self._ticks % self.trace_every == 0)
+                        include_trace=(self._ticks % self.trace_every == 0),
+                        include_verify_trace=False,
                     )
                 except OSError:
                     # A transient write failure (ENOSPC clearing, tmp-dir
@@ -1768,8 +1852,14 @@ class MetricsServer:
     JSON (503 iff any rule is firing; 200 with ``status: unmonitored``
     when no monitor is attached), ``GET /debug/flight`` → the flight
     recorder's live event ring (what the node was doing in its last
-    seconds — pulled by the bench scraper at quiesce).  Anything else
-    is 404.
+    seconds — pulled by the bench scraper at quiesce),
+    ``GET /debug/profile?seconds=<s>`` → one device-profiler session of
+    that length (0.05–10 s, default 0.1) on a node whose verifier holds a
+    device, written under ``profile_dir`` (node/main.py: beside the
+    ``--metrics-path`` file, never a path the caller names); the reply
+    comes when ``stop_trace`` has returned and carries the wall-clock
+    stamps that bound the traced window (utils/devtrace.py).  Anything
+    else is 404.
 
     Hand-rolled over ``asyncio.start_server`` — the container bakes no
     http framework, and a scrape endpoint needs exactly one request per
@@ -1780,13 +1870,20 @@ class MetricsServer:
     same convention as every other listener here — NARWHAL_BIND_ANY=1
     widens it to 0.0.0.0 for scrapers on other hosts (receiver.py)."""
 
-    def __init__(self, reg: Registry) -> None:
+    def __init__(
+        self, reg: Registry, profile_dir: Optional[str] = None
+    ) -> None:
         self.registry = reg
+        self.profile_dir = profile_dir
         self._server: Optional[asyncio.AbstractServer] = None
 
     @classmethod
     async def spawn(
-        cls, reg: Registry, port: int, host: Optional[str] = None
+        cls,
+        reg: Registry,
+        port: int,
+        host: Optional[str] = None,
+        profile_dir: Optional[str] = None,
     ) -> "MetricsServer":
         if host is None:
             host = (
@@ -1794,7 +1891,7 @@ class MetricsServer:
                 if env_flag("NARWHAL_BIND_ANY")
                 else "127.0.0.1"
             )
-        self = cls(reg)
+        self = cls(reg, profile_dir)
         self._server = await asyncio.start_server(self._handle, host, port)
         log.info("Metrics endpoint listening on %s:%d", host, self.port)
         return self
@@ -1849,6 +1946,10 @@ class MetricsServer:
                 ).encode()
                 ctype = "application/json"
                 status = "200 OK"
+            elif path == "/debug/profile":
+                status, payload = await self._profile(params)
+                body = json.dumps(payload).encode()
+                ctype = "application/json"
             elif path == "/healthz":
                 monitor = self.registry.health
                 if monitor is None:
@@ -1888,6 +1989,28 @@ class MetricsServer:
                 await writer.wait_closed()
             except Exception:
                 pass
+
+    async def _profile(self, params: Dict[str, str]) -> Tuple[str, dict]:
+        """The node's own profiler hook: what ``chipbench/device_node.py``
+        wraps ``main`` for, as a route.  Only the process that holds the
+        chip can trace it, so a node without a device answers 409."""
+        from .utils import devtrace
+
+        if self.profile_dir is None or not devtrace.holds_device():
+            return "409 Conflict", {
+                "error": "no device-backed verifier in this process, "
+                "or no --metrics-path to write beside"
+            }
+        try:
+            seconds = float(params.get("seconds", "0.1"))
+        except ValueError:
+            seconds = -1.0
+        if not 0.05 <= seconds <= 10.0:
+            return "400 Bad Request", {"error": "seconds must be 0.05-10"}
+        stamps = await devtrace.profile(self.profile_dir, seconds)
+        if stamps is None:
+            return "409 Conflict", {"error": "a profile is already running"}
+        return "200 OK", stamps
 
     async def shutdown(self) -> None:
         if self._server is not None:

@@ -347,13 +347,15 @@ def main(argv=None) -> int:
         metrics_server = None
         health_task = None
         flight_task = None
-        # Loop-stall watchdog (NARWHAL_LOOP_WATCHDOG_MS): measured proof
-        # that no callback holds this node's event loop — the runtime
-        # half of the narwhal-lint invariant suite.
+        # Loop-stall watchdog (NARWHAL_LOOP_WATCHDOG_MS, default 100:
+        # one header timer): every hold of this node's event loop past
+        # the threshold is recorded with its stack and its cause — the
+        # runtime half of the narwhal-lint invariant suite.
         loop_watchdog = install_loop_watchdog()
-        # Sampling profiler (NARWHAL_PROFILE_HZ, default ~67 Hz): all-
-        # thread stack samples folded into the `profile.*` series —
-        # general CPU attribution with no hand-placed probes.
+        # Sampling profiler (NARWHAL_PROFILE_HZ, default 0 = off; an
+        # operator's flame graph sets ~67): all-thread stack samples
+        # folded into the `profile.*` series — general CPU attribution
+        # with no hand-placed probes.
         from .. import profiling as _profiling
 
         profiler_thread = _profiling.install_from_env()
@@ -383,8 +385,15 @@ def main(argv=None) -> int:
             _metrics.registry().health = monitor
             health_task = spawn(monitor.run(), name="health-monitor")
         if args.metrics_port:
+            # GET /debug/profile traces the device this process holds
+            # (none on a CPU node: 409) into a directory beside the
+            # snapshot file.
             metrics_server = await _metrics.MetricsServer.spawn(
-                _metrics.registry(), args.metrics_port
+                _metrics.registry(), args.metrics_port,
+                profile_dir=(
+                    args.metrics_path + ".profile"
+                    if args.metrics_path else None
+                ),
             )
 
         # One plan file serves a whole authority: each role acts only on

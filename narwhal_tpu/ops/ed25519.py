@@ -29,6 +29,7 @@ adversarial inputs (tests/test_ed25519.py).
 from __future__ import annotations
 
 import hashlib
+import time
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -36,6 +37,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..utils.clock import wall_now
+from ..utils.devtrace import annotate, current_burst
 from ..utils.env import env_flag
 from . import compile_stats, device_identity
 from . import field25519 as F
@@ -246,12 +249,18 @@ def _verify_kernel(
     # dtype may be float32 (NARWHAL_FIELD_DTYPE) — cast once at entry.
     a_y = a_y.astype(F.DTYPE)
     r_y = r_y.astype(F.DTYPE)
-    a_point, a_valid = decompress(a_y, a_sign, a_canon)
-    r_point, r_valid = decompress(r_y, r_sign, r_canon)
-    small = is_small_order(a_point) | is_small_order(r_point)
+    # The named scopes group the ~118,000 device operations of a call by
+    # phase in a profile, whatever a refactor does to the operations'
+    # own names.  They are location metadata: not part of the compile
+    # cache's key, and the program is still `_verify_kernel`.
+    with jax.named_scope("verify_decompress"):
+        a_point, a_valid = decompress(a_y, a_sign, a_canon)
+        r_point, r_valid = decompress(r_y, r_sign, r_canon)
+        small = is_small_order(a_point) | is_small_order(r_point)
 
-    neg_a = point_neg(a_point)
-    a_table = _build_neg_a_table(neg_a)  # [B, 16, 4, LIMBS]
+    with jax.named_scope("verify_table"):
+        neg_a = point_neg(a_point)
+        a_table = _build_neg_a_table(neg_a)  # [B, 16, 4, LIMBS]
 
     def step(i, acc):
         acc = point_double(point_double(point_double(point_double(acc))))
@@ -259,10 +268,12 @@ def _verify_kernel(
         acc = point_add(acc, _select_from_table(a_table, k_windows[:, i]))
         return acc
 
-    start = identity_like(a_y)
-    result = jax.lax.fori_loop(0, 64, step, start)
+    with jax.named_scope("verify_ladder"):
+        start = identity_like(a_y)
+        result = jax.lax.fori_loop(0, 64, step, start)
 
-    return a_valid & r_valid & ~small & s_ok & point_eq(result, r_point)
+    with jax.named_scope("verify_compare"):
+        return a_valid & r_valid & ~small & s_ok & point_eq(result, r_point)
 
 
 # ----------------------------------------------------------- host-side prep
@@ -392,9 +403,14 @@ def _mesh_verify_kernel(n_dev: int):
 
         mesh = Mesh(np.array(jax.devices()), ("batch",))
         spec = P_("batch")
+
+        def _mesh_verify(*args):
+            with jax.named_scope("verify_mesh"):
+                return _verify_kernel.__wrapped__(*args)  # un-jitted
+
         fn = jax.jit(
             jax.shard_map(
-                _verify_kernel.__wrapped__,  # the un-jitted kernel
+                _mesh_verify,
                 mesh=mesh,
                 in_specs=(spec,) * 9,
                 out_specs=spec,
@@ -475,6 +491,8 @@ def verify_batch_arrays(
     sigs,
     dispatched: Optional[dict] = None,
     plan: Optional[Tuple[Callable, Sequence[int]]] = None,
+    stamps: Optional[dict] = None,
+    dispatch: int = 0,
 ) -> np.ndarray:
     """Bool mask for a batch of (message, key, signature) triples, padded
     and chunked by the pad ladder above (``plan``: a ``dispatch_plan()``
@@ -483,18 +501,48 @@ def verify_batch_arrays(
     k+1 overlaps the device's work on chunk k.  With NARWHAL_VERIFY_MESH
     and several visible devices each padded chunk is sharded across the
     device mesh.  ``dispatched`` (padded shape -> count) is incremented
-    per dispatch."""
+    per dispatch.  ``stamps`` (verify-stage trace, metrics.VERIFY_STAGES)
+    receives ``enqueued`` when the last chunk's kernel call has returned
+    (host preparation, transfer in and launch done) and ``fetched`` when
+    the last mask is on the host, with ``pad`` and ``chunks``;
+    ``dispatch`` is the burst number the profiler annotations carry."""
     n = len(messages)
     if n == 0:
         return np.zeros(0, dtype=bool)
     kernel, ladder = plan or dispatch_plan()
     pending = []
-    for lo, hi, pad in chunk_plan(n, ladder):
-        args = prepare_batch(messages[lo:hi], keys[lo:hi], sigs[lo:hi], pad)
-        pending.append((kernel(*(jnp.asarray(a) for a in args)), hi - lo))
-        if dispatched is not None:
-            dispatched[pad] = dispatched.get(pad, 0) + 1
-    return np.concatenate([np.asarray(out)[:m] for out, m in pending])
+    chunks = chunk_plan(n, ladder)
+    with annotate("verify.dispatch", dispatch=dispatch):
+        for lo, hi, pad in chunks:
+            with annotate("verify.prepare", dispatch=dispatch):
+                args = prepare_batch(
+                    messages[lo:hi], keys[lo:hi], sigs[lo:hi], pad
+                )
+            with annotate("verify.launch", dispatch=dispatch):
+                out = kernel(*(jnp.asarray(a) for a in args))
+            pending.append((out, hi - lo))
+            if dispatched is not None:
+                dispatched[pad] = dispatched.get(pad, 0) + 1
+    if stamps is not None:
+        stamps["enqueued"] = wall_now()
+    with annotate("verify.fetch", dispatch=dispatch):
+        masks = [np.asarray(out)[:m] for out, m in pending]
+    if stamps is not None:
+        stamps["fetched"] = wall_now()
+        pads = [pad for _, _, pad in chunks]
+        stamps["pad"] = pads[0] if len(pads) == 1 else pads
+        stamps["chunks"] = len(pads)
+    return np.concatenate(masks)
+
+
+def memory_peak_bytes() -> int:
+    """The most device memory in use at once so far, over the visible
+    devices, as the platform reports it (0 where it reports none: the
+    CPU backend has no allocator statistics)."""
+    return max(
+        int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+        for d in jax.devices()
+    )
 
 
 class TpuBackend:
@@ -543,24 +591,40 @@ class TpuBackend:
     async def averify_batch_mask(
         self, messages: Sequence[bytes], keys, sigs
     ) -> List[bool]:
-        mask, _ = await self.averify_batch_mask_timed(messages, keys, sigs)
+        mask, *_ = await self.averify_batch_mask_timed(messages, keys, sigs)
         return mask
 
     async def averify_batch_mask_timed(
         self, messages: Sequence[bytes], keys, sigs
-    ) -> Tuple[List[bool], float]:
-        """(mask, compute_seconds): compute time is measured ON the
-        dispatch thread around host prep + device round trip — the wall
-        the caller observes additionally includes executor queueing and
-        the event-loop wakeup, which is pipelining headroom, not crypto
-        cost (the `crypto.verify.device_seconds` split)."""
+    ) -> Tuple[List[bool], float, dict]:
+        """(mask, compute_seconds, stamps): compute time is measured ON
+        the dispatch thread around host prep + device round trip — the
+        wall the caller observes additionally includes executor queueing
+        and the event-loop wakeup, which is pipelining headroom, not
+        crypto cost (the `crypto.verify.device_seconds` split).
+        ``stamps`` are the dispatch thread's stages of the verify-stage
+        trace (``prepare``, ``enqueued``, ``fetched``) and its extras
+        (``pad``, ``chunks``, ``cpu_s``: this thread's CPU time across
+        them); the seam marks them on the loop."""
         import asyncio
-        import time
 
-        def timed() -> Tuple[List[bool], float]:
+        # The verify-stage burst this dispatch belongs to (read here, on
+        # the loop): the number its profiler annotations carry.
+        seq = int(current_burst() or 0)
+
+        def timed() -> Tuple[List[bool], float, dict]:
+            stamps = {"prepare": wall_now()}
+            cpu0 = time.thread_time()
             t0 = time.perf_counter()
-            mask = self.verify_batch_mask(messages, keys, sigs)
-            return mask, time.perf_counter() - t0
+            mask = list(
+                verify_batch_arrays(
+                    messages, keys, sigs, self._dispatched, self._plan,
+                    stamps=stamps, dispatch=seq,
+                )
+            )
+            compute_s = time.perf_counter() - t0
+            stamps["cpu_s"] = time.thread_time() - cpu0
+            return mask, compute_s, stamps
 
         return await asyncio.get_running_loop().run_in_executor(
             self._executor, timed
@@ -611,6 +675,7 @@ class TpuBackend:
         ``programs_at_ready`` means a live burst paid for a compile)."""
         return {
             **device_identity(),
+            "memory_peak_bytes": memory_peak_bytes(),
             "rungs": list(self.rungs),
             "dispatched": {
                 # dict(): one atomic copy — the dispatch thread may insert

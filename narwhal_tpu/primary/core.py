@@ -63,7 +63,8 @@ from ..crypto import Digest, PublicKey, SignatureService
 from ..messages import Round
 from ..network import ReliableSender
 from ..store import Store
-from ..utils.clock import loop_now
+from ..utils.clock import loop_now, wall_now
+from ..utils.devtrace import annotate, burst
 from ..utils.env import env_flag, env_float, env_int
 from ..utils.serde import Writer
 from .aggregators import CertificatesAggregator, VotesAggregator
@@ -316,6 +317,11 @@ class Core:
         # it back into protocol terms (a header contributes 1 claim, a
         # vote 1, a certificate 2f+2), which is what the bench's
         # protocol-arithmetic cross-check reads.
+        # Verify-stage trace (metrics.VERIFY_STAGES): one entry per burst,
+        # keyed by this sequence number; the seam and the backend's
+        # dispatch thread stamp the stages between ours.
+        self._verify_trace = metrics.verify_trace()
+        self._verify_seq = 0
         self._m_burst_claims = {
             kind: metrics.counter(f"crypto.burst_claims.{kind}")
             for kind in ("header", "vote", "certificate")
@@ -788,11 +794,44 @@ class Core:
     # scenario (100% CPU verifying duplicates, zero commits, 60+ s).
     VERIFIED_CACHE = 8192
 
-    async def _handle_primaries_burst(self, items: List) -> None:
+    async def _handle_primaries_burst(
+        self, items: List, collected: Optional[float] = None
+    ) -> str:
         """Batch-verify the signature claims of a drained burst in one
-        backend call, then replay the messages in arrival order."""
+        backend call, then replay the messages in arrival order.
+        Returns the burst's key in the verify-stage trace: the caller
+        marks ``replayed`` once its per-burst epilogue (log flush, GC
+        sweep) is done.  ``collected`` is when the pipelined stage closed
+        the batch; inline, the burst is collected as it enters."""
         from ..crypto import backend as crypto_backend
 
+        self._verify_seq += 1
+        key = str(self._verify_seq)
+        self._verify_trace.mark(
+            key, "collected", collected,
+            items=len(items),
+            round=max(getattr(item[1], "round", 0) for item in items),
+        )
+        with annotate("verify.submit", dispatch=self._verify_seq):
+            # lint: allow-interleave(_burst_claims reads current_header/gc_round for its stale pre-filter and _verified_recent for the dedup cache, and the replay below writes them after the backend await — safely, by the arguments pragma'd at those reads inside _burst_claims: rounds are monotone so a stale verdict only ever errs permissive and sanitize_* re-checks at replay, and the burst is single-flight by mode exclusivity so no other burst inserts into the cache meanwhile)
+            spans, msgs, keys, sigs = self._burst_claims(items)
+        with burst(key):
+            mask = (
+                await crypto_backend.averify_batch_mask(
+                    msgs, keys, sigs, site="batch_burst"
+                )
+                if msgs
+                else []
+            )
+        with annotate("verify.replay", dispatch=self._verify_seq):
+            await self._replay_burst(items, spans, mask)
+        return key
+
+    def _burst_claims(self, items: List):
+        """(spans, messages, keys, signatures): the signature claims of
+        a burst, flattened for one backend call, with per item where its
+        claims sit and whether it was filtered as stale or already
+        verified."""
         spans = []
         msgs: List[bytes] = []
         keys: List[PublicKey] = []
@@ -873,13 +912,10 @@ class Core:
                 msgs.append(m)
                 keys.append(k)
                 sigs.append(s)
-        mask = (
-            await crypto_backend.averify_batch_mask(
-                msgs, keys, sigs, site="batch_burst"
-            )
-            if msgs
-            else []
-        )
+        return spans, msgs, keys, sigs
+
+    async def _replay_burst(self, items: List, spans: List, mask) -> None:
+        """Replay a verified burst in arrival order."""
         for item, (off, count, stale, seen, dedup_key) in zip(items, spans):
             # Fail CLOSED on stale-filtered items: they carry zero verified
             # claims, so `all([])` would hand them sig_ok=True.  Today the
@@ -925,12 +961,14 @@ class Core:
                     )
                 except asyncio.TimeoutError:
                     break
+            collected = wall_now()
             # lint: allow-interleave(run() may flush/sweep after a waiter/proposer burst while this replay is suspended — safely: _flush_pending is subset-safe (flush_deferred appends EVERY buffered store record before releasing any staged vote, so persist-before-vote holds for an early flush of a partial burst) and _gc_sweep is monotonic-guarded (gc_round only advances; a concurrent sweep makes this one a no-op))
-            await self._handle_primaries_burst(items)
+            key = await self._handle_primaries_burst(items, collected)
             # Same per-burst epilogue as run(): one coalesced log flush
             # releasing the staged votes, then the per-round-map sweep.
             self._flush_pending()
             self._gc_sweep()
+            self._verify_trace.mark(key, "replayed")
 
     async def _forward_to_verify(self, items, verify_task) -> None:
         """Forward a drained burst into the verify pipeline.  Each
@@ -1001,6 +1039,7 @@ class Core:
                     gets[name] = loop.create_task(
                         queue.get(), name=f"core-{name}"
                     )
+                    verify_key = None
                     if name == "primaries":
                         if self._verify_q is not None:
                             # Pipelined: hand the burst to the verify
@@ -1012,7 +1051,9 @@ class Core:
                             )
                         else:
                             # lint: allow-interleave(mode exclusivity: this arm only runs with the pipeline OFF, where _verify_loop was never spawned — the "other root" the static merge sees cannot exist at runtime; the shared epilogue below is additionally subset-safe/monotonic as pragma'd in _verify_loop)
-                            await self._handle_primaries_burst(burst)
+                            verify_key = await self._handle_primaries_burst(
+                                burst
+                            )
                     else:
                         for item in burst:
                             await self._handle(name, item)
@@ -1020,6 +1061,8 @@ class Core:
                     # coalesced log flush, then sweep the per-round maps.
                     self._flush_pending()
                     self._gc_sweep()
+                    if verify_key is not None:
+                        self._verify_trace.mark(verify_key, "replayed")
         finally:
             for task in gets.values():
                 task.cancel()

@@ -179,17 +179,20 @@ _VARS = [
         "Seconds between health-rule sweeps.",
     ),
     EnvVar(
-        "NARWHAL_LOOP_WATCHDOG_MS", "int", 0,
-        "Opt-in event-loop stall watchdog: >0 installs it with this "
-        "threshold (ms). Stalls land in the "
-        "`runtime.loop_stall_seconds` histogram with a stack excerpt in "
-        "`runtime.loop_stall_last`; 0/unset = off.",
+        "NARWHAL_LOOP_WATCHDOG_MS", "int", 100,
+        "Event-loop stall watchdog threshold (ms; default one header "
+        "timer). Stalls land in the `runtime.loop_stall_seconds` "
+        "histogram, and each leaves a `loop_stall` flight event and "
+        "`runtime.loop_stall_last` with the loop thread's stack taken "
+        "during it and its cause (CPU time, collector time, a snapshot "
+        "write, the verify burst in flight); `0` = off.",
     ),
     EnvVar(
-        "NARWHAL_PROFILE_HZ", "float", 67.0,
-        "Sampling-profiler frequency (all-thread stack samples/s into "
-        "the `profile.*` series, folded-stack + top-N tables in the "
-        "snapshot detail); `0` disables the sampler thread.",
+        "NARWHAL_PROFILE_HZ", "float", 0.0,
+        "Opt-in sampling profiler: >0 samples all thread stacks this "
+        "many times a second into the `profile.*` series (folded-stack "
+        "+ top-N tables in the snapshot detail; ~67 avoids aliasing "
+        "with the 10/100 ms timers); 0/unset = off.",
     ),
     EnvVar(
         "NARWHAL_FLIGHT", "flag", True,

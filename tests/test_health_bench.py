@@ -20,12 +20,15 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmark.local_bench import run_bench  # noqa: E402
 
 
-def _run_clean_bench(tmp_path):
+def _run_clean_bench(tmp_path, monkeypatch):
     """Same shared-core retry convention as tests/test_remote_bench.py:
     a fixed-duration measurement window on a loaded host can starve the
     whole committee — a host artifact, retried once with the scraped
     time-series dumped for diagnosis.  A genuine regression fails both
     attempts."""
+    # The sampling profiler is opt-in (PR 26); the trace export's cpu
+    # track, asserted below, needs it (run_bench hands os.environ on).
+    monkeypatch.setenv("NARWHAL_PROFILE_HZ", "67")
     for attempt in (1, 2):
         result = run_bench(
             nodes=4,
@@ -80,8 +83,10 @@ def _run_clean_bench(tmp_path):
             )
 
 
-def test_clean_local_bench_has_timeline_and_no_firing_rules(tmp_path):
-    result, workdir = _run_clean_bench(tmp_path)
+def test_clean_local_bench_has_timeline_and_no_firing_rules(
+    tmp_path, monkeypatch
+):
+    result, workdir = _run_clean_bench(tmp_path, monkeypatch)
 
     # CI artifacts: the committee timeline, the exported Perfetto trace,
     # and the quiesce flight rings from the bench run, uploaded by the
@@ -338,7 +343,7 @@ def test_clean_local_bench_has_timeline_and_no_firing_rules(tmp_path):
     assert {ev["args"]["rank"] for ev in cp_slices} >= {1}, cp_slices
 
     # -- sampling profiler, always on (ISSUE 11 tentpole) --------------------
-    # Default NARWHAL_PROFILE_HZ (~67) armed the profiler in every node:
+    # NARWHAL_PROFILE_HZ=67 (set above) armed the profiler in every node:
     # the trace carries sampled-CPU slices and every snapshot-backed row
     # must have accumulated samples (asserted via the cpu track the
     # exporter builds from `profile.timeline`).

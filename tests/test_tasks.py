@@ -156,9 +156,17 @@ def test_watchdog_quiet_loop_observes_nothing():
 
 
 def test_install_from_env(monkeypatch):
-    async def unset():
-        monkeypatch.delenv("NARWHAL_LOOP_WATCHDOG_MS", raising=False)
+    async def off():
+        monkeypatch.setenv("NARWHAL_LOOP_WATCHDOG_MS", "0")
         assert install_from_env() is None
+
+    async def unset():
+        # On by default since PR 26: one header timer.
+        monkeypatch.delenv("NARWHAL_LOOP_WATCHDOG_MS", raising=False)
+        dog = install_from_env()
+        assert dog is not None and dog.threshold_s == pytest.approx(0.1)
+        assert dog.interval_s == pytest.approx(0.025)
+        await dog.shutdown()
 
     async def armed():
         monkeypatch.setenv("NARWHAL_LOOP_WATCHDOG_MS", "50")
@@ -169,5 +177,6 @@ def test_install_from_env(monkeypatch):
         )
         await dog.shutdown()
 
+    asyncio.run(off())
     asyncio.run(unset())
     asyncio.run(armed())

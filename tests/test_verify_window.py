@@ -219,7 +219,7 @@ def test_crashed_verify_loop_surfaces_instead_of_wedging():
         core, store, qs = make_window_core(c, me, window_ms=50.0)
         core._verify_q = asyncio.Queue(maxsize=1)  # force the full path
 
-        async def boom(items):
+        async def boom(items, collected=None):
             raise RuntimeError("verify stage boom")
 
         core._handle_primaries_burst = boom
@@ -250,7 +250,7 @@ def test_crashed_verify_loop_wakes_idle_run():
         me = keys()[0]
         core, store, qs = make_window_core(c, me, window_ms=10.0)
 
-        async def boom(items):
+        async def boom(items, collected=None):
             raise RuntimeError("idle boom")
 
         core._handle_primaries_burst = boom
