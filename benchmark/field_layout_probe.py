@@ -46,9 +46,11 @@ BITS, LIMBS, MASK, FOLD = 8, 32, 255, 38
 
 
 def _mul_limbs_minor(a, b):
-    """The LIVE layout (kept as a verbatim inline copy so the probe's two
-    arms stay symmetric): limbs on the minor axis, [..., 32] — what
-    field25519.mul is."""
+    """The LIVE layout (kept as an inline copy so the probe's two arms
+    stay symmetric): limbs on the minor axis, [..., 32] — field25519.mul
+    as it was when the layouts were compared (indexed-update carries;
+    since PR 27 the live mul reduces by shifted reads and its weak limbs
+    differ, its value does not)."""
     import jax.numpy as jnp
 
     conv = jnp.zeros(a.shape[:-1] + (2 * LIMBS - 1,), jnp.int32)
@@ -134,9 +136,17 @@ def main() -> None:
         from narwhal_tpu.ops import field25519 as F
 
         k = min(args.batch, 512)
-        live = np.asarray(F.mul(jnp.asarray(a[:k]), jnp.asarray(b[:k])))
+        # Held to the live mul by VALUE (canonical limbs): two weak forms
+        # of one element need not agree limb for limb.
+        live = np.asarray(
+            F.canon(F.mul(jnp.asarray(a[:k]), jnp.asarray(b[:k])))
+        )
         copy = np.asarray(
-            jax.jit(_mul_limbs_minor)(jnp.asarray(a[:k]), jnp.asarray(b[:k]))
+            F.canon(
+                jax.jit(_mul_limbs_minor)(
+                    jnp.asarray(a[:k]), jnp.asarray(b[:k])
+                )
+            )
         )
         if not (live == copy).all():
             raise SystemExit(

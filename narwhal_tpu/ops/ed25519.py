@@ -77,13 +77,22 @@ def point_add(p: Point, q: Point) -> Point:
     x2, y2, z2, t2 = q
     a = F.mul(F.sub(y1, x1), F.sub(y2, x2))
     b = F.mul(F.add(y1, x1), F.add(y2, x2))
-    c = F.mul(F.mul(t1, _2D), t2)
+    c = F.mul(F.mul(_2D, t1), t2)
     d = F.mul(F.add(z1, z1), z2)
     e = F.sub(b, a)
     f = F.sub(d, c)
     g = F.add(d, c)
     h = F.add(b, a)
-    return (F.mul(e, f), F.mul(g, h), F.mul(f, g), F.mul(e, h))
+    return _completed(e, f, g, h)
+
+
+def _completed(e, f, g, h) -> Point:
+    """(E·F, G·H, F·G, E·H): the four products that close an add or a
+    doubling.  mul() reads its FIRST operand limb by limb (one broadcast
+    column per limb, which the compiler extracts in two operations of
+    their own), so the products are written with two first operands, not
+    three: the extraction is shared."""
+    return (F.mul(e, f), F.mul(g, h), F.mul(g, f), F.mul(e, h))
 
 
 def point_double(p: Point) -> Point:
@@ -97,7 +106,7 @@ def point_double(p: Point) -> Point:
     e = F.sub(h, F.square(F.add(x1, y1)))
     g = F.sub(a, b)
     f = F.add(c, g)
-    return (F.mul(e, f), F.mul(g, h), F.mul(f, g), F.mul(e, h))
+    return _completed(e, f, g, h)
 
 
 def point_neg(p: Point) -> Point:
@@ -142,9 +151,8 @@ def decompress(y_limbs: jnp.ndarray, sign: jnp.ndarray,
     """
     y = y_limbs
     yy = F.square(y)
-    u = F.sub(yy, jnp.broadcast_to(_ONE, y.shape))
-    v = F.add(F.mul(yy, jnp.broadcast_to(_D, y.shape)),
-              jnp.broadcast_to(_ONE, y.shape))
+    u = F.sub(yy, _ONE)
+    v = F.add(F.mul(_D, yy), _ONE)
     # x = u·v³·(u·v⁷)^((p-5)/8)  (RFC 8032 §5.1.3)
     v3 = F.mul(F.square(v), v)
     v7 = F.mul(F.square(v3), v)
@@ -152,8 +160,7 @@ def decompress(y_limbs: jnp.ndarray, sign: jnp.ndarray,
     vxx = F.mul(v, F.square(x))
     ok_direct = F.eq(vxx, u)
     ok_twist = F.eq(vxx, F.neg(u))
-    x = F.select(ok_direct, x,
-                 F.mul(x, jnp.broadcast_to(_SQRT_M1, x.shape)))
+    x = F.select(ok_direct, x, F.mul(_SQRT_M1, x))
     on_curve = ok_direct | ok_twist
     xc = F.canon(x)
     x_is_zero = jnp.all(xc == 0, axis=-1)
@@ -220,14 +227,24 @@ def _select_from_table(table: jnp.ndarray, w: jnp.ndarray) -> Point:
 
 
 def _build_neg_a_table(neg_a: Point) -> jnp.ndarray:
-    """[..., 16, 4, LIMBS]: j·(-A) for j in 0..15 (15 sequential adds)."""
-    rows: List[Point] = [identity_like(neg_a[0])]
-    for _ in range(15):
-        rows.append(point_add(rows[-1], neg_a))
-    stacked = jnp.stack(
-        [jnp.stack(r, axis=-2) for r in rows], axis=-3
-    )  # [..., 16, 4, LIMBS]
-    return stacked
+    """[B, 16, 4, LIMBS]: j·(-A) for j in 0..15: 15 sequential adds, one
+    loop body that writes its row (unrolled they were 37,000 of the
+    kernel's 98,000 traced equations for the same device work)."""
+    zero = identity_like(neg_a[0])
+    first = jnp.stack(zero, axis=-2)  # [B, 4, LIMBS]
+    table = jnp.broadcast_to(
+        first[:, None], first.shape[:1] + (16,) + first.shape[1:]
+    )
+
+    def write_row(j, state):
+        table, row = state
+        row = point_add(row, neg_a)
+        stacked = jnp.stack(row, axis=-2)[:, None]
+        table = jax.lax.dynamic_update_slice_in_dim(table, stacked, j, axis=1)
+        return table, row
+
+    table, _ = jax.lax.fori_loop(1, 16, write_row, (table, zero))
+    return table
 
 
 # ------------------------------------------------------------ verification
@@ -249,20 +266,33 @@ def _verify_kernel(
     # dtype may be float32 (NARWHAL_FIELD_DTYPE) — cast once at entry.
     a_y = a_y.astype(F.DTYPE)
     r_y = r_y.astype(F.DTYPE)
-    # The named scopes group the ~118,000 device operations of a call by
-    # phase in a profile, whatever a refactor does to the operations'
-    # own names.  They are location metadata: not part of the compile
-    # cache's key, and the program is still `_verify_kernel`.
+    # The named scopes group the device operations of a call by phase in
+    # a profile, whatever a refactor does to the operations' own names.
+    # They are location metadata: not part of the compile cache's key,
+    # and the program is still `_verify_kernel`.
     with jax.named_scope("verify_decompress"):
-        a_point, a_valid = decompress(a_y, a_sign, a_canon)
-        r_point, r_valid = decompress(r_y, r_sign, r_canon)
-        small = is_small_order(a_point) | is_small_order(r_point)
+        # A and R go through ONE decompression and one small-order test,
+        # stacked along the batch axis: every operation is per row, so
+        # 2B rows cost the launches (and the traced equations) of B.
+        n = a_y.shape[0]
+        both, valid = decompress(
+            jnp.concatenate([a_y, r_y]),
+            jnp.concatenate([a_sign, r_sign]),
+            jnp.concatenate([a_canon, r_canon]),
+        )
+        small = is_small_order(both)
+        a_point = tuple(c[:n] for c in both)
+        r_point = tuple(c[n:] for c in both)
+        ok = valid[:n] & valid[n:] & ~(small[:n] | small[n:])
 
     with jax.named_scope("verify_table"):
         neg_a = point_neg(a_point)
         a_table = _build_neg_a_table(neg_a)  # [B, 16, 4, LIMBS]
 
     def step(i, acc):
+        # Four doublings written out: as an inner loop they traced a
+        # second faster a rung and ran 0.24 ms a call slower at 128 rows
+        # (2.155 against 1.913 ms, PERF.md section 5, PR 27).
         acc = point_double(point_double(point_double(point_double(acc))))
         acc = point_add(acc, _select_from_table(_B_TABLE, s_windows[:, i]))
         acc = point_add(acc, _select_from_table(a_table, k_windows[:, i]))
@@ -273,7 +303,7 @@ def _verify_kernel(
         result = jax.lax.fori_loop(0, 64, step, start)
 
     with jax.named_scope("verify_compare"):
-        return a_valid & r_valid & ~small & s_ok & point_eq(result, r_point)
+        return ok & s_ok & point_eq(result, r_point)
 
 
 # ----------------------------------------------------------- host-side prep
@@ -434,30 +464,35 @@ def mesh_devices() -> int:
 
 # -- the pad ladder -----------------------------------------------------------
 #
-# XLA compiles one program per padded batch shape, and one shape costs
-# about 150 s to build for a v5e (30 s of Python tracing and lowering in
-# every process, 110-145 s of compile when the persistent cache misses;
-# PERF.md).  So the shapes are a short fixed ladder, not every power of
-# two up to the committee's worst burst: a batch pads to the smallest rung
-# that holds it, and a batch above the top rung is split into top-rung
-# chunks.  The pad policy and the warm-up read the SAME ladder, so no live
-# burst — however large a late joiner's catch-up makes it — can reach a
-# shape that was not built before the node joined.
+# XLA compiles one program per padded batch shape.  On the chip's host one
+# shape traces and lowers in ~5.6 s in EVERY process, cache hit or not,
+# then loads from the persistent cache in ~2 s or builds cold in 17-25 s
+# (PERF.md, PR 27; before that PR the program was four times the size:
+# ~24 s, 9-16 s and 110-145 s).  So the shapes are a short fixed ladder,
+# not every power of two up to the committee's worst burst: a batch pads
+# to the smallest rung that holds it, and a batch above the top rung is
+# split into top-rung chunks.  The pad policy and the warm-up read the
+# SAME ladder, so no live burst — however large a late joiner's catch-up
+# makes it — can reach a shape that was not built before the node joined.
 #
-# The chip's rungs come from one reading of the kernel on a v5e (ms per
-# call, prepared arrays in, mask fetched; PERF.md, PR 22): 16 -> 37.5, 64
-# -> 17.1, 128 -> 17.4, 256 -> 20.4, 512 -> 26.5, 2048 -> 53.9.  The
-# 64-step ladder costs ~17 ms whatever it holds, and 16 rows is the
-# SLOWEST small shape, so the bottom rung is 128: the widest shape still
-# at the floor.  Top rung 512: one DRAIN_LIMIT burst of quorum-carrying
-# certificates at N=4 (128 x (3 + 1) claims) in a single dispatch.
-# ROADMAP S5 re-chooses both from the batch-size histograms of the
+# The chip's rungs were chosen from one reading of the old program on a
+# v5e (ms per call, prepared arrays in, mask fetched; PERF.md, PR 22): 16
+# -> 37.5, 64 -> 17.1, 128 -> 17.4, 256 -> 20.4, 512 -> 26.5, 2048 ->
+# 53.9: a floor of ~17 ms whatever the call held, 16 rows the SLOWEST
+# small shape, so the bottom rung is 128, the widest shape at the floor.
+# Top rung 512: one DRAIN_LIMIT burst of quorum-carrying certificates at
+# N=4 (128 x (3 + 1) claims) in a single dispatch.  Today's program reads
+# 128 -> 2.96 and 512 -> 5.23 the same way (1.89 and 4.20 ms of device
+# time; PERF.md, PR 27); the smaller shapes have not been read again.
+# ROADMAP S5 re-chooses both rungs from the batch-size histograms of the
 # benchmark's cells.
 #
 # Off the chip (jax-cpu: the tests and the A/B arms, never a speed) one
-# shape takes ~90 s to build and a call costs ~0.2 s at 16 rows against
-# ~2.8 s at 512, so the ladder there is one small rung and bursts above
-# it exercise the split.  The platform is what JAX reports, not a knob.
+# shape takes ~85 s to build and a call costs ~2 s at 16 rows (the CPU
+# compiler recomputes a product inside every shifted read of it, where
+# the chip's materializes it once), so the ladder there is one small
+# rung and bursts above it exercise the split.  The platform is what JAX
+# reports, not a knob.
 
 CHIP_RUNGS = (128, 512)
 CPU_RUNGS = (16,)
@@ -551,8 +586,9 @@ class TpuBackend:
     under: "tpu" (the chip, checked at selection) or "jax" (whatever
     platform JAX has — the CPU tests and A/B arms)."""
 
-    # A dispatch is one device round trip on the dispatch thread, ~20 ms
-    # on a v5e whatever it holds: the Core drives such a backend through
+    # A dispatch is one device round trip on the dispatch thread, ~7.5 ms
+    # on a v5e at the bottom rung (PERF.md, PR 27; 22 ms before), most of
+    # it host preparation and launch: the Core drives such a backend through
     # its pipelined verify stage (primary/core.py) instead of awaiting
     # each drained burst inline.
     dispatches_off_loop = True

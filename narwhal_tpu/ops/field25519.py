@@ -6,7 +6,13 @@ intermediate exactly representable: weak limbs < 2^9, pairwise products
 < 2^18, a 32-term convolution row < 2^23.  The schoolbook convolution
 runs as 32 fused shifted multiply-accumulates on the VPU (see mul() for
 why this beats the MXU matmul formulation on v5e); carries, folds and
-comparisons are elementwise, also VPU.  This is the TPU-shaped answer to
+comparisons are elementwise, also VPU.  Every move of a value along the
+limb axis is a SHIFTED READ of an operand (_shift: one lax.pad whose
+negative edges cut), never an indexed update: the v5e compiler fuses
+elementwise work and shifted reads of a materialized value into one
+device operation, while each `.at[].add` became a scatter of two or
+three (PERF.md section 5, PR 27: they were 82% of a ladder step's
+launches).  This is the TPU-shaped answer to
 the reference's ed25519-dalek (crypto/src/lib.rs:206-219), whose Rust
 backend uses 51-bit limbs in u128 — a layout that cannot map to vector
 lanes.
@@ -17,8 +23,9 @@ f32 machine first — if 32-bit integer multiply is emulated or
 rate-limited, the same algorithm in floats wins.  Every f32 intermediate
 is an INTEGER kept strictly below 2^24 (the f32 exact-integer range):
 the 2^23 convolution-row bound fits as-is; carries use an exact
-power-of-two scale + floor instead of shifts; mul's ×38 fold is split
-into two sub-2^24 halves (see mul()).  The same differential suite
+power-of-two scale + floor instead of shifts; mul splits each row sum
+into bytes BEFORE the ×38 fold, so the fold never leaves the range (see
+mul()).  The same differential suite
 proves either dtype against Python big ints: the default test run
 covers int32 plus an f32 field-op subprocess check
 (tests/test_ed25519.py::test_float32_lane_mode_field_ops); the FULL
@@ -65,7 +72,6 @@ if _DTYPE_ENV not in ("int32", "float32"):
 FP = _DTYPE_ENV == "float32"
 DTYPE = jnp.float32 if FP else jnp.int32
 NP_DTYPE = np.float32 if FP else np.int32
-_INV_RADIX = 1.0 / (1 << BITS)  # exact power-of-two scale for f32 carries
 
 
 def to_limbs(x: int) -> np.ndarray:
@@ -80,26 +86,46 @@ def from_limbs(limbs) -> int:
     return sum(int(v) << (BITS * i) for i, v in enumerate(arr))
 
 
-def _split(c: jnp.ndarray):
-    """(carry, low 8 bits) of every limb.  int32: shift/mask.  float32:
-    exact scale-by-2^-8 + floor, then subtract back — every step is exact
-    for integer-valued c < 2^24 (scaling by a power of two never rounds,
-    floor of an exact value is exact, and hi·256 < 2^24)."""
+def _hi(c: jnp.ndarray, bits: int = BITS) -> jnp.ndarray:
+    """c // 2^bits.  int32: a shift.  float32: exact scale by 2^-bits +
+    floor (scaling by a power of two never rounds, and the floor of an
+    exact value is exact) for integer-valued c < 2^24."""
     if FP:
-        hi = jnp.floor(c * _INV_RADIX)
-        lo = c - hi * (1 << BITS)
-    else:
-        hi = c >> BITS
-        lo = c & MASK
-    return hi, lo
+        return jnp.floor(c * (1.0 / (1 << bits)))
+    return c >> bits
+
+
+def _lo(c: jnp.ndarray) -> jnp.ndarray:
+    """The low 8 bits of every limb.  float32 subtracts the carry back
+    (hi·256 ≤ c < 2^24: exact)."""
+    return c - _hi(c) * (1 << BITS) if FP else c & MASK
+
+
+def _shift(x: jnp.ndarray, lo: int, hi: int) -> jnp.ndarray:
+    """x moved ``lo`` places up the limb axis and its width changed by
+    ``lo + hi``: zeros come in where an edge is positive, limbs fall off
+    where it is negative.  One lax.pad: the form the TPU compiler reads
+    as an offset load inside the consumer's fusion."""
+    edges = [(0, 0, 0)] * (x.ndim - 1) + [(lo, hi, 0)]
+    return jax.lax.pad(x, NP_DTYPE(0), edges)
+
+
+# Weight of the limb each carry lands on after the cyclic move of one
+# place: the carry out of the top limb wraps to limb 0 times 38.
+_WRAP = jnp.asarray(np.array([FOLD] + [1] * (LIMBS - 1), dtype=NP_DTYPE))
+
+
+def _rotate(c: jnp.ndarray) -> jnp.ndarray:
+    """Every limb one place up, the top limb round to limb 0: two shifted
+    reads with disjoint support, so their sum is exact."""
+    return _shift(c, 1, -1) + _shift(c, 1 - LIMBS, LIMBS - 1)
 
 
 def _carry_once(c: jnp.ndarray) -> jnp.ndarray:
     """One vectorized carry sweep; the carry out of the top limb wraps to
-    limb 0 multiplied by 38 (2^256 ≡ 38 mod p)."""
-    hi, lo = _split(c)
-    out = lo.at[..., 1:].add(hi[..., :-1])
-    return out.at[..., 0].add(hi[..., -1] * FOLD)
+    limb 0 multiplied by 38 (2^256 ≡ 38 mod p).  The limbs are moved
+    first and split after: the move is then a read of the operand."""
+    return _lo(c) + _hi(_rotate(c)) * _WRAP
 
 
 def carry(c: jnp.ndarray, sweeps: int = 4) -> jnp.ndarray:
@@ -113,11 +139,17 @@ def carry(c: jnp.ndarray, sweeps: int = 4) -> jnp.ndarray:
     weak bound (see mul's exactness note and sub's ZP offset).
 
     ``sweeps`` lets callers with tighter input bounds skip work (each
-    sweep is ~5 vector ops on the hot path); every reduced-sweep call
-    site must carry its own bound proof (see add/sub)."""
+    sweep is one device operation on the hot path); every reduced-sweep
+    call site must carry its own bound proof (see mul/add/sub)."""
     for _ in range(sweeps):
         c = _carry_once(c)
     return c
+
+
+def _byte(c: jnp.ndarray, n: int) -> jnp.ndarray:
+    """Byte n (0, 1 or 2) of every limb of c < 2^24."""
+    top = _hi(c, BITS * n) if n else c
+    return top if n == 2 else _lo(top)
 
 
 def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -130,36 +162,40 @@ def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     < 2^23 — inside int32's 2^31 budget and f32's 2^24 exact-integer
     range alike.
 
+    Reduction, in three device operations after the convolution.  Each
+    row sum is cut into its three bytes where it stands: byte n of row k
+    belongs to limb k + n, so r[k] = byte0(c[k]) + byte1(c[k-1]) +
+    byte2(c[k-2]) for k in 0..64 has every limb ≤ 3·255 = 765.  Limbs
+    32..63 fold ×38 (2^256 ≡ 38 mod p) and r[64] = byte2(c[62]) ≤ 3
+    (c[62] = a_31·b_31 < 2^18) folds ×38² = 1444, onto limb 0: limbs
+    1..31 ≤ 765·39 = 29,835 and limb 0 ≤ 29,835 + 3·1444 = 34,167, in
+    both dtypes (no product above 2^15.1: the f32 mode needs no split
+    fold of its own).  Two carry sweeps then reach the weak bound: the
+    first leaves limb 1 ≤ 255 + 133, limbs 2..31 ≤ 255 + 116 and limb 0
+    ≤ 255 + 38·116 = 4,663; the second limb 1 ≤ 255 + 18, limbs 2..31 ≤
+    256 and limb 0 ≤ 255 + 38 = 293 — all < 2^9.
+
     Why not the MXU?  The "one-hot convolution tensor" formulation — a
     single [B·32², 63] f32 matmul — was measured 1.4× SLOWER end-to-end
     on v5e: it must materialize the [B, 32²] outer product through HBM
     (66 MB round trip per multiply at B=8192) and its useful-FLOP ratio
     is 1/63, while the shifted-MAC chain fuses into one VPU kernel whose
-    only HBM traffic is the operands and the result."""
-    shape = jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1])
-    conv = jnp.zeros(shape + (2 * LIMBS - 1,), DTYPE)
-    pad_base = [(0, 0)] * (b.ndim - 1)
-    for i in range(LIMBS):
-        conv = conv + a[..., i : i + 1] * jnp.pad(
-            b, pad_base + [(i, LIMBS - 1 - i)]
-        )
-    # Fold limbs ≥ 32: 2^(8(32+j)) ≡ 38·2^(8j) (mod p).
-    hi = conv[..., LIMBS:]
-    lo = conv[..., :LIMBS]
-    if FP:
-        # Direct ×38 would reach 38·2^23 ≈ 2^28.3 — outside f32's exact
-        # range.  Split each hi limb into 8-bit halves first: hi_hi < 2^15
-        # lands one limb higher (2^8·38·2^(8j) = 38·2^(8(j+1))), so both
-        # products stay < 2^21 and every folded limb < 2^23 + 2^13.3 +
-        # 2^20.3 < 2^23.3 — exact.  hi has 31 entries (j ≤ 30), so j+1 ≤
-        # 31 never needs a secondary fold.
-        hi_hi, hi_lo = _split(hi)
-        folded = lo.at[..., : LIMBS - 1].add(hi_lo * FOLD)
-        folded = folded.at[..., 1:LIMBS].add(hi_hi * FOLD)
-    else:
-        # conv < 2^23 so the ×38 (< 2^29) stays inside int32.
-        folded = lo.at[..., : LIMBS - 1].add(hi * FOLD)
-    return carry(folded)
+    only HBM traffic is the operands and the result.  The outer product
+    with a skewing reshape (45 traced equations against 170) was
+    compiled for the v5e and left: pad, reshape and reduce each stay a
+    device operation of their own (PERF.md section 5, PR 27)."""
+    conv = a[..., :1] * _shift(b, 0, LIMBS - 1)
+    for i in range(1, LIMBS):
+        conv = conv + a[..., i : i + 1] * _shift(b, i, LIMBS - 1 - i)
+    top = LIMBS - 1  # conv is 2·LIMBS - 1 wide; row `at + top` is its last
+    low, high = (
+        _byte(_shift(conv, -at, at - top), 0)
+        + _byte(_shift(conv, 1 - at, at - top - 1), 1)
+        + _byte(_shift(conv, 2 - at, at - top - 2), 2)
+        for at in (0, LIMBS)
+    )
+    last = _byte(_shift(conv, -2 * top, top), 2)  # r[64], on limb 0
+    return carry(low + high * FOLD + last * (FOLD * FOLD), sweeps=2)
 
 
 def square(a: jnp.ndarray) -> jnp.ndarray:
@@ -189,30 +225,28 @@ def add(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 # then (a + ZP - b) is non-negative per limb and carry() reduces it.
 # Construct: put 2·MASK = 510 in every limb, then add the canonical limbs of
 # the complement that makes the total a multiple of p — every final limb is
-# ≥ 510 + 0... asserted ≥ 512 below via the 637 minimum that construction
-# actually yields.
+# in [510, 765]; asserted ≥ 512 below (the construction's minimum is 637).
 _base = sum(2 * MASK << (BITS * i) for i in range(LIMBS))
 _comp = (-_base) % P
 _zp = [2 * MASK + ((_comp >> (BITS * i)) & MASK) for i in range(LIMBS)]
 assert sum(v << (BITS * i) for i, v in enumerate(_zp)) % P == 0
-assert all((1 << 9) <= v < (1 << 15) for v in _zp), _zp
+assert all((1 << 9) <= v <= 3 * MASK for v in _zp), _zp
 _ZP = jnp.asarray(np.array(_zp, dtype=NP_DTYPE))
 
 
 def sub(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """a - b (mod p): the ZP offset keeps every limb non-negative.
 
-    Two carry sweeps suffice: a + ZP - b < 2^9 + 2^15 = 33280 per limb,
-    so sweep 1's carries are ≤ 130, leaving limbs 1..31 ≤ 255 + 130 and
-    limb 0 ≤ 255 + 130·38 = 5195; sweep 2's carries are then ≤ 20
-    (limb 0) / ≤ 1 (rest), leaving limb 1 ≤ 275, limbs 2..31 ≤ 256, and
-    limb 0 ≤ 255 + 1·38 = 293 — all < 2^9."""
-    return carry(a + _ZP - b, sweeps=2)
+    One carry sweep suffices: ZP's limbs are ≤ 765 (asserted above), so
+    a + ZP - b ≤ 511 + 765 = 1276 per limb, the carry out of a limb is
+    ≤ 4, and after the sweep limbs 1..31 are ≤ 255 + 4 and limb 0 is
+    ≤ 255 + 4·38 = 407 — all < 2^9."""
+    return carry(a + _ZP - b, sweeps=1)
 
 
 def neg(a: jnp.ndarray) -> jnp.ndarray:
-    """-a (mod p); same bound argument as sub (a ≤ ZP + 2^9 per limb)."""
-    return carry(_ZP - a, sweeps=2)
+    """-a (mod p); same bound argument as sub (ZP - a ≤ 765 per limb)."""
+    return carry(_ZP - a, sweeps=1)
 
 
 def mul_small(a: jnp.ndarray, k: int) -> jnp.ndarray:
@@ -227,9 +261,7 @@ def mul_small(a: jnp.ndarray, k: int) -> jnp.ndarray:
         k_hi, k_lo = k >> BITS, k & MASK
         lo_part = a * jnp.asarray(k_lo, DTYPE)
         hi_part = a * jnp.asarray(k_hi, DTYPE)
-        c = lo_part.at[..., 1:].add(hi_part[..., :-1])
-        c = c.at[..., 0].add(hi_part[..., -1] * FOLD)
-        return carry(c)
+        return carry(lo_part + _rotate(hi_part) * _WRAP)
     return carry(a * jnp.asarray(k, DTYPE))
 
 
@@ -304,8 +336,9 @@ def canon(a: jnp.ndarray) -> jnp.ndarray:
     # carry bit above a full 2^8-1 limb), and one sweep only moves such a
     # spike up one position — run LIMBS+2 sweeps so any spike exits the
     # top and wraps to a small limb-0 term, leaving every limb < 2^8.
-    for _ in range(LIMBS + 2):
-        c = _carry_once(c)
+    # (A loop on the device: 34 copies of the sweep were a third of a
+    # canon's traced equations and run no faster.)
+    c = jax.lax.fori_loop(0, LIMBS + 2, lambda _, x: _carry_once(x), c)
     # Value is now < 2^256 < 3p: strip multiples of p by conditional
     # subtraction until below p (3 rounds give margin).
     for _ in range(3):

@@ -424,10 +424,55 @@ def test_tpu_averify_runs_off_event_loop(monkeypatch):
     assert ticks, "event loop starved during device verify"
 
 
+def count_equations(jaxpr) -> int:
+    """Equations of a jaxpr, those of every nested one (loop bodies,
+    branches, inner jits) included."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += 1
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)  # ClosedJaxpr -> Jaxpr
+                if hasattr(inner, "eqns"):
+                    total += count_equations(inner)
+    return total
+
+
+# The verify program's size, in traced equations at the chip's bottom
+# rung.  Tracing and lowering cost ~0.25 ms an equation in EVERY process
+# that builds the ladder, cache hit or not (PR 27: 98,258 equations were
+# ~24 s a rung on the chip's host before the node could join, 22,722 are
+# ~5.6 s).  The ceiling is a fifth above what PR 27 reached, so that a
+# later edit cannot quietly bring the wait back; 35,000 is the most it
+# may ever be raised to.  No compile, no chip: runs wherever JAX does.
+VERIFY_KERNEL_EQUATIONS_CEILING = 27_000
+
+
+def test_verify_program_stays_within_its_size_budget():
+    import jax.numpy as jnp
+
+    b = E.CHIP_RUNGS[0]
+    limbs = jax.ShapeDtypeStruct((b, F.LIMBS), jnp.int32)
+    sign = jax.ShapeDtypeStruct((b,), jnp.int32)
+    flag = jax.ShapeDtypeStruct((b,), jnp.bool_)
+    windows = jax.ShapeDtypeStruct((b, 64), jnp.int32)
+    traced = jax.make_jaxpr(E._verify_kernel.__wrapped__)(
+        limbs, sign, flag, limbs, sign, flag, windows, flag, windows
+    )
+    equations = count_equations(traced.jaxpr)
+    assert VERIFY_KERNEL_EQUATIONS_CEILING <= 35_000
+    assert equations <= VERIFY_KERNEL_EQUATIONS_CEILING, equations
+    # One multiplication is the unit everything else is made of.
+    one = jax.ShapeDtypeStruct((b, F.LIMBS), F.DTYPE)
+    assert count_equations(jax.make_jaxpr(F.mul)(one, one).jaxpr) <= 200
+
+
 def test_float32_lane_mode_field_ops():
     """The float32 lane dtype (NARWHAL_FIELD_DTYPE=float32) computes the
-    dtype-sensitive pieces — field mul/sub/canon (split carries, split
-    ×38 fold, ×k chunking) and the one-hot table select — exactly, in a
+    dtype-sensitive pieces — field mul/sub/canon (scale-and-floor
+    carries, the byte split before the ×38 fold, ×k chunking) at random
+    values and at the weak bound's corners, and the one-hot table select
+    — exactly, in a
     subprocess so the env-selected dtype is picked up at import.  Scoped
     to ops that compile in seconds; the FULL verify kernel under f32
     (several minutes of cold CPU compile) is covered by running
@@ -457,6 +502,11 @@ for _ in range(8):
     assert F.from_limbs(np.asarray(F.mul_small(xl, 121666))[0]) %% P == (
         x * 121666 %% P)
     assert F.from_limbs(np.asarray(F.canon(xl))[0]) == x
+# The weak bound's corners (every limb 511, limb 0 at 293, row sums at
+# 2^23, carries from the lane's whole exact range), the same table the
+# int32 run parametrises.
+from tests.test_field25519 import check_all_corners
+assert check_all_corners() >= 40
 from narwhal_tpu.ops import ed25519 as E
 import jax.numpy as jnp
 ws = [3, 0, 15]
