@@ -1,9 +1,9 @@
 """One committee on localhost, as the product starts it.
 
 Copied from ``benchmark/local_bench.py::run_bench`` (keys from the seed,
-committee and parameters files, device-backed primary first and booted
-before its peers, the last ``faults`` validators never started, SIGTERM
-teardown with a long grace for the chip holder) because that file lives
+committee and parameters files, device-backed primaries first and booted
+before their peers, the last ``faults`` validators never started, SIGTERM
+teardown with a long grace for a chip holder) because that file lives
 outside the benchmark's directory and a later PR may change it.  What is
 different, and why:
 
@@ -15,6 +15,12 @@ different, and why:
   never start (``dead_key_ranks``); every seed gets the same schedule.
 - this process never imports JAX or the program: it writes the key,
   committee and parameter files in their JSON formats itself.
+- the configuration says which primaries hold a chip
+  (``chip_primaries``, launch indices).  In the deployment each
+  primary's host has one chip; on one host with several chips the same
+  is had by giving each device-backed primary's PROCESS one chip to see
+  (``chip_env``), as it is given its ports.  The program does not change:
+  its ``jax.devices()[0]`` is then the process's only device.
 """
 
 from __future__ import annotations
@@ -41,6 +47,20 @@ REPO = os.path.dirname(HERE)
 # allows a compiling run 1200 s in all.
 DEVICE_BOOT_DEADLINE_S = 1000
 PEER_BOOT_DEADLINE_S = 90
+
+
+def chip_env(k: int) -> dict:
+    """What lets a process see chip ``k`` of its host and no other:
+    libtpu's own process-level visibility, the documented way to run
+    several one-chip processes on one host.  Proven on the four-chip v5e
+    host by ``chip_probe.py`` (README, "A chip per primary")."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(k),
+        "TPU_VISIBLE_DEVICES": str(k),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_PORT": str(8476 + k),
+    }
 
 
 class RunFailure(Exception):
@@ -164,6 +184,17 @@ class Committee:
         self.nodes = config["nodes"]
         self.workers = config["workers"]
         self.alive = self.nodes - config["faults"]
+        # Launch indices of the primaries that verify on a device: the
+        # configuration's on the chip, primary 0 alone on jax-cpu (a
+        # sandbox holds one such process), none on OpenSSL.
+        named = sorted(set(config["chip_primaries"]))
+        if not named or not all(0 <= i < self.alive for i in named):
+            raise ValueError("chip_primaries must name live primaries")
+        self.chip_nodes = {"tpu": named, "jax": [0], None: []}[backend]
+        # The primaries that are sent forged headers: the configuration's
+        # chip holders, whatever verifies in this run (a rehearsal's
+        # OpenSSL has to reject them as well).
+        self.forged_nodes = named
         self.base_port = port_base(config)
         self.ids = make_identities(seed, config)
         self.procs = []  # (Popen, log file, holds the chip)
@@ -225,12 +256,22 @@ class Committee:
             "--metrics-port", str(mport),
         ]
 
-    def spawn_primary(self, i: int):
-        on_device = i == 0 and self.backend is not None
+    def primary_env(self, i: int) -> dict:
+        """Primary i's environment: the audit path, and for a device
+        node chip k's variables, k its place among the device nodes.
+        Where one primary alone holds a chip it gets none of them: its
+        process sees the host's chips as it always did."""
         env = dict(
             self.env,
             NARWHAL_CONSENSUS_AUDIT=self.path(f"audit-primary-{i}.bin"),
         )
+        if i in self.chip_nodes and len(self.chip_nodes) > 1:
+            env.update(chip_env(self.chip_nodes.index(i)))
+        return env
+
+    def spawn_primary(self, i: int):
+        on_device = i in self.chip_nodes
+        env = self.primary_env(i)
         args = self.node_args(
             i, f"db-primary-{i}", f"metrics-primary-{i}.json",
             self.primary_metrics_port(i),
@@ -239,11 +280,12 @@ class Committee:
             # The same entry (narwhal_tpu.node.main.main) with the same
             # arguments, inside a wrapper that can read the chip's peak
             # memory at exit and trace it on request: only the process
-            # that holds the chip can do either.
+            # that holds the chip can do either.  Primary 0's chip is
+            # the one traced.
             cmd = [
                 sys.executable, os.path.join(HERE, "device_node.py"),
-                "--report", self.path("device-node.json"),
-                "--trace-dir", self.path("trace") if self.traced else "",
+                "--report", self.path(f"device-node-{i}.json"),
+                "--trace-dir", self.path("trace") if self.traced and i == 0 else "",
                 "--trace-seconds", str(self.harness["trace_seconds"]),
                 "--", *args, "--crypto-backend", self.backend, "primary",
             ]
@@ -289,17 +331,20 @@ class Committee:
             return ""
 
     def start_nodes(self) -> None:
-        """Device-backed primary first and nothing else until it booted:
-        three OpenSSL primaries are a quorum in the four-up deployment and
-        any peers would time out on a validator that is still compiling."""
-        first = self.spawn_primary(0)
+        """Device-backed primaries first, together, and nothing else
+        until they booted: three OpenSSL primaries are a quorum in the
+        four-up deployment and any peers would time out on a validator
+        that is still compiling.  (On OpenSSL alone primary 0 goes
+        first, as it always did.)"""
+        first = self.chip_nodes or [0]
         self.wait_for_boot(
-            ["primary-0.log"],
+            [f"primary-{i}.log" for i in first],
             DEVICE_BOOT_DEADLINE_S if self.backend else PEER_BOOT_DEADLINE_S,
-            [first],
+            [self.spawn_primary(i) for i in first],
         )
-        procs = [self.spawn_primary(i) for i in range(1, self.alive)]
-        names = [f"primary-{i}.log" for i in range(1, self.alive)]
+        rest = [i for i in range(self.alive) if i not in first]
+        procs = [self.spawn_primary(i) for i in rest]
+        names = [f"primary-{i}.log" for i in rest]
         for i in range(self.alive):
             for w in range(self.workers):
                 procs.append(self.spawn_worker(i, w))
@@ -337,7 +382,7 @@ class Committee:
 
     def teardown(self) -> None:
         """SIGTERM everything, then wait per process (the SIGTERM path
-        flushes each node's final metrics snapshot and audit segment); the
+        flushes each node's final metrics snapshot and audit segment); a
         chip holder gets 75 s to finish its device call and let go."""
         for p, _, _ in self.procs:
             if p.poll() is None:

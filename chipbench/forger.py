@@ -1,20 +1,24 @@
-"""A Byzantine peer that sends primary 0 headers with bad signatures.
+"""A Byzantine peer that sends a primary headers with bad signatures.
 
 In honest traffic every signature is valid, so a verifier that accepts
 everything would pass unseen.  This part of the traffic makes the
-verifier's rejections visible on the timed path: ``forged_per_s`` headers
-a second (the workload file's number) go to primary 0's
-primary-to-primary socket as raw frames, each in the name of a validator
-that is down, each with a signature that OpenSSL refuses.  Primary 0 has
-to put each through its verifier, reject it and count it
-(``primary.invalid_signatures``); `correct` holds the count to what was
-sent.  The kinds are ``chip_smoke.py::make_batch``'s: a bit flipped in
+verifiers' rejections visible on the timed path: ``forged_per_s`` headers
+a second (the workload file's number), shared evenly among the
+device-backed primaries (one ``Forger`` each), go to the target's
+primary-to-primary socket as raw frames, each with a signature that
+OpenSSL refuses, each in the name of a validator that is down where one
+is and of the target's next neighbour where all are up (the target
+never receives its own headers from a peer).  The target has to put each
+through its verifier, reject it and count it
+(``primary.invalid_signatures``); `correct` holds each target's count to
+what it was sent.  The kinds are ``chip_smoke.py::make_batch``'s: a bit flipped in
 R, a bit flipped in S, a genuine signature by the wrong key, and S + L
 (non-canonical).
 
 A forged header carries a round far above the committee's and one random
 parent, so that it is never stale, is well formed (its id is the hash
-of its content) and can only fall at the signature.
+of its content) and can only fall at the signature, whether its author
+is up or down.
 """
 
 from __future__ import annotations
@@ -61,17 +65,26 @@ def forged_header(k: int, kind: str, author, other, rng: random.Random) -> Heade
     return h
 
 
+def forged_names(ids: list, alive: int, target: int) -> tuple:
+    """(the identity the forgeries name as author, the one that signs the
+    wrong-key kind) for the primary of launch index ``target``: the first
+    validator that is down, else the target's next neighbour; the wrong
+    key is the target's own.  They differ whatever the layout."""
+    author = ids[alive] if alive < len(ids) else ids[(target + 1) % len(ids)]
+    return author, ids[target]
+
+
 class Forger(threading.Thread):
     """Sends one forged header every ``1 / per_s`` seconds until stopped;
     ``sent`` lists (wall time, kind, header id) of every frame the peer
     acknowledged."""
 
-    def __init__(self, address: str, ids: list, dead_from: int,
-                 sorted_keys: List[bytes], per_s: float, seed: int) -> None:
+    def __init__(self, address: str, ids: list, alive: int,
+                 sorted_keys: List[bytes], per_s: float, seed: int,
+                 target: int = 0) -> None:
         super().__init__(daemon=True)
         self.address = address
-        self.author = ids[dead_from]
-        self.other = ids[0]
+        self.author, self.other = forged_names(ids, alive, target)
         self.sorted_keys = sorted_keys
         self.period = 1.0 / per_s
         self.rng = random.Random(seed)

@@ -1,7 +1,8 @@
 """The comparison that decides ``correct``.
 
 What the timed run produced (every live replica's audit segment, every
-live worker's store, primary 0's counters, the clients' samples) is held
+live worker's store, the device-backed primaries' counters, the clients'
+samples) is held
 against a plain reference that imports nothing of the program: OpenSSL
 for signatures, ``tusk.PlainTusk`` for the order, SHA-256 and the
 clients' own bytes for the batches.  Every number compared is a count of
@@ -17,11 +18,13 @@ Layer by layer:
 - primary and verify seam: every certificate that entered any live
   replica's commit rule carries a valid header signature and 2f+1 valid
   votes of distinct validators under OpenSSL (``certificates_invalid``:
-  primary 0 took each through the on-chip verifier); primary 0 rejected
-  exactly the forged headers it was sent (``verifier_reject_gap``); it
-  built no program after ready and dispatched no shape outside the
-  warmed ladder (``device_off_ladder``), and it did dispatch to the
-  device inside the window (``window_without_dispatch``);
+  a device-backed primary took each through its on-chip verifier); EACH
+  device-backed primary rejected exactly the forged headers it was sent
+  (``verifier_reject_gap``: the gaps summed, so that one verifier of
+  four that accepts is not hidden by the others); each built no program
+  after ready and dispatched no shape outside the warmed ladder
+  (``device_off_ladder``, summed), and each did dispatch to its device
+  inside the window (``window_without_dispatch``: how many did not);
 - Tusk: each replica's recorded commit sequence is what the plain rule
   makes of the certificates it was given, in the order it was given
   them, and every sequence is a prefix of the longest
@@ -95,10 +98,13 @@ class Artifacts:
     due: list                                # joins.Sample, due in the window
     sample_worker: Dict[int, int]            # client index -> worker id
     batch_of: Dict[int, Optional[bytes]]     # sample id -> digest of its batch
-    forged_sent: int
-    invalid_signatures: int                  # primary 0's counter at the end
-    device: Optional[dict]                   # crypto.verify.device, or None
-    window_dispatches: Optional[int]         # primary 0's, inside the window
+    # One entry per primary that was sent forgeries (those the
+    # configuration puts on a chip):
+    forged_sent: List[int]                   # forgeries it acknowledged
+    invalid_signatures: List[int]            # its counter at the end
+    # One entry per device-backed primary, or None where there is none:
+    device: Optional[List[dict]]             # its crypto.verify.device
+    window_dispatches: Optional[List[int]]   # its dispatches inside the window
     # A control can stand in other bytes for a stored batch.
     store_overrides: Dict[Tuple[int, int, bytes], Optional[bytes]] = field(
         default_factory=dict
@@ -235,22 +241,33 @@ def compare(art: Artifacts) -> Dict[str, int]:
         "samples_unanswered": unanswered,
         "samples_misread": misread,
         "certificates_invalid": invalid,
-        "verifier_reject_gap": abs(art.invalid_signatures - art.forged_sent),
+        "verifier_reject_gap": sum(
+            abs(counted - sent)
+            for counted, sent in zip(art.invalid_signatures, art.forged_sent)
+        ),
         "replica_order_mismatches": mismatches,
     }
     if art.device is not None:
-        d = art.device
-        off = sum(
-            n for shape, n in d.get("dispatched", {}).items()
-            if int(shape) not in d.get("rungs", [])
+        numbers["device_off_ladder"] = sum(off_ladder(d) for d in art.device)
+        numbers["window_without_dispatch"] = sum(
+            not n for n in art.window_dispatches
         )
-        built_late = (
-            d.get("programs_built", 0) - d["programs_at_ready"]
-            if d.get("programs_at_ready") is not None else 1
-        )
-        numbers["device_off_ladder"] = off + max(0, built_late)
-        numbers["window_without_dispatch"] = int(not art.window_dispatches)
     return numbers
+
+
+def off_ladder(d: dict) -> int:
+    """Dispatches of one verifier at a shape outside its warmed ladder,
+    plus the programs it built after it said ready (1 where it never
+    said)."""
+    off = sum(
+        n for shape, n in d.get("dispatched", {}).items()
+        if int(shape) not in d.get("rungs", [])
+    )
+    built_late = (
+        d.get("programs_built", 0) - d["programs_at_ready"]
+        if d.get("programs_at_ready") is not None else 1
+    )
+    return off + max(0, built_late)
 
 
 def verdict(numbers: Dict[str, int]) -> bool:

@@ -5,12 +5,15 @@ the configuration states, in the artifacts of a run, where the program
 would have produced the wrong answer:
 
 - ``quorum_short``: a certificate that entered primary 0's commit rule
-  loses one vote (guarantee: 2f+1 votes a certificate);
+  is left with 2f votes (guarantee: 2f+1 votes a certificate; with all
+  four up a certificate may carry four, so "one vote less" is not short);
 - ``forged_vote``: one vote of such a certificate has a bit of its
   signature flipped (the verifier's answer altered where it is produced:
   it accepted a signature that does not verify);
-- ``verifier_accepts_all``: primary 0 counted no invalid signature (a
-  verifier that returns an all-true mask);
+- ``verifier_accepts_all``: ONE of the primaries that were sent
+  forgeries counted no invalid signature (a verifier that returns an
+  all-true mask; with a chip under every primary the other three still
+  reject theirs, and the one has to show);
 - ``order_swapped``: two neighbouring commits of the last replica change
   places (guarantee: every replica the same order);
 - ``commit_withheld``: the last replica's commit sequence stops before
@@ -51,7 +54,7 @@ def quorum_short(art, rng):
     out = _copy(art)
     i = _pick_insert(art, rng)
     cert = decode_certificate(out.audits[0][i][1], art.sorted_keys)
-    cert.votes = cert.votes[:-1]
+    cert.votes = cert.votes[:art.quorum - 1]
     out.audits[0][i] = (b"I", encode_certificate(cert, art.sorted_keys))
     return out
 
@@ -67,7 +70,9 @@ def forged_vote(art, rng):
 
 
 def verifier_accepts_all(art, rng):
-    return _copy(art, invalid_signatures=0)
+    counted = list(art.invalid_signatures)
+    counted[rng.randrange(len(counted))] = 0
+    return _copy(art, invalid_signatures=counted)
 
 
 def order_swapped(art, rng):

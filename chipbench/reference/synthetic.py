@@ -37,6 +37,7 @@ def make_run(tmpdir: str, seed: int, config: dict, rounds: int = 24,
     ids = make_identities(seed, config)
     alive = config["nodes"] - config["faults"]
     live = ids[:alive]
+    verifiers = len(config.get("chip_primaries", [0]))
     keys = sorted(i.name for i in ids)
     gc_depth = config["parameters"]["gc_depth"]
 
@@ -97,8 +98,9 @@ def make_run(tmpdir: str, seed: int, config: dict, rounds: int = 24,
         ],
         stores=indexes, due=due,
         sample_worker={c: 0 for c in range(alive)}, batch_of=batch_of,
-        forged_sent=forged, invalid_signatures=forged,
-        device={"rungs": [128, 512], "dispatched": {"128": 40},
-                "programs_built": 2, "programs_at_ready": 2},
-        window_dispatches=40,
+        forged_sent=[forged] * verifiers, invalid_signatures=[forged] * verifiers,
+        device=[{"rungs": [128, 512], "dispatched": {"128": 40},
+                 "programs_built": 2, "programs_at_ready": 2}
+                for _ in range(verifiers)],
+        window_dispatches=[40] * verifiers,
     )

@@ -4,7 +4,8 @@
     python chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 One process tree per run: set up a committee as the product starts it
-(primary 0 on the chip, the rest OpenSSL), warm up, measure for
+(the configuration's ``chip_primaries`` each on a chip of its own, the
+rest OpenSSL), warm up, measure for
 ``--seconds``, drain, tear down, hold what the run produced against the
 plain reference, print one JSON line.  This parent never imports JAX
 while a child needs the chip.  No chip is an error: the run exits
@@ -116,14 +117,13 @@ def all_due_committed(com: Committee, t0: float, seconds: float) -> bool:
     return True
 
 
-def wait_rejections(com: Committee, forged: int, deadline_s: float) -> None:
-    """Until primary 0 has counted every forgery it acknowledged.  The
-    last ones may still be in its queue, and primary 0 now and then
+def wait_rejections(com: Committee, node: int, forged: int, deadline: float) -> None:
+    """Until primary ``node`` has counted every forgery it acknowledged.
+    The last ones may still be in its queue, and a primary now and then
     stands still for a second or two (PERF.md, Open questions), so no
     fixed sleep is long enough; a verifier that accepts them never gets
     there, and the comparison says so after the deadline."""
-    deadline = time.time() + deadline_s
-    port = com.primary_metrics_port(0)
+    port = com.primary_metrics_port(node)
     while time.time() < deadline:
         com.check_alive()
         try:
@@ -135,7 +135,25 @@ def wait_rejections(com: Committee, forged: int, deadline_s: float) -> None:
         if counted >= forged:
             return
         time.sleep(0.1)
-    say(f"primary 0 counted fewer rejections than the {forged} forgeries sent")
+    say(f"primary {node} counted fewer rejections than the {forged} forgeries sent")
+
+
+def start_forgers(com: Committee, workload: dict, seed: int) -> dict:
+    """launch index -> Forger: one for each primary the configuration
+    puts on a chip, the workload's ``forged_per_s`` shared evenly among
+    them, so that every chip's verifier has its rejections on the timed
+    path."""
+    forgers = {}
+    if workload.get("forged_per_s"):
+        share = workload["forged_per_s"] / len(com.forged_nodes)
+        for k, node in enumerate(com.forged_nodes):
+            forgers[node] = Forger(
+                com.authority(node)["primary"]["primary_to_primary"], com.ids,
+                com.alive, sorted(i.name for i in com.ids), share, seed + k,
+                target=node,
+            )
+            forgers[node].start()
+    return forgers
 
 
 def drive(com: Committee, args, workload: dict, harness: dict) -> dict:
@@ -143,14 +161,7 @@ def drive(com: Committee, args, workload: dict, harness: dict) -> dict:
     is still up (the caller tears it down)."""
     com.start_nodes()
     com.start_clients(args.seed, workload)
-    forger = None
-    if workload.get("forged_per_s"):
-        forger = Forger(
-            com.authority(0)["primary"]["primary_to_primary"], com.ids,
-            com.alive, sorted(i.name for i in com.ids),
-            workload["forged_per_s"], args.seed,
-        )
-        forger.start()
+    forgers = start_forgers(com, workload, args.seed)
     wait_warm(com, harness["warmup_deadline_s"])
     time.sleep(harness["settle_s"])
     com.check_alive()
@@ -188,13 +199,18 @@ def drive(com: Committee, args, workload: dict, harness: dict) -> dict:
             say("drain: samples still uncommitted after the longest wait")
             break
         time.sleep(1.0)
-    forged = 0
-    if forger is not None:
+    for forger in forgers.values():
         forger.stop()
+    for node, forger in forgers.items():
         if forger.error is not None or forger.is_alive():
-            raise RunFailure(f"forger failed: {forger.error}")
-        forged = len(forger.sent)
-        wait_rejections(com, forged, harness["reject_count_wait_s"])
+            raise RunFailure(f"forger of primary {node} failed: {forger.error}")
+    # Without forgers each of those primaries still has to have counted
+    # what it was sent: nothing.
+    forged = {node: 0 for node in com.forged_nodes}
+    forged.update((node, len(forger.sent)) for node, forger in forgers.items())
+    deadline = time.time() + harness["reject_count_wait_s"]
+    for node in forgers:
+        wait_rejections(com, node, forged[node], deadline)
     return {
         "t0": t0, "seconds": float(args.seconds), "setup_s": setup_s,
         "scrape0": scrape0, "scrape1": scrape1, "forged_sent": forged,
@@ -252,19 +268,50 @@ def gather(com: Committee, facts: dict) -> dict:
             if node.startswith("worker") and "bytes" in e:
                 batch_bytes[d] = e["bytes"]
     run["commit_time"], run["batch_bytes"] = commit_time, batch_bytes
-    run["device_detail"] = snapshots["primary-0"].get("detail", {}).get(
-        "crypto.verify.device"
-    )
+    # What each device-backed primary's verifier says of itself, by
+    # launch index; `device_detail` is primary 0's (the readers' name).
+    run["device_details"] = {
+        i: snapshots[f"primary-{i}"].get("detail", {}).get("crypto.verify.device")
+        for i in com.chip_nodes
+    }
+    run["device_detail"] = run["device_details"].get(0)
     return run
 
 
-def window_dispatches(run: dict):
+def window_dispatches(run: dict, node: int = 0):
     series = "crypto.verify.device_seconds.batch_burst"
-    h1 = run["scrape1"]["primary-0"].get("histograms", {}).get(series)
-    h0 = run["scrape0"]["primary-0"].get("histograms", {}).get(series)
+    h1 = run["scrape1"][f"primary-{node}"].get("histograms", {}).get(series)
+    h0 = run["scrape0"][f"primary-{node}"].get("histograms", {}).get(series)
     if h1 is None:
         return 0
     return h1["count"] - (h0["count"] if h0 else 0)
+
+
+def device_of(com: Committee, run: dict) -> dict:
+    """The run's ``device``: primary 0's report of its own chip, with
+    ``count`` the SUM over the device nodes (each process sees one chip
+    where several hold one) and ``memory_peak_bytes`` that of the
+    fullest.  Each node's verifier has to have run on the device its
+    own process saw, and all on one kind."""
+    reports = {}
+    for i in com.chip_nodes:
+        reports[i] = json.loads(read(com.path(f"device-node-{i}.json")))
+        detail = run["device_details"][i] or {}
+        seen = tuple(reports[i][k] for k in ("platform", "kind", "count"))
+        if tuple(detail.get(k) for k in ("platform", "kind", "count")) != seen:
+            raise RunFailure(
+                f"primary {i}'s verifier ran on {detail}, its process saw {reports[i]}")
+    if len(reports) > 1 and any(r["count"] != 1 for r in reports.values()):
+        raise RunFailure(f"a device node saw more than its own chip: {reports}")
+    first = reports[com.chip_nodes[0]]
+    if any((r["platform"], r["kind"]) != (first["platform"], first["kind"])
+           for r in reports.values()):
+        raise RunFailure(f"device nodes on different devices: {reports}")
+    return dict(
+        first,
+        count=sum(r["count"] for r in reports.values()),
+        memory_peak_bytes=max(r["memory_peak_bytes"] for r in reports.values()),
+    )
 
 
 def artifacts(com: Committee, run: dict, workload: dict, on_device: bool):
@@ -283,12 +330,16 @@ def artifacts(com: Committee, run: dict, workload: dict, on_device: bool):
             for i in range(com.alive) for w in range(com.workers)
         },
         batch_of=run["batch_of"],
-        forged_sent=run["forged_sent"],
-        invalid_signatures=run["snapshots"]["primary-0"]["counters"].get(
-            "primary.invalid_signatures", 0
-        ),
-        device=run["device_detail"] if on_device else None,
-        window_dispatches=window_dispatches(run) if on_device else None,
+        forged_sent=list(run["forged_sent"].values()),
+        invalid_signatures=[
+            run["snapshots"][f"primary-{i}"]["counters"].get(
+                "primary.invalid_signatures", 0)
+            for i in run["forged_sent"]
+        ],
+        device=[run["device_details"][i] or {} for i in com.chip_nodes]
+        if on_device else None,
+        window_dispatches=[window_dispatches(run, i) for i in com.chip_nodes]
+        if on_device else None,
     )
 
 
@@ -349,6 +400,10 @@ def main(argv=None) -> int:
     harness = load_json("harness.json")
     if args.rate is not None:
         workload = dict(workload, rate=args.rate)
+    if workload["chips"] != len(config["chip_primaries"]):
+        say("chipbench: a cell takes as many chips as its configuration's "
+            "chip_primaries names")
+        return 2
     backend = {None: "tpu", "openssl": None, "jax": "jax"}[args.rehearse]
     on_device = backend is not None
 
@@ -365,12 +420,7 @@ def main(argv=None) -> int:
         device = {"platform": "cpu", "kind": "rehearsal", "count": 0,
                   "memory_peak_bytes": 0}
         if on_device:
-            device = json.loads(read(com.path("device-node.json")))
-            detail = run["device_detail"] or {}
-            if (detail.get("platform"), detail.get("kind"), detail.get("count")) != (
-                device["platform"], device["kind"], device["count"]
-            ):
-                raise RunFailure(f"verifier ran on {detail}, the process saw {device}")
+            device = device_of(com, run)
         if not args.rehearse and (
             device["platform"] != "tpu" or device["count"] < workload["chips"]
         ):
@@ -383,8 +433,9 @@ def main(argv=None) -> int:
         art = artifacts(com, run, workload, on_device)
         numbers = check.compare(art)
         correct = check.verdict(numbers)
-        say(f"reference took {time.time() - t_ref:.1f} s; {art.forged_sent} forged "
-            f"headers sent, primary 0 counted {art.invalid_signatures} invalid signatures")
+        say(f"reference took {time.time() - t_ref:.1f} s; forged headers sent to "
+            f"primaries {list(run['forged_sent'])}: {art.forged_sent}, invalid "
+            f"signatures they counted: {art.invalid_signatures}")
 
         if args.controls:
             from reference import control

@@ -37,6 +37,7 @@ def test_workload_file(path):
     cfg = load(os.path.join(CHIPBENCH, "configs", w["config"] + ".json"))
     assert w["clients"] == (cfg["nodes"] - cfg["faults"]) * cfg["workers"]
     assert w["chips"] in (1, 4) and w["arrival"] == "steady"
+    assert w["chips"] == len(cfg["chip_primaries"])
 
 
 @pytest.mark.parametrize("path", files("configs"), ids=os.path.basename)
@@ -44,13 +45,34 @@ def test_config_file(path):
     c = load(path)
     assert c["name"] + ".json" == os.path.basename(path)
     assert len(c["dead_key_ranks"]) == c["faults"]
-    assert c["nodes"] - c["faults"] == 2 * c["nodes"] // 3 + 1  # exactly a quorum
+    # Nothing commits without an on-chip verifier: the live primaries
+    # that hold no chip are fewer than a quorum.
+    alive = c["nodes"] - c["faults"]
+    holders = c["chip_primaries"]
+    assert holders == sorted(set(holders)) and all(0 <= i < alive for i in holders)
+    assert alive >= 2 * c["nodes"] // 3 + 1 > alive - len(holders)
     assert set(c["parameters"]) == {
         "header_size", "max_header_delay", "min_header_delay", "header_linger",
         "gc_depth", "sync_retry_delay", "sync_retry_nodes", "batch_size",
         "max_batch_delay"}
-    for key in ("source", "guarantees", "assumed", "reduced", "message_delay"):
+    for key in ("source", "guarantees", "assumed", "reduced", "message_delay",
+                "chip_mapping"):
         assert key in c
+
+
+def test_configurations_keep_apart():
+    """Port blocks do not meet (a checkout's shift is the same for all),
+    sources differ, and the guarantees are one text."""
+    cfgs = [load(p) for p in files("configs")]
+    blocks = []
+    for c in cfgs:
+        n, w = c["nodes"], c["workers"]
+        blocks.append(set(range(c["base_port"], c["base_port"] + n * (2 + 3 * w) + n + n * w)))
+    for i, a in enumerate(blocks):
+        assert max(a) - min(a) < 128
+        assert all(not a & b for b in blocks[i + 1:])
+    assert len({c["source"] for c in cfgs}) == len(cfgs)
+    assert all(c["guarantees"] == cfgs[0]["guarantees"] for c in cfgs)
 
 
 @pytest.mark.parametrize("path", files("layer_metrics"), ids=os.path.basename)
