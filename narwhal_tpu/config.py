@@ -191,20 +191,22 @@ class Parameters:
     # max_header_delay waiting for header_size bytes of digests.  Empty
     # rounds still wait for max_header_delay (an idle committee must not
     # spin headers at wire speed).  0 (the default) disables the fast
-    # cadence and keeps the reference behavior bit-for-bit.
+    # cadence and keeps the reference's timers.
     min_header_delay: int = 0
     # Parent-linger window: when > 0, a proposer whose round just advanced
-    # holds the next header open for this many milliseconds so parent
-    # certificates arriving AFTER the round-advance quorum still get cited
-    # (the Core forwards post-quorum certificates while the window is
-    # open).  Without it a header's parents are exactly the FIRST 2f+1
-    # certificates of the round, which leaves commit-rule slot support
-    # sitting at the quorum borderline (the multileader rule's motivating
-    # measurement — see consensus/tusk.py::MultiLeaderTusk).  Price it off
-    # the measured consensus.support_arrival_ms headroom: a linger of
-    # roughly that spread converts borderline support rounds into direct
-    # commits.  max_header_delay still caps every round; 0 (the default)
-    # disables the window and keeps the reference behavior bit-for-bit.
+    # holds the fast mint paths (payload-ready, full header) for this many
+    # milliseconds, so that more certificates of the parent round are in
+    # hand when the header is minted.  It only HOLDS: what a header cites
+    # does not depend on it (every certificate of the parent round in
+    # hand at the mint, whatever this says — primary/proposer.py,
+    # PARITY.md "Departures").  Where headers are minted before the
+    # stragglers land, commit-rule slot support sits at the quorum
+    # borderline (the multileader rule's motivating measurement — see
+    # consensus/tusk.py::MultiLeaderTusk).  Price it off the measured
+    # consensus.support_arrival_ms headroom: a linger of roughly that
+    # spread converts borderline support rounds into direct commits.
+    # max_header_delay still caps every round; 0 (the default) holds
+    # nothing, and every header rides its timers as in the reference.
     header_linger: int = 0
     # Depth of garbage collection, in rounds.
     gc_depth: int = 50

@@ -687,15 +687,17 @@ class MultiLeaderTusk(Tusk):
     One leader per even round leaves the commit cadence hostage to one
     validator's support-arrival luck: the lowdepth rule's 2.05× win at
     N=4 collapses to ~1.0–1.3× at N=10/20 because a header's parents
-    are exactly the FIRST 2f+1 certificates of the round (the
-    round-advance quorum), so each round-(L+1) certificate cites the
-    round-L leader with probability ≈ 2/3 and the leader's direct
-    support hovers AT the quorum line (artifacts/commit_rule_ab_r20.json
-    caveat).  This rule gives every even round K = ``MULTILEADER_SLOTS``
-    leader slots (schedule: :func:`leader_slots`) so any supported slot
-    can anchor the round's commit, and pairs with the Proposer's
-    ``header_linger_ms`` knob, which widens parent sets past the bare
-    quorum so slot support stops being borderline.
+    were exactly the FIRST 2f+1 certificates of the round (the
+    round-advance quorum; as measured for
+    artifacts/commit_rule_ab_r20.json, before a header cited every
+    certificate in hand at its mint), so each round-(L+1) certificate
+    cites the round-L leader with probability ≈ 2/3 and the leader's
+    direct support hovers AT the quorum line.  This rule gives every
+    even round K = ``MULTILEADER_SLOTS`` leader slots (schedule:
+    :func:`leader_slots`) so any supported slot can anchor the round's
+    commit, and pairs with the Proposer's ``header_linger_ms`` knob,
+    which holds the fast mint paths so that more of the parent round's
+    certificates are in hand and slot support stops being borderline.
 
     Decision rules (all pure functions of the DAG, which is what makes
     the commit sequence a cross-node-consistent prefix — the same

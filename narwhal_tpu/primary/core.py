@@ -142,11 +142,13 @@ class Core:
                 "(Proposer.deliver_parents) or a tx_proposer queue"
             )
         self.parents_cb = parents_cb
-        # Post-quorum parent forwarding (the proposer's header_linger
-        # window): a FRESH certificate of a round whose 2f+1 parent list
-        # already went out is offered to the Proposer as a late parent.
-        # Only wired when the linger is on — with no window open the
-        # callback would be pure per-certificate overhead.
+        # Post-quorum parent forwarding: a FRESH certificate of a round
+        # whose 2f+1 parent list already went out is offered to the
+        # Proposer as a late parent, so that a header cites every
+        # certificate of its parent round in hand when it is minted
+        # (Proposer.deliver_late_parent drops what comes too late).
+        # Primary always wires it; harnesses that run a Core alone may
+        # leave it out.
         self.late_parents_cb = late_parents_cb
         # Rounds whose parent quorum has emitted (Dict so the _gc_sweep
         # map loop collects it like the other per-round state).
@@ -610,7 +612,7 @@ class Core:
             and certificate.round in self._parents_emitted
         ):
             # Quorum already emitted for this round: a fresh straggler
-            # can still be cited if the proposer's linger window is open.
+            # is still cited if the proposer has not minted yet.
             self.late_parents_cb(certificate.digest(), certificate.round)
 
         await self.tx_consensus.put(certificate)

@@ -207,6 +207,7 @@ class Primary:
             benchmark=benchmark,
             min_header_delay_ms=parameters.min_header_delay,
             header_linger_ms=parameters.header_linger,
+            gc_depth=parameters.gc_depth,
         )
         core = core_cls(
             *extra,
@@ -223,17 +224,16 @@ class Primary:
             rx_proposer=tx_own_headers,
             tx_consensus=tx_consensus,
             parents_cb=proposer.deliver_parents,
-            # Late-parent forwarding only matters while a linger window
-            # can be open; leave it unwired otherwise so the post-quorum
-            # certificate path stays zero-cost.
-            late_parents_cb=(
-                proposer.deliver_late_parent
-                if parameters.header_linger > 0
-                else None
-            ),
+            # Whatever header_linger says: a header cites every
+            # certificate of its parent round in hand at the mint.
+            late_parents_cb=proposer.deliver_late_parent,
         )
         garbage_collector = GarbageCollector(
-            name, committee, consensus_round, rx_consensus
+            name,
+            committee,
+            consensus_round,
+            rx_consensus,
+            committed_cb=proposer.deliver_commit,
         )
         payload_receiver = PayloadReceiver(store, rx_others_digests)
         header_waiter = HeaderWaiter(

@@ -25,21 +25,39 @@ half):
 
 - **header_linger** — when > 0, a round advance arms a linger deadline
   and the fast mint paths (payload-ready, full header) hold until it
-  passes; certificates of the just-advanced round that land AFTER the
-  2f+1 quorum are merged into the pending parent set via
-  :meth:`deliver_late_parent` (the Core forwards them while the round is
-  current).  Without it every header cites exactly the FIRST 2f+1
-  certificates of its round, so each commit-rule leader slot is cited
-  with probability ≈ 2/3 and slot support hovers at the quorum
-  borderline.  max_header_delay still caps the round; 0 disables the
-  window and keeps prior behavior bit-for-bit.
+  passes, so that more of the parent round's certificates are in hand
+  when the header is minted.  max_header_delay still caps the round; 0
+  holds nothing.  The knob only HOLDS: what a header cites does not
+  depend on it (next paragraph).
+
+Two departures from the reference that no knob switches (ISSUE 29,
+PARITY.md "Departures"; with all n validators up the reference leaves part
+of what it is sent uncommitted, its own Quick Start reads 46,478 of
+50,000 tx/s):
+
+- **parents in hand at mint**: a header cites EVERY certificate of its
+  parent round that this primary holds when the header is minted, not
+  the first 2f+1 alone.  The round still advances at the first 2f+1,
+  once (CertificatesAggregator); the Core offers each later certificate
+  of that round to :meth:`deliver_late_parent` while the parent set is
+  unconsumed.  Nothing is held back for it and no timer moves.  A header
+  must cite AT LEAST 2f+1 certificates; citing the first 2f+1 only
+  leaves the certificate that is last everywhere cited by nobody, and
+  Tusk never reaches it.
+- **re-proposal**: the payload of each own header is kept by round until
+  it is settled.  :meth:`deliver_commit` is told the committed sequence
+  (GarbageCollector).  An own round r below a committed own round can
+  never commit (Tusk skips a certificate at or under its origin's last
+  committed round), nor can one under the garbage horizon; its digests
+  go back to the front of the queue and ride the next header.  What may
+  still commit is never re-proposed.
 """
 
 from __future__ import annotations
 
 import asyncio
 import logging
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from ..utils.env import env_flag
 
@@ -49,6 +67,7 @@ from .. import metrics
 from ..config import Committee, WorkerId
 from ..crypto import Digest, PublicKey, SignatureService
 from ..messages import Round
+from ..utils.clock import wall_now
 from .messages import Header, genesis
 
 log = logging.getLogger("narwhal.primary")
@@ -69,6 +88,7 @@ class Proposer:
         benchmark: bool = False,
         min_header_delay_ms: int = 0,
         header_linger_ms: int = 0,
+        gc_depth: int = 50,
     ) -> None:
         self.name = name
         self.signature_service = signature_service
@@ -106,6 +126,12 @@ class Proposer:
         self.last_parents: List[Digest] = [c.digest() for c in genesis(committee)]
         self.digests: List[Tuple[Digest, WorkerId]] = []
         self.payload_size = 0
+        # Own headers not yet settled: round -> payload as proposed, in
+        # round order (one header a round, rounds only rise).  An entry
+        # leaves when its round commits or when deliver_commit finds it
+        # can no longer commit, so at most ~gc_depth rounds are kept.
+        self.gc_depth = gc_depth
+        self._unsettled: Dict[Round, List[Tuple[Digest, WorkerId]]] = {}
         # Set by deliver_parents (the Core's direct, queue-skipping path)
         # to nudge the run loop out of its queue wait.
         self._wake = asyncio.Event()
@@ -114,6 +140,16 @@ class Proposer:
         self._linger_deadline = 0.0
         self._m_headers = metrics.counter("primary.headers_proposed")
         self._m_late_parents = metrics.counter("primary.late_parents_cited")
+        # Parents per minted header: 2f+1 is the first quorum alone, n
+        # every certificate of the parent round.
+        self._m_header_parents = metrics.histogram(
+            "primary.header_parents", metrics.COUNT_BUCKETS
+        )
+        self._m_orphaned = metrics.counter("primary.own_headers_orphaned")
+        # Digests re-queued per orphaned header that carried any.
+        self._m_reproposed = metrics.histogram(
+            "primary.payload_reproposed", metrics.COUNT_BUCKETS
+        )
         self._m_payload_digests = metrics.counter("primary.payload_digests")
         self._m_round = metrics.gauge("primary.round")
         # Round period: seconds between consecutive round advances.  The
@@ -141,11 +177,10 @@ class Proposer:
 
     def deliver_late_parent(self, digest: Digest, round: Round) -> None:
         """Merge a post-quorum certificate of the CURRENT round's parent
-        round into the pending parent set (Core forwards these only while
-        a linger window can still be open).  A stale round, an
-        already-consumed parent set, or a duplicate digest are all
-        silently dropped — the certificate is already in the DAG either
-        way, this only widens the citation."""
+        round into the pending parent set (the Core offers every fresh
+        one).  A stale round, an already-consumed parent set, or a
+        duplicate digest are all silently dropped — the certificate is
+        already in the DAG either way, this only widens the citation."""
         if round + 1 != self.round or not self.last_parents:
             return
         if digest in self.last_parents:
@@ -154,6 +189,46 @@ class Proposer:
         self._m_late_parents.inc()
         if _TRACE:
             log.info("TRACE late parent cited %r for round %d", digest, self.round)
+
+    def deliver_commit(self, round: Round, own: bool) -> None:
+        """One certificate of the committed sequence, in commit order
+        (GarbageCollector).  Settles the own round it commits and
+        re-queues the payload of every kept own round that can no longer
+        commit: one below a committed own round (order_dag skips a
+        certificate at or under its origin's last committed round), or
+        one under the garbage horizon (``State.gc``'s predicate).  The
+        rule is chipbench/reference/orphans.py's."""
+        if own:
+            self._unsettled.pop(round, None)
+        below = round if own else round - self.gc_depth
+        orphaned = [r for r in self._unsettled if r < below]
+        if not orphaned:
+            return
+        requeued: List[Tuple[Digest, WorkerId]] = []
+        for r in orphaned:
+            payload = self._unsettled.pop(r)
+            self._m_orphaned.inc()
+            if payload:
+                self._m_reproposed.observe(len(payload))
+                requeued += payload
+        if not requeued:
+            return
+        # First-wins marks: `header` keeps the first header's stamp and
+        # `reproposed` the first re-queue's, so it lies between that
+        # header and the one the digest rides next.
+        now = wall_now()
+        for digest, _ in requeued:
+            self._mtrace.mark(bytes(digest).hex(), "header", reproposed=now)
+        metrics.flight_event(
+            "payload_reproposed",
+            rounds=orphaned,
+            digests=len(requeued),
+            settled_by=round,
+        )
+        log.debug("Re-proposing %d digests of rounds %s", len(requeued), orphaned)
+        self.digests = requeued + self.digests
+        self.payload_size += sum(len(d) for d, _ in requeued)
+        self._wake.set()
 
     def _advance(self, parents: List[Digest], round: Round) -> bool:
         """Apply a parent quorum for ``round``; returns True if the round
@@ -177,13 +252,16 @@ class Proposer:
 
     async def _make_header(self) -> None:
         payload = dict(self.digests)
+        self._unsettled[self.round] = self.digests
         self.digests = []
+        self.payload_size = 0
         parents, self.last_parents = self.last_parents, []
         header = await Header.new(
             self.name, self.round, payload, parents, self.signature_service
         )
         log.debug("Created %r", header)
         self._m_headers.inc()
+        self._m_header_parents.observe(len(parents))
         self._m_payload_digests.inc(len(payload))
         self._rtrace.mark(str(header.round), "header_proposed")
         for digest in payload:
@@ -212,10 +290,12 @@ class Proposer:
                 now = loop.time()
                 timer_expired = now >= deadline
                 min_expired = now >= min_deadline
+                # lint: allow-interleave(digests/payload_size ARE written mid-mint by deliver_commit, the GarbageCollector's synchronous callback, while _make_header awaits Header.new — safely: _make_header took the queue into locals and zeroed both before its first yield, deliver_commit only PREPENDS to the queue and adds the same bytes in one sync block, and every loop iteration re-reads both fresh before the next mint decision)
                 enough_digests = self.payload_size >= self.header_size
                 # "Ready" payload: a full header, or — with the min-delay
                 # cadence enabled — any payload at all.
                 ready = enough_digests or (
+                    # lint: allow-interleave(same window as payload_size above)
                     self.min_header_delay > 0 and bool(self.digests)
                 )
                 # The linger window holds the fast paths only; the max
@@ -225,7 +305,6 @@ class Proposer:
                     timer_expired or (min_expired and linger_ok and ready)
                 ):
                     await self._make_header()
-                    self.payload_size = 0
                     now = loop.time()
                     deadline = now + self.max_header_delay
                     min_deadline = now + self.min_header_delay

@@ -362,3 +362,155 @@ def test_header_linger_clamped_to_max(run):
         assert p.header_linger == p.max_header_delay == 0.1
 
     run(go())
+
+
+def test_late_parent_cited_without_linger_and_mint_not_held(run):
+    """header_linger 0 (the source's): a post-quorum certificate that is
+    in hand when the header is minted is cited, and nothing is held for
+    it — the timer-bound mint falls at the timer, and the payload-ready
+    mint goes out at once and drops what comes after it."""
+
+    async def go():
+        c = committee()
+        kp = keys()[0]
+        loop = asyncio.get_running_loop()
+        parents = [digest32(bytes([i]) * 3) for i in range(3)]
+        late = digest32(b"the fourth certificate")
+
+        # Timer-bound: empty payload, minted at max_header_delay.
+        p, _, _, tx_core = make_proposer(c, kp, delay_ms=200)
+        assert p.header_linger == 0
+        task = asyncio.ensure_future(p.run())
+        first = await asyncio.wait_for(tx_core.get(), 5)
+        t0 = loop.time()
+        p.deliver_parents(parents, first.round)
+        p.deliver_late_parent(late, first.round)
+        second = await asyncio.wait_for(tx_core.get(), 5)
+        assert 0.15 <= loop.time() - t0 < 0.4  # the timer, no later
+        assert second.parents == set(parents) | {late}
+        task.cancel()
+
+        # Payload-ready: minted as soon as parents and payload meet.
+        p, _, rx_workers, tx_core = make_proposer(
+            c, kp, header_size=16, delay_ms=60_000
+        )
+        task = asyncio.ensure_future(p.run())
+        await rx_workers.put((digest32(b"a"), 0))
+        first = await asyncio.wait_for(tx_core.get(), 5)
+        await rx_workers.put((digest32(b"b"), 0))
+        await asyncio.sleep(0.02)
+        t0 = loop.time()
+        p.deliver_parents(parents, first.round)
+        second = await asyncio.wait_for(tx_core.get(), 5)
+        assert loop.time() - t0 < 0.1  # not held
+        assert second.parents == set(parents)
+        p.deliver_late_parent(late, first.round)  # consumed set: dropped
+        assert p.last_parents == []
+        task.cancel()
+
+    run(go())
+
+
+def test_header_parents_histogram_and_late_counter_count_always(run):
+    """primary.header_parents observes every minted header's parent
+    count and primary.late_parents_cited counts with header_linger 0."""
+    from narwhal_tpu import metrics
+
+    async def go():
+        c = committee()
+        kp = keys()[0]
+        p, _, _, tx_core = make_proposer(c, kp, delay_ms=30)
+        hist = metrics.registry().histograms["primary.header_parents"]
+        cited = metrics.registry().counters["primary.late_parents_cited"]
+        count0, sum0, cited0 = hist.count, hist.sum, cited.value
+        task = asyncio.ensure_future(p.run())
+        first = await asyncio.wait_for(tx_core.get(), 5)  # 4 genesis parents
+        p.deliver_parents([digest32(bytes([i]) * 3) for i in range(3)], 1)
+        p.deliver_late_parent(digest32(b"late"), 1)
+        await asyncio.wait_for(tx_core.get(), 5)
+        task.cancel()
+        assert len(first.parents) == 4
+        assert hist.count - count0 == 2 and hist.sum - sum0 == 8
+        assert cited.value - cited0 == 1
+
+    run(go())
+
+
+async def mint(p, tx_core, round_, payload):
+    """One own header of ``round_`` with ``payload``, minted directly."""
+    p.round = round_
+    p.last_parents = [digest32(b"parent")]
+    p.digests = list(payload)
+    p.payload_size = 32 * len(payload)
+    await p._make_header()
+    header = tx_core.get_nowait()
+    assert header.round == round_ and header.payload == dict(payload)
+    return header
+
+
+def test_own_commit_settles_and_reproposes_what_it_skipped(run):
+    """Own rounds 1-3 proposed; round 3 commits first: rounds 1 and 2 can
+    never commit (Tusk skips at or under the origin's last committed
+    round), so their digests go back to the FRONT in their old order,
+    are counted, marked and logged to the flight ring, and ride the next
+    header.  A second report changes nothing."""
+    from narwhal_tpu import metrics
+
+    async def go():
+        c = committee()
+        kp = keys()[0]
+        p, _, _, tx_core = make_proposer(c, kp)
+        reg = metrics.registry()
+        orphaned0 = reg.counters["primary.own_headers_orphaned"].value
+        hist = reg.histograms["primary.payload_reproposed"]
+        count0, sum0 = hist.count, hist.sum
+        a, b, d, e, f = (digest32(bytes([i]) * 5) for i in range(5))
+        await mint(p, tx_core, 1, [(a, 0), (b, 0)])
+        await mint(p, tx_core, 2, [])  # an empty header: nothing to carry
+        await mint(p, tx_core, 3, [(d, 0)])
+        await mint(p, tx_core, 4, [(e, 1)])
+        p.digests, p.payload_size = [(f, 0)], 32
+        p.deliver_commit(2, False)  # a peer's certificate: settles nothing
+        assert sorted(p._unsettled) == [1, 2, 3, 4]
+        p.deliver_commit(3, True)
+        assert sorted(p._unsettled) == [4]  # may still commit: kept
+        assert p.digests == [(a, 0), (b, 0), (f, 0)]
+        assert p.payload_size == 96
+        assert reg.counters["primary.own_headers_orphaned"].value - orphaned0 == 2
+        assert (hist.count - count0, hist.sum - sum0) == (1, 2)
+        entry = reg.trace.entries[bytes(a).hex()]
+        assert entry["header"] <= entry["reproposed"]
+        assert "reproposed" not in reg.trace.entries[bytes(d).hex()]
+        event = [
+            ev for ev in reg.flight.events
+            if ev["kind"] == "payload_reproposed"
+        ][-1]
+        assert event["rounds"] == [1, 2] and event["digests"] == 2
+        p.deliver_commit(3, True)
+        assert p.digests == [(a, 0), (b, 0), (f, 0)]
+        header = await mint(p, tx_core, 5, p.digests)
+        assert list(header.payload) == [a, b, f]
+        # The second `header` stamp does not move the first.
+        assert reg.trace.entries[bytes(a).hex()]["header"] == entry["header"]
+
+    run(go())
+
+
+def test_garbage_horizon_orphans_without_an_own_commit(run):
+    """No own commit at all (a header that never got its certificate):
+    the kept round is re-proposed once the committed round has passed it
+    by more than gc_depth (State.gc's predicate), not before."""
+
+    async def go():
+        c = committee()
+        kp = keys()[0]
+        p, _, _, tx_core = make_proposer(c, kp)
+        assert p.gc_depth == 50
+        a = digest32(b"never certified")
+        await mint(p, tx_core, 7, [(a, 0)])
+        p.deliver_commit(57, False)  # 7 + 50 >= 57: may still commit
+        assert sorted(p._unsettled) == [7] and p.digests == []
+        p.deliver_commit(58, False)
+        assert p._unsettled == {} and p.digests == [(a, 0)]
+
+    run(go())
