@@ -12,8 +12,8 @@ Needs one TPU; there is no CPU mode.  Two phases, in this order:
   ``--seed``, with forged signatures, wrong keys, a non-canonical S and a
   small-order key planted at known positions; the on-chip mask must equal
   OpenSSL's (``crypto.keys.cpu_verify``) item for item.  A second process
-  then repeats the first calls and must find every program in the
-  persistent compile cache.
+  then repeats the first calls and must load every program whole from
+  the file the first one wrote (``ops/programs.py``): nothing traced.
 - ``committee``: the upstream local deployment through the normal entry
   points (``benchmark/local_bench.py::run_bench`` -> ``python -m
   narwhal_tpu.node run``): 4 validators, 1 worker each, 512 B
@@ -181,7 +181,7 @@ def verify_child(seed: int, report_path: str, first: bool,
 
     device = ops.device_identity()
     ladder = cb.get_backend().rungs
-    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") or ops.CACHE_DIR
+    cache = ops.program_dir()
     say(
         "verify[{}]: device {platform} / {kind} / count {count}".format(
             "first process" if first else "second process", **device
@@ -217,7 +217,8 @@ def verify_child(seed: int, report_path: str, first: bool,
             },
             **{
                 k: after[k] - before[k]
-                for k in ("programs_built", "cache_hits", "cache_misses")
+                for k in ("programs_built", "programs_from_file",
+                          "program_files_rejected", "cache_hits", "cache_misses")
             },
         }
         if first and n in ladder:
@@ -230,10 +231,10 @@ def verify_child(seed: int, report_path: str, first: bool,
                 cb.verify_batch_mask(msgs, keys, sigs, site="chip_smoke")
                 calls.append(1e3 * (time.perf_counter() - t0))
             args = [jnp.asarray(a) for a in E.prepare_batch(msgs, keys, sigs, n)]
-            kernel = []
+            program, kernel = E.verify_program(n), []
             for _ in range(20):
                 t0 = time.perf_counter()
-                np.asarray(E._verify_kernel(*args))
+                np.asarray(program(*args))
                 kernel.append(1e3 * (time.perf_counter() - t0))
             row["steady_call_ms_median"] = statistics.median(calls)
             row["steady_kernel_ms_median"] = statistics.median(kernel)
@@ -281,18 +282,22 @@ def phase_verify(seed: int) -> dict:
     first = run_child("verify-first", seed, timeout=800)
     # The child above has exited, so the chip is free again.
     second = run_child("verify-second", seed, timeout=400)
-    built = sum(s["programs_built"] for s in second["shapes"])
-    hits = sum(s["cache_hits"] for s in second["shapes"])
-    misses = sum(s["cache_misses"] for s in second["shapes"])
+    built, from_file, rejected, traced = (
+        sum(s[k] for s in second["shapes"])
+        for k in ("programs_built", "programs_from_file",
+                  "program_files_rejected", "trace_seconds")
+    )
     say(
-        f"verify: second process built {built} programs with {hits} cache "
-        f"hits / {misses} misses, first result after "
+        f"verify: second process built {built} programs, {from_file} of them "
+        f"loaded from program files ({rejected} files rejected, "
+        f"{traced} s of tracing), first result after "
         f"{second['seconds_to_first_result']} s (first process: "
         f"{first['seconds_to_first_result']} s)"
     )
     require(
-        built > 0 and hits == built and misses == 0,
-        "second process compiled instead of hitting the persistent cache",
+        built > 0 and from_file == built and traced == 0 and rejected == 0,
+        "second process traced or built a program instead of loading the "
+        "file the first process wrote",
     )
     require(first["device"] == second["device"], "device changed between children")
     return first["device"]

@@ -86,11 +86,12 @@ async def spawn_primary_node(
     loop = asyncio.get_running_loop()
     node.store = Store(store_path) if store is None else store
 
-    # If the batched verify backend is selected, build (compile or load
-    # from the persistent cache) the kernel for every rung of its pad
-    # ladder BEFORE joining the committee: one shape costs minutes to
-    # compile for the chip, which must not land on the first
-    # certificate's critical path.  The harness waits for the ready line.
+    # If the batched verify backend is selected, resolve (load from its
+    # program file, or build) the kernel for every rung of its pad ladder
+    # BEFORE joining the committee: one shape costs seconds to load and
+    # tens of seconds to build for the chip, which must not land on the
+    # first certificate's critical path.  The harness waits for the
+    # ready line.
     from ..crypto import backend as crypto_backend
 
     backend = crypto_backend.get_backend()

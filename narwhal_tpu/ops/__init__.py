@@ -16,14 +16,17 @@ import os as _os
 
 import jax as _jax
 
-# Persistent XLA compilation cache.  One verify-kernel shape costs minutes
-# to compile for the chip, and every process of a run (the prewarm child,
-# a device-backed primary, each chip_smoke phase) needs the same shapes, so
-# they must all land in ONE directory.  JAX reads JAX_COMPILATION_CACHE_DIR
-# itself: when the environment places the cache, nothing is set here.
-# Otherwise the cache lives at one fixed, git-ignored path inside the
-# checkout — never under $HOME or a temp name, so a second process of the
-# same run always finds what the first compiled.
+# One directory for everything this process compiles.  Every process of a
+# run (the prewarm child, each device-backed primary, each chip_smoke
+# phase) needs the same programs, so they must all land in ONE place.  JAX
+# reads JAX_COMPILATION_CACHE_DIR itself: when the environment places the
+# cache, nothing is set here.  Otherwise it lives at one fixed,
+# git-ignored path inside the checkout (never under $HOME or a temp
+# name), so a second process of the same run always finds what the first
+# compiled.  The directory holds JAX's persistent cache (keyed by the
+# lowered module: a hit still costs the trace and the lowering) and, as
+# `*.program`, the verify ladder's executables whole (ops/programs.py:
+# found by name and key, no trace at all).
 CACHE_DIR = _os.path.join(
     _os.path.dirname(_os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))),
     ".jax_cache",
@@ -31,13 +34,24 @@ CACHE_DIR = _os.path.join(
 if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
     _jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
 
-# Compile ledger: how many XLA programs this process built (compiled OR
-# loaded from the persistent cache — either way a jit miss that stalls its
-# caller), the seconds spent tracing/lowering/building them, and the
-# persistent cache's hits and misses.  Read by the node's ready line, the
-# `crypto.verify.device` snapshot detail and chip_smoke.py: "no compile
-# after warm-up" and "the second process hit the cache" are counted, not
-# inferred.
+
+def program_dir() -> str:
+    """The directory above, as the environment has it NOW."""
+    return _os.environ.get("JAX_COMPILATION_CACHE_DIR") or CACHE_DIR
+
+
+# Compile ledger: how many XLA programs this process built (compiled,
+# loaded from the persistent cache after a trace, or loaded whole from a
+# program file: each a miss that stalls its caller), the seconds spent
+# tracing / lowering / building them (`build_seconds` holds a program
+# file's read + deserialize + load too: what the caller waited), the
+# persistent cache's hits and misses, how many of `programs_built` came
+# from a program file without any trace (`programs_from_file`) and how
+# many program files were found and not used (`program_files_rejected`;
+# each is then built and written anew).  Read by the node's ready line,
+# the `crypto.verify.device` snapshot detail and chip_smoke.py: "no
+# compile after warm-up" and "the second process traced nothing" are
+# counted, not inferred.
 _DURATION_KEYS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace_seconds",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_seconds",
@@ -54,6 +68,8 @@ _compile_stats = {
     "build_seconds": 0.0,
     "cache_hits": 0,
     "cache_misses": 0,
+    "programs_from_file": 0,
+    "program_files_rejected": 0,
 }
 
 
@@ -73,6 +89,17 @@ def _on_event(event: str, **_kw) -> None:
 
 _jax.monitoring.register_event_duration_secs_listener(_on_duration)
 _jax.monitoring.register_event_listener(_on_event)
+
+
+def count_program_file(seconds: float, used: bool) -> None:
+    """A program file was read in ``seconds`` and served (``used``) or
+    was rejected: the caller waited either way."""
+    _compile_stats["build_seconds"] += seconds
+    if used:
+        _compile_stats["programs_built"] += 1
+        _compile_stats["programs_from_file"] += 1
+    else:
+        _compile_stats["program_files_rejected"] += 1
 
 
 def compile_stats() -> dict:

@@ -29,6 +29,7 @@ adversarial inputs (tests/test_ed25519.py).
 from __future__ import annotations
 
 import hashlib
+import threading
 import time
 from typing import Callable, List, Optional, Sequence, Tuple
 
@@ -40,7 +41,7 @@ import jax.numpy as jnp
 from ..utils.clock import wall_now
 from ..utils.devtrace import annotate, current_burst
 from ..utils.env import env_flag
-from . import compile_stats, device_identity
+from . import compile_stats, device_identity, programs
 from . import field25519 as F
 
 P = F.P
@@ -465,15 +466,17 @@ def mesh_devices() -> int:
 # -- the pad ladder -----------------------------------------------------------
 #
 # XLA compiles one program per padded batch shape.  On the chip's host one
-# shape traces and lowers in ~5.6 s in EVERY process, cache hit or not,
-# then loads from the persistent cache in ~2 s or builds cold in 17-25 s
-# (PERF.md, PR 27; before that PR the program was four times the size:
-# ~24 s, 9-16 s and 110-145 s).  So the shapes are a short fixed ladder,
-# not every power of two up to the committee's worst burst: a batch pads
-# to the smallest rung that holds it, and a batch above the top rung is
-# split into top-rung chunks.  The pad policy and the warm-up read the
-# SAME ladder, so no live burst — however large a late joiner's catch-up
-# makes it — can reach a shape that was not built before the node joined.
+# shape traces and lowers in ~5.6 s and builds cold in 17-25 s (PERF.md,
+# PR 27; before that PR the program was four times the size: ~24 s and
+# 110-145 s); a process that finds the shape's executable whole in its
+# program file (ops/programs.py, PR 30) pays its load alone, ~2 s, which
+# until then it paid for a hit in JAX's persistent cache AFTER the trace
+# and the lowering.  So the shapes are a short fixed ladder, not every
+# power of two up to the committee's worst burst: a batch pads to the
+# smallest rung that holds it, and a batch above the top rung is split
+# into top-rung chunks.  The pad policy and the warm-up read the SAME
+# ladder, so no live burst — however large a late joiner's catch-up makes
+# it — can reach a shape that was not built before the node joined.
 #
 # The chip's rungs were chosen from one reading of the old program on a
 # v5e (ms per call, prepared arrays in, mask fetched; PERF.md, PR 22): 16
@@ -498,15 +501,98 @@ CHIP_RUNGS = (128, 512)
 CPU_RUNGS = (16,)
 
 
-def dispatch_plan() -> Tuple[Callable, Tuple[int, ...]]:
-    """(kernel, pad ladder) for the platform JAX runs on: the ladder
-    ascending, each rung scaled by the mesh's device count.  A backend
-    resolves this once, at construction."""
+def kernel_args(n: int, sharding=None) -> Tuple[jax.ShapeDtypeStruct, ...]:
+    """`_verify_kernel`'s nine arrays at ``n`` rows, as shapes: what
+    `prepare_batch(..., pad_to=n)` hands over."""
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    limbs, sign, flag, windows = (
+        shape((n, F.LIMBS), jnp.int32),
+        shape((n,), jnp.int32),
+        shape((n,), jnp.bool_),
+        shape((n, 64), jnp.int32),
+    )
+    return (limbs, sign, flag, limbs, sign, flag, windows, flag, windows)
+
+
+def known_answers(n: int):
+    """(`_verify_kernel`'s arrays, the mask it must give) for ``n`` rows
+    of one valid signature with a forged row (a signature of another
+    message) and a malleable one (S + L, not canonical) among them: a
+    verifier is held to saying no, row by row, not only to saying yes."""
+    from ..crypto import KeyPair
+    from ..crypto.digest import Digest
+
+    kp = KeyPair.generate(b"\x05" * 32)
+    msg = bytes(Digest(b"\x05" * 32))
+    sig = bytes(kp.sign(Digest(msg)))
+    msgs, sigs = [msg] * n, [sig] * n
+    msgs[1] = bytes(Digest(b"\x06" * 32))
+    s_plus_l = int.from_bytes(sig[32:], "little") + L_ORDER
+    sigs[2] = sig[:32] + s_plus_l.to_bytes(32, "little")
+    expected = np.ones(n, dtype=bool)
+    expected[1:3] = False
+    return prepare_batch(msgs, [kp.name] * n, sigs, n), expected
+
+
+def wrong_answers(program: Callable, n: int) -> Optional[str]:
+    """None where ``program`` gives the known answers at ``n`` rows, else
+    which rows it got wrong."""
+    args, expected = known_answers(n)
+    mask = np.asarray(program(*(jnp.asarray(a) for a in args)))
+    if mask.shape != expected.shape:
+        return f"a mask of shape {mask.shape} for {n} rows"
+    rows = np.flatnonzero(mask != expected)
+    if not rows.size:
+        return None
+    kind = {1: " (forged)", 2: " (S + L)"}
+    return "rows " + ", ".join(
+        f"{r}{kind.get(r, '')} {'accepted' if mask[r] else 'rejected'}"
+        for r in rows[:8]
+    )
+
+
+# One compiled program per rung for the whole process, whichever backend
+# or caller asked first (off the chip a build costs ~85 s: never twice).
+_programs: dict = {}
+_programs_lock = threading.Lock()
+
+
+def verify_program(rung: int) -> Callable:
+    """THE compiled `_verify_kernel` for a padded shape: live dispatch and
+    warm-up call this same object.  Resolved on first use: loaded whole
+    from its program file where that is sound (held to `known_answers`
+    before it may serve), else lowered from the `jax.jit` definition
+    above, built and written (ops/programs.py)."""
+    program = _programs.get(rung)
+    if program is None:
+        with _programs_lock:
+            program = _programs.get(rung)
+            if program is None:
+                program = _programs[rung] = programs.resolve(
+                    _verify_kernel,
+                    kernel_args(rung),
+                    {"rung": rung, "field_dtype": F.NP_DTYPE.__name__},
+                    lambda loaded: wrong_answers(loaded, rung),
+                )
+    return program
+
+
+def dispatch_plan() -> Tuple[Callable[[int], Callable], Tuple[int, ...]]:
+    """(program for a padded shape, pad ladder) for the platform JAX runs
+    on: the ladder ascending, each rung scaled by the mesh's device count.
+    A backend resolves this once, at construction."""
     on_chip = jax.devices()[0].platform == "tpu"
     n_dev = mesh_devices()
-    kernel = _mesh_verify_kernel(n_dev) if n_dev > 1 else _verify_kernel
     base = CHIP_RUNGS if on_chip else CPU_RUNGS
-    return kernel, tuple(r * n_dev for r in base)
+    if n_dev > 1:
+        # A different program (never run on chips, D1), on the path it
+        # had: the jit object looks the shape up.
+        mesh_kernel = _mesh_verify_kernel(n_dev)
+        return (lambda pad: mesh_kernel), tuple(r * n_dev for r in base)
+    return verify_program, base
 
 
 def chunk_plan(n: int, ladder: Sequence[int]) -> List[Tuple[int, int, int]]:
@@ -544,7 +630,7 @@ def verify_batch_arrays(
     n = len(messages)
     if n == 0:
         return np.zeros(0, dtype=bool)
-    kernel, ladder = plan or dispatch_plan()
+    program_for, ladder = plan or dispatch_plan()
     pending = []
     chunks = chunk_plan(n, ladder)
     with annotate("verify.dispatch", dispatch=dispatch):
@@ -554,7 +640,7 @@ def verify_batch_arrays(
                     messages[lo:hi], keys[lo:hi], sigs[lo:hi], pad
                 )
             with annotate("verify.launch", dispatch=dispatch):
-                out = kernel(*(jnp.asarray(a) for a in args))
+                out = program_for(pad)(*(jnp.asarray(a) for a in args))
             pending.append((out, hi - lo))
             if dispatched is not None:
                 dispatched[pad] = dispatched.get(pad, 0) + 1
@@ -601,8 +687,8 @@ class TpuBackend:
         from concurrent.futures import ThreadPoolExecutor
 
         self.name = name
-        # Kernel and pad ladder, resolved once: live dispatch and warm-up
-        # read the same one.
+        # Program per rung and pad ladder, resolved once: live dispatch
+        # and warm-up read the same one.
         self._plan = dispatch_plan()
         self.rungs: Tuple[int, ...] = self._plan[1]
         self._executor = ThreadPoolExecutor(
@@ -667,39 +753,34 @@ class TpuBackend:
         )
 
     def warmup(self) -> str:
-        """Build (compile, or load from the persistent cache) the kernel
-        for every rung of the pad ladder, so no live burst pays minutes
-        of XLA compile on the critical path.  Returns a one-line account
-        for the caller's ready log; the counts it states are also in the
+        """Resolve the program for every rung of the pad ladder (load its
+        file, or trace, lower and compile it and write the file) and hold
+        each to the known answers, so no live burst pays for a program on
+        the critical path and none is served by a verifier that was not
+        seen to say no.  Returns a one-line account for the caller's
+        ready log; the counts it states are also in the
         `crypto.verify.device` snapshot detail."""
         from .. import metrics
-        from ..crypto import KeyPair
-        from ..crypto.digest import Digest
 
-        kp = KeyPair.generate()
-        msg = bytes(Digest(b"\x05" * 32))
-        sig = kp.sign(Digest(msg))
         ladder = self.rungs
         for n in ladder:
-            if not all(
-                verify_batch_arrays(
-                    [msg] * n, [kp.name] * n, [sig] * n, plan=self._plan
-                )
-            ):
-                raise RuntimeError(
-                    f"verify kernel rejected a valid signature at rung {n}"
-                )
+            wrong = wrong_answers(self._plan[0](n), n)
+            if wrong is not None:
+                raise RuntimeError(f"verify kernel at rung {n}: {wrong}")
         stats = compile_stats()
         self._programs_at_ready = stats["programs_built"]
         metrics.detail_fn("crypto.verify.device", self.device_report)
         return (
             "rungs {}, {} programs built in {:.1f} s (trace {:.1f} s), "
+            "{} of them loaded from program files ({} files rejected), "
             "persistent cache {} hits / {} misses".format(
                 ",".join(map(str, ladder)),
                 stats["programs_built"],
                 stats["trace_seconds"] + stats["lower_seconds"]
                 + stats["build_seconds"],
                 stats["trace_seconds"],
+                stats["programs_from_file"],
+                stats["program_files_rejected"],
                 stats["cache_hits"],
                 stats["cache_misses"],
             )

@@ -50,22 +50,9 @@ def lowered_verify(one_chip):
     chip (~7 s of Python tracing and lowering): one test compiles it
     (~20 s), one reads it."""
     from narwhal_tpu.ops import ed25519 as E
-    from narwhal_tpu.ops import field25519 as F
 
     b = E.CHIP_RUNGS[0]
-
-    def shape(dims, dtype):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
-
-    limbs, sign, flag, windows = (
-        shape((b, F.LIMBS), jnp.int32),
-        shape((b,), jnp.int32),
-        shape((b,), jnp.bool_),
-        shape((b, 64), jnp.int32),
-    )
-    return b, E._verify_kernel.lower(
-        limbs, sign, flag, limbs, sign, flag, windows, flag, windows
-    )
+    return b, E._verify_kernel.lower(*E.kernel_args(b, sharding=one_chip))
 
 
 def test_verify_kernel_compiles_for_v5e_at_bottom_rung(lowered_verify):

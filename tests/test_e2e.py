@@ -428,14 +428,19 @@ def test_commit_with_crash_fault(run):
     run(go())
 
 
-def test_prewarm_cli(tmp_path):
+def test_prewarm_cli(tmp_path, monkeypatch):
     """`node prewarm --crypto-backend jax` builds the verify kernel for
     every rung of the pad ladder (and optionally the consensus kernel)
     and exits 0 — the step the bench harness runs before spawning
-    SEVERAL device-backed nodes so their boot warmup is a cache load.
+    SEVERAL device-backed nodes so their boot warmup is a load of the
+    program files it wrote.
     Runs on the CPU jax backend here, on the tests' one-rung ladder."""
     from narwhal_tpu.node.main import main as node_main
+    from narwhal_tpu.ops import programs
     from tests.common import committee
+
+    # The program file goes to this test's directory, not the checkout's.
+    monkeypatch.setattr(programs, "program_dir", lambda: str(tmp_path / "programs"))
 
     c = committee(base_port=15200)
     path = str(tmp_path / "committee.json")

@@ -9,6 +9,7 @@ rejection paths.  Reference semantics: crypto/src/lib.rs:200-219
 """
 
 import hashlib
+import os
 import random
 
 import numpy as np
@@ -29,6 +30,20 @@ from narwhal_tpu.ops import ed25519 as E  # noqa: E402
 from narwhal_tpu.ops import field25519 as F  # noqa: E402
 
 rng = random.Random(7)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def program_files(tmp_path_factory):
+    """This file's program files (ops/programs.py) go to a directory of
+    its own, never the checkout's `.jax_cache/`: the first test that
+    needs the 16-row rung builds it and writes it HERE, and
+    `test_the_next_backend_loads_the_program_file` loads it back."""
+    from narwhal_tpu.ops import programs
+
+    directory = tmp_path_factory.mktemp("programs")
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(programs, "program_dir", lambda: str(directory))
+        yield directory
 
 
 def keypair():
@@ -269,7 +284,7 @@ def test_ladder_follows_the_platform_not_a_knob(monkeypatch):
     resolves its ladder once, at construction."""
     from narwhal_tpu.ops.ed25519 import TpuBackend
 
-    assert E.dispatch_plan() == (E._verify_kernel, E.CPU_RUNGS)
+    assert E.dispatch_plan() == (E.verify_program, E.CPU_RUNGS)
     assert E.CPU_RUNGS == (16,) and E.CHIP_RUNGS == (128, 512)
     backend = TpuBackend("jax")
 
@@ -277,7 +292,7 @@ def test_ladder_follows_the_platform_not_a_knob(monkeypatch):
         platform = "tpu"
 
     monkeypatch.setattr(E.jax, "devices", lambda: [Chip()])
-    assert E.dispatch_plan() == (E._verify_kernel, (128, 512))
+    assert E.dispatch_plan() == (E.verify_program, (128, 512))
     assert backend.rungs == (16,)  # resolved before the platform "changed"
     assert TpuBackend("jax").rungs == (128, 512)
 
@@ -302,6 +317,95 @@ def test_warmup_builds_the_ladder_and_reports_it():
     # Peak device memory as the platform reports it (jax-cpu keeps no
     # allocator statistics: 0, never a missing key).
     assert report["memory_peak_bytes"] == E.memory_peak_bytes() >= 0
+
+
+def adversarial_batch():
+    """Valid, forged, malleable (S + L), small-order and wrong-key rows,
+    mixed, with what OpenSSL-or-stricter says of each."""
+    sk, pk = keypair()
+    _, other = keypair()
+    msgs, keys, sigs, want = [], [], [], []
+    for i in range(12):
+        m = bytes([i]) * 32
+        key, sig, ok = pk, sk.sign(m), True
+        if i % 6 == 1:  # forged: a signature of another message
+            sig, ok = sk.sign(b"\xff" * 32), False
+        elif i % 6 == 2:  # malleable
+            s_int = int.from_bytes(sig[32:], "little") + E.L_ORDER
+            sig, ok = sig[:32] + s_int.to_bytes(32, "little"), False
+        elif i % 6 == 3:  # small order: A = identity, R = [s]B
+            rx, ry = E._ref_scalarmult(777)
+            key = (1).to_bytes(32, "little")
+            sig = (ry | ((rx & 1) << 255)).to_bytes(32, "little") + (777).to_bytes(32, "little")
+            ok = False
+        elif i % 6 == 4:
+            key, ok = other, False
+        msgs.append(m), keys.append(key), sigs.append(sig), want.append(ok)
+    return msgs, keys, sigs, want
+
+
+def test_known_answers_catch_a_verifier_that_says_yes():
+    """What a loaded program must pass before it may serve: the valid
+    rows accepted AND the forged and the malleable row rejected, row by
+    row.  The real program passes; a foreign one is named for what it
+    got wrong, whichever way."""
+    n = E.CPU_RUNGS[0]
+    assert E.wrong_answers(E.verify_program(n), n) is None
+    assert E.wrong_answers(lambda *a: np.ones(n, bool), n) == (
+        "rows 1 (forged) accepted, 2 (S + L) accepted"
+    )
+    assert E.wrong_answers(lambda *a: np.zeros(n, bool), n).startswith(
+        "rows 0 rejected, 3 rejected, 4 rejected"
+    )
+    assert "shape (4,)" in E.wrong_answers(lambda *a: np.ones(4, bool), n)
+    args, expected = E.known_answers(n)
+    assert [a.shape for a in args] == [a.shape for a in E.kernel_args(n)]
+    assert [a.dtype for a in args] == [a.dtype for a in E.kernel_args(n)]
+    assert expected.tolist() == [True, False, False] + [True] * (n - 3)
+
+
+def test_the_next_backend_loads_the_program_file(program_files, monkeypatch):
+    """The 16-row program this process built was written to its file; a
+    backend that starts with nothing in memory (as a second process does)
+    loads it whole: no trace, no lowering, the load's seconds in
+    `build_seconds`, `programs_built == programs_at_ready` as on the
+    built path, and the same verdicts as the built program gives."""
+    from narwhal_tpu import ops
+    from narwhal_tpu.ops import programs
+    from narwhal_tpu.ops.ed25519 import TpuBackend
+
+    n = E.CPU_RUNGS[0]
+    built = E.verify_program(n)
+    variant = {"rung": n, "field_dtype": "int32"}
+    key = programs.program_key("_verify_kernel", variant)
+    path = programs.program_path(key, variant)
+    if not os.path.exists(path):
+        # An earlier test FILE of this worker process built the program
+        # (one a process, whoever asks first) and wrote it elsewhere.
+        programs.store(path, key, built)
+    assert os.listdir(program_files) == ["_verify_kernel-16-int32-cpu-cpu-d0.program"]
+    msgs, keys, sigs, want = adversarial_batch()
+    assert list(E.verify_batch_arrays(msgs, keys, sigs)) == want
+
+    monkeypatch.setattr(E, "_programs", {})  # nothing resolved yet
+    before = ops.compile_stats()
+    backend = TpuBackend("jax")
+    line = backend.warmup()
+    after = ops.compile_stats()
+    assert E.verify_program(n) is not built
+    assert after["programs_from_file"] - before["programs_from_file"] == 1
+    assert after["programs_built"] - before["programs_built"] == 1
+    assert after["program_files_rejected"] == before["program_files_rejected"]
+    assert after["trace_seconds"] == before["trace_seconds"]
+    assert after["lower_seconds"] == before["lower_seconds"]
+    assert after["build_seconds"] > before["build_seconds"]
+    assert "{} of them loaded from program files".format(after["programs_from_file"]) in line
+    assert backend.verify_batch_mask(msgs, keys, sigs) == want
+    report = backend.device_report()
+    assert report["programs_built"] == report["programs_at_ready"]
+    assert report["programs_from_file"] == after["programs_from_file"]
+    assert report["program_files_rejected"] == before["program_files_rejected"]
+    assert report["dispatched"] == {"16": 1}
 
 
 @pytest.mark.parametrize("n, pad, chunks", [(3, 16, 1), (21, [16, 16], 2)])
@@ -449,16 +553,8 @@ VERIFY_KERNEL_EQUATIONS_CEILING = 27_000
 
 
 def test_verify_program_stays_within_its_size_budget():
-    import jax.numpy as jnp
-
     b = E.CHIP_RUNGS[0]
-    limbs = jax.ShapeDtypeStruct((b, F.LIMBS), jnp.int32)
-    sign = jax.ShapeDtypeStruct((b,), jnp.int32)
-    flag = jax.ShapeDtypeStruct((b,), jnp.bool_)
-    windows = jax.ShapeDtypeStruct((b, 64), jnp.int32)
-    traced = jax.make_jaxpr(E._verify_kernel.__wrapped__)(
-        limbs, sign, flag, limbs, sign, flag, windows, flag, windows
-    )
+    traced = jax.make_jaxpr(E._verify_kernel.__wrapped__)(*E.kernel_args(b))
     equations = count_equations(traced.jaxpr)
     assert VERIFY_KERNEL_EQUATIONS_CEILING <= 35_000
     assert equations <= VERIFY_KERNEL_EQUATIONS_CEILING, equations
