@@ -22,7 +22,7 @@ native:
 
 lint:
 	$(PYTHON) -m compileall -q narwhal_tpu benchmark tests bench.py \
-		bench_consensus.py bench_cadence.py bench_crypto.py \
+		bench_cadence.py bench_crypto.py \
 		__graft_entry__.py
 	@if $(PYTHON) -c "import flake8" 2>/dev/null; then \
 		$(PYTHON) -m flake8 --select=F,E9 --extend-ignore=F401 \

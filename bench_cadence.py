@@ -47,8 +47,13 @@ import time
 REPO = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, REPO)
 
-from bench_consensus import make_committee  # noqa: E402
-from narwhal_tpu.crypto import Signature, SignatureService  # noqa: E402
+from narwhal_tpu.config import (  # noqa: E402
+    Authority,
+    Committee,
+    PrimaryAddresses,
+    WorkerAddresses,
+)
+from narwhal_tpu.crypto import KeyPair, Signature, SignatureService  # noqa: E402
 from narwhal_tpu.primary.core import AtomicRound, Core  # noqa: E402
 from narwhal_tpu.primary.messages import (  # noqa: E402
     Certificate,
@@ -58,6 +63,22 @@ from narwhal_tpu.primary.messages import (  # noqa: E402
 )
 from narwhal_tpu.primary.synchronizer import Synchronizer  # noqa: E402
 from narwhal_tpu.store import Store  # noqa: E402
+
+
+def make_committee(n: int):
+    """(committee, keypairs): seeded stake-1 loopback committee."""
+    kps = [
+        KeyPair.generate(rng_seed=i.to_bytes(32, "little")) for i in range(n)
+    ]
+    auths = {
+        kp.name: Authority(
+            stake=1,
+            primary=PrimaryAddresses("127.0.0.1:0", "127.0.0.1:0"),
+            workers={0: WorkerAddresses("127.0.0.1:0", "127.0.0.1:0", "127.0.0.1:0")},
+        )
+        for kp in kps
+    }
+    return Committee(auths), kps
 
 
 class NullSender:
@@ -186,7 +207,7 @@ async def run_arm(committee, kps, me_kp, prebuilt, fast_path: bool, store_path: 
 
 
 def bench_size(n: int, rounds: int, iters: int, storedir: str):
-    committee, kps = make_committee(n, return_keypairs=True)
+    committee, kps = make_committee(n)
     me_kp = kps[0]
     prebuilt = prebuild_rounds(committee, kps, me_kp, rounds)
     samples = {"fast": [], "legacy": []}

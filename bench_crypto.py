@@ -10,10 +10,8 @@ on the same host, at batch sizes spanning the protocol's realistic range
 through the Core's accumulate→batch-verify seam).
 
 Methodology:
-- steady state only: first call per shape compiles (minutes for the chip,
-  then cached persistently — JAX_COMPILATION_CACHE_DIR if set, else
-  .jax_cache/ in the checkout); timings start after a warmup call per
-  shape.
+- steady state only: first call per shape compiles; timings start after
+  a warmup call per shape.
 - `device`: median-of-N wall time of dispatch→block on the result mask —
   the latency a Core burst actually pays.
 - `pipelined`: K batches dispatched back-to-back before blocking — the

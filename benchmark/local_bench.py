@@ -211,7 +211,6 @@ def run_bench(
     keep_logs: bool = False,
     quiet: bool = False,
     crypto_backend: str = None,
-    consensus_kernel: bool = False,
     tpu_primaries: int = None,
     scrape_interval: float = 1.0,
     progress_wait: float = 0.0,
@@ -227,11 +226,10 @@ def run_bench(
     """Run one committee + clients on localhost; return the ParseResult.
 
     ``tpu_primaries`` limits the device flags (``crypto_backend="tpu"`` or
-    ``"jax"`` / ``consensus_kernel``) to the first N primaries: a chip
-    belongs to one process, so on a one-chip host a mixed committee (one
-    device-backed primary, the rest CPU) is the honest way to exercise
-    the device path end-to-end.  ``None`` means every primary gets the
-    flags (all-CPU or all-jax runs).
+    ``"jax"``) to the first N primaries: a chip belongs to one process, so
+    on a one-chip host a mixed committee (one device-backed primary, the
+    rest CPU) is the honest way to exercise the device path end-to-end.
+    ``None`` means every primary gets the flags (all-CPU or all-jax runs).
 
     ``audit``: every primary appends its consensus audit segment to
     ``{workdir}/audit-primary-{i}.bin`` for ``replay_segments``.
@@ -372,8 +370,6 @@ def run_bench(
         device_flags += ["--crypto-backend", crypto_backend]
     elif crypto_backend:
         base_flags += ["--crypto-backend", crypto_backend]
-    if consensus_kernel:
-        device_flags += ["--experimental-consensus-kernel"]
 
     alive = nodes - faults  # crash faults: the last `faults` nodes never boot
     n_device = 0
@@ -390,10 +386,10 @@ def run_bench(
             "processes — pass tpu_primaries=1 (or 'jax', the same verifier "
             "on whatever platform JAX has)"
         )
-    # A separate prewarm process earns its cost (a process start plus
-    # ~30 s of tracing per shape, cache hit or not) only when SEVERAL
-    # device-backed primaries follow: it compiles each program once and
-    # they all load it, instead of every one compiling cold side by side.
+    # A separate prewarm process earns its cost (a process start) only
+    # when SEVERAL device-backed primaries follow: it compiles each
+    # program once and they all load its program file, instead of every
+    # one compiling cold side by side.
     # A single device-backed primary is its own prewarm — it is started
     # first, below, and the rest of the committee waits for it.  Either
     # way a failure here is fatal: carrying on would measure a committee
@@ -406,13 +402,9 @@ def run_bench(
             "-m",
             "narwhal_tpu.node",
             "prewarm",
-            "--committee",
-            f"{workdir}/committee.json",
+            "--crypto-backend",
+            crypto_backend,
         ]
-        if crypto_backend in ("tpu", "jax"):
-            warm_cmd += ["--crypto-backend", crypto_backend]
-        if consensus_kernel:
-            warm_cmd.append("--experimental-consensus-kernel")
         # subprocess.run returns only once the child has EXITED, so it
         # has released the device before any primary asks for it.
         warm = subprocess.run(warm_cmd, env=env, cwd=REPO, check=False)
@@ -709,14 +701,6 @@ def main():
         "(default classic)",
     )
     parser.add_argument(
-        "--experimental-consensus-kernel",
-        dest="consensus_kernel",
-        action="store_true",
-        help="EXPERIMENTAL: run the committee with the device-resident "
-        "consensus kernel (correct, never measured faster than the "
-        "Python walk, not measured on this machine)",
-    )
-    parser.add_argument(
         "--tpu-primaries",
         type=int,
         default=None,
@@ -738,7 +722,6 @@ def main():
         header_linger=args.header_linger,
         max_header_delay=args.max_header_delay,
         crypto_backend=args.crypto_backend,
-        consensus_kernel=args.consensus_kernel,
         tpu_primaries=args.tpu_primaries,
         loop_watchdog_ms=args.loop_watchdog_ms,
         trace_out=args.trace_out,

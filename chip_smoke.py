@@ -186,7 +186,7 @@ def verify_child(seed: int, report_path: str, first: bool,
         "verify[{}]: device {platform} / {kind} / count {count}".format(
             "first process" if first else "second process", **device
         )
-        + f"; ladder {ladder}; compile cache {cache}"
+        + f"; ladder {ladder}; program files in {cache}"
     )
     rng = random.Random(seed)
     sizes = sorted({ladder[0], ladder[-1]})
@@ -218,7 +218,7 @@ def verify_child(seed: int, report_path: str, first: bool,
             **{
                 k: after[k] - before[k]
                 for k in ("programs_built", "programs_from_file",
-                          "program_files_rejected", "cache_hits", "cache_misses")
+                          "program_files_rejected")
             },
         }
         if first and n in ladder:

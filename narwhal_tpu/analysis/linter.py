@@ -42,7 +42,6 @@ TEXT_GLOBS = (
     "tests",
     ".github/workflows",
     "bench.py",
-    "bench_consensus.py",
     "bench_cadence.py",
     "bench_crypto.py",
     "__graft_entry__.py",

@@ -13,13 +13,10 @@ The live ``Tusk`` rebuilt around indexed, incremental state (PR 4) must
 stay certificate-for-certificate equivalent to THIS walk; the discipline
 follows the "Reusable Formal Verification of DAG-based Consensus
 Protocols" observation (PAPERS.md) that every commit-rule rewrite needs
-an unchanged oracle to diff against.  Consumers:
-
-- tests/test_tusk_equivalence.py replays recorded certificate streams
-  (multi-leader burst, gc-window wrap, checkpoint restore, fuzz) through
-  both implementations and asserts byte-identical commit sequences;
-- bench_consensus.py's commit-burst phase uses it as the "before" arm of
-  the indexed-walk speedup table.
+an unchanged oracle to diff against.  tests/test_tusk_equivalence.py
+replays recorded certificate streams (multi-leader burst, gc-window
+wrap, checkpoint restore, fuzz) through both implementations and asserts
+byte-identical commit sequences.
 
 Do not optimize this file.  Its only job is to stay what it was.
 """

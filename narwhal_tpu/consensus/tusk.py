@@ -7,10 +7,7 @@ it is linked to, each flattening its causal sub-DAG in deterministic order.
 No extra messages: the commit rule is a pure function of the DAG.
 
 The pure state machine (`Tusk.process_certificate`) is separated from the
-async runner (`Consensus`) so the commit rule can be golden-tested directly
-and swapped for the JAX adjacency-matrix kernel
-(narwhal_tpu/ops/reachability.py) validated certificate-for-certificate
-against this implementation.
+async runner (`Consensus`) so the commit rule can be golden-tested directly.
 
 Commit-path latency model (PR 4 rebuild — the r07 stage breakdown measured
 cert→commit at 77% of seal→commit end-to-end latency, and Mysticeti's core
@@ -390,11 +387,10 @@ class Tusk:
         return self._sorted_keys[coin % len(self._sorted_keys)]
 
     def insert_certificate(self, certificate: Certificate) -> None:
-        """Insert into the DAG without running the commit rule.  Separate
-        seam so KernelTusk can maintain its dense device window
-        incrementally, and benchmarks can build large DAG states.  Also
-        the single maintenance point for the digest index (via
-        State.insert) and the incremental leader-support counters."""
+        """Insert into the DAG without running the commit rule: the seam
+        through which tests and benchmarks build DAG states, and the
+        single maintenance point for the digest index (via State.insert)
+        and the incremental leader-support counters."""
         d, prev = self.state.insert(certificate)
         if prev is not None and prev == d:
             return  # idempotent re-insert: counters already reflect it
@@ -505,11 +501,8 @@ class Tusk:
         cone of the current chain head; when it reaches the leader of an
         even round, that leader joins the chain and the frontier RESETS
         to it alone — exactly the reference's ``leader = prev_leader``
-        rebinding, and exactly the semantics the device kernel's
-        ``_chain_scan`` executes (ops/reachability.py), which the r06
-        equivalence suite validated certificate-for-certificate.
-        Parent digests resolve through the digest index, so each hop is
-        O(frontier edges)."""
+        rebinding.  Parent digests resolve through the digest index, so
+        each hop is O(frontier edges)."""
         state = self.state
         index = state.digest_index
         to_commit = [leader]
@@ -543,9 +536,8 @@ class Tusk:
 
     # NOTE: the reference's per-pair ``linked()`` BFS (lib.rs:247-259) has
     # no standalone counterpart here — its reachability question is
-    # answered inside order_leaders' single frontier pass (the TPU kernel
-    # re-expresses the same loop as boolean adjacency-matrix products).
-    # The frozen oracle keeps the original per-pair form (golden.py).
+    # answered inside order_leaders' single frontier pass.  The frozen
+    # oracle keeps the original per-pair form (golden.py).
 
     def order_dag(self, leader: Certificate) -> List[Certificate]:
         """DFS flatten of the leader's causal history, skipping
@@ -1003,7 +995,6 @@ class Consensus:
         tx_output: asyncio.Queue,
         benchmark: bool = False,
         fixed_coin: bool = False,
-        use_kernel: bool = False,
         checkpoint_path: Optional[str] = None,
         audit_path: Optional[str] = None,
         commit_rule: Optional[str] = None,
@@ -1013,18 +1004,7 @@ class Consensus:
         # rides the same resolution the node CLI does.
         rule = resolve_commit_rule(commit_rule)
         self.commit_rule = rule
-        if use_kernel:
-            if rule != "classic":
-                raise ValueError(
-                    "--experimental-consensus-kernel implements the "
-                    "classic walk only; it cannot run commit rule "
-                    f"{rule!r}"
-                )
-            # Deferred: the pure-CPU node path must not pay the JAX import.
-            from ..ops.reachability import KernelTusk
-
-            self.tusk = KernelTusk(committee, gc_depth, fixed_coin=fixed_coin)
-        elif rule == "lowdepth":
+        if rule == "lowdepth":
             self.tusk = LowDepthTusk(committee, gc_depth, fixed_coin=fixed_coin)
         elif rule == "multileader":
             self.tusk = MultiLeaderTusk(
@@ -1182,10 +1162,6 @@ class Consensus:
                     checkpoint_path,
                 )
             else:
-                if hasattr(self.tusk, "_win_shift"):
-                    # Realign the kernel's dense window to the restored
-                    # frontier (slot 0 == last_committed_round).
-                    self.tusk._win_shift()
                 log.info(
                     "Restored consensus frontier at round %d",
                     self.tusk.state.last_committed_round,

@@ -71,7 +71,7 @@ LATENCY_BUCKETS: Tuple[float, ...] = (
     0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
 )
 
-# Size/count buckets (e.g. KernelTusk flush batch sizes, queue bursts).
+# Size/count buckets (e.g. commit batch sizes, queue bursts).
 COUNT_BUCKETS: Tuple[float, ...] = (
     1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024,
 )

@@ -122,17 +122,6 @@ def main(argv=None) -> int:
     run.add_argument("--store", required=True)
     run.add_argument("--benchmark", action="store_true", default=False)
     run.add_argument(
-        "--experimental-consensus-kernel",
-        action="store_true",
-        default=False,
-        help="EXPERIMENTAL: run Tusk's order_leaders on the JAX device "
-        "kernel (device-resident window, W-bit commit fetch).  Correct "
-        "(golden-tested cert-for-cert) but never measured faster than "
-        "the Python walk end to end, and not measured on this machine — "
-        "excluded from the default benchmark flag set; see README.md "
-        "'Consensus kernel'",
-    )
-    run.add_argument(
         "--crypto-backend",
         choices=["cpu", "tpu", "jax"],
         default=None,
@@ -224,28 +213,17 @@ def main(argv=None) -> int:
 
     warm = sub.add_parser(
         "prewarm",
-        help="Build the device kernels a committee's nodes will need into "
-        "the persistent XLA cache, then exit.  Worth its own process only "
-        "when SEVERAL device-backed nodes follow: they then load in "
-        "parallel what this compiled once.  A single device-backed node "
-        "is its own prewarm (its boot-time warm-up builds the same "
-        "programs before it joins).",
+        help="Build the verify programs and write their program files, "
+        "then exit.  Worth its own process only when SEVERAL device-backed "
+        "nodes follow (they load in parallel what this compiled once); a "
+        "single one is its own prewarm.",
     )
-    warm.add_argument("--committee", required=True)
     warm.add_argument(
         "--crypto-backend",
         choices=["tpu", "jax"],
-        default=None,
-        help="Warm the verify kernel under this backend name (the one the "
-        "committee's nodes will be started with).  Unset = skip the "
-        "verify kernel (consensus-kernel-only runs keep CPU crypto).",
+        required=True,
+        help="The backend name the committee's nodes will be started with.",
     )
-    warm.add_argument(
-        "--experimental-consensus-kernel",
-        action="store_true",
-        default=False,
-    )
-    warm.add_argument("--gc-depth", type=int, default=None)
 
     args = parser.parse_args(argv)
 
@@ -256,31 +234,17 @@ def main(argv=None) -> int:
     if args.command == "prewarm":
         setup_logging(args.verbosity, args.log_level)
         log = logging.getLogger("narwhal.node")
-        committee = Committee.load(args.committee)
-        if args.crypto_backend:
-            from ..crypto import backend as crypto_backend
+        from ..crypto import backend as crypto_backend
 
-            crypto_backend.set_backend(args.crypto_backend)
-            log.info(
-                "Prewarming verify backend: %s",
-                crypto_backend.describe_backend(),
-            )
-            log.info(
-                "Verify backend %s ready: %s",
-                args.crypto_backend,
-                crypto_backend.get_backend().warmup(),
-            )
-        if args.experimental_consensus_kernel:
-            from ..ops.reachability import KernelTusk
-
-            gc_depth = (
-                args.gc_depth
-                if args.gc_depth is not None
-                else Parameters().gc_depth
-            )
-            log.info("Prewarming consensus kernel...")
-            KernelTusk(committee, gc_depth).prewarm()
-            log.info("Consensus kernel ready")
+        crypto_backend.set_backend(args.crypto_backend)
+        log.info(
+            "Prewarming verify backend: %s", crypto_backend.describe_backend()
+        )
+        log.info(
+            "Verify backend %s ready: %s",
+            args.crypto_backend,
+            crypto_backend.get_backend().warmup(),
+        )
         return 0
 
     # Keypair first: the JSON log formatter stamps every record with a
@@ -423,7 +387,6 @@ def main(argv=None) -> int:
                 parameters,
                 store_path=f"{args.store}/store.log",
                 benchmark=args.benchmark,
-                use_kernel=args.experimental_consensus_kernel,
                 fault_plan=fault_plan,
                 commit_rule=args.commit_rule,
             )

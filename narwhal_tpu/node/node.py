@@ -55,7 +55,6 @@ async def spawn_primary_node(
     store_path: Optional[str] = None,
     benchmark: bool = False,
     on_commit: Optional[Callable] = None,
-    use_kernel: bool = False,
     fault_plan=None,
     audit_path: Optional[str] = None,
     store: Optional[Store] = None,
@@ -115,10 +114,6 @@ async def spawn_primary_node(
     tx_feedback = metrics.InstrumentedQueue(cap, channel="node.tx_feedback")
     tx_output = metrics.InstrumentedQueue(cap, channel="node.tx_output")
 
-    # Same for the consensus kernel: compile its one static window shape
-    # before the primary joins the committee (KernelTusk.prewarm docstring),
-    # which is why the Consensus is built before Primary.spawn logs the
-    # boot banner the harness waits on.
     consensus = (consensus_cls or Consensus)(
         committee,
         parameters.gc_depth,
@@ -126,7 +121,6 @@ async def spawn_primary_node(
         tx_primary=tx_feedback,
         tx_output=tx_output,
         benchmark=benchmark,
-        use_kernel=use_kernel,
         # Committed-frontier crash recovery (beyond reference parity):
         # a small atomically-rewritten file next to the store log, so a
         # restarted primary's ordering anchors at its old frontier and
@@ -141,10 +135,6 @@ async def spawn_primary_node(
         # value (node run --commit-rule) arrives here already resolved.
         commit_rule=commit_rule,
     )
-    if hasattr(consensus.tusk, "prewarm"):
-        log.info("Warming up consensus kernel...")
-        consensus.tusk.prewarm()
-        log.info("Consensus kernel ready")
     node.consensus = consensus
 
     node.primary = await Primary.spawn(

@@ -1,13 +1,13 @@
-"""TPU-resident kernels (JAX/XLA) behind the framework's CPU seams.
+"""The device layer: the batched ed25519 verifier (JAX/XLA) behind the
+crypto seam.
 
-- reachability: the Tusk commit rule's graph traversals (linked()/order_dag
-  frontier walks, reference consensus/src/lib.rs:247-303) as one jitted
-  boolean matrix scan over the (gc_depth x committee) certificate window.
 - ed25519: batched on-device signature verification (reference
   crypto/src/lib.rs:206-219 verify_batch) — field/point arithmetic from
-  32-bit lanes, vmapped over the batch.
+  32-bit lanes (field25519), vmapped over the batch.
+- programs: the verifier's compiled programs kept whole as files, so a
+  process loads them without tracing.
 
-Import is deferred by callers (crypto.backend, consensus) so the pure-CPU
+Import is deferred by the one caller (crypto.backend) so the pure-CPU
 protocol path never pays the JAX import cost — and never holds the chip,
 which belongs to one process at a time.
 """
@@ -16,58 +16,51 @@ import os as _os
 
 import jax as _jax
 
-# One directory for everything this process compiles.  Every process of a
-# run (the prewarm child, each device-backed primary, each chip_smoke
-# phase) needs the same programs, so they must all land in ONE place.  JAX
-# reads JAX_COMPILATION_CACHE_DIR itself: when the environment places the
-# cache, nothing is set here.  Otherwise it lives at one fixed,
-# git-ignored path inside the checkout (never under $HOME or a temp
-# name), so a second process of the same run always finds what the first
-# compiled.  The directory holds JAX's persistent cache (keyed by the
-# lowered module: a hit still costs the trace and the lowering) and, as
-# `*.program`, the verify ladder's executables whole (ops/programs.py:
-# found by name and key, no trace at all).
+# One cache for compiled programs: the program files (ops/programs.py).
+# JAX's persistent cache is off for the process: nothing a node jits but
+# the verifier costs a second to compile, and an executable that cache
+# hands back cannot be written out whole on XLA:CPU (it serializes without
+# its kernels; sandbox, PR 30), so a program file only ever comes from a
+# build of this process's own.
+_jax.config.update("jax_enable_compilation_cache", False)
+
+# Where the program files live.  Every process of a run (the prewarm
+# child, each device-backed primary, each chip_smoke phase) needs the same
+# programs, so they must all land in ONE place: the deployment's
+# JAX_COMPILATION_CACHE_DIR where that is set, else one fixed, git-ignored
+# path inside the checkout (never under $HOME or a temp name), so a second
+# process of the same run always finds what the first compiled.
 CACHE_DIR = _os.path.join(
     _os.path.dirname(_os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))),
     ".jax_cache",
 )
-if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-    _jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
 
 
 def program_dir() -> str:
-    """The directory above, as the environment has it NOW."""
+    """The directory of the program files, as the environment has it NOW."""
     return _os.environ.get("JAX_COMPILATION_CACHE_DIR") or CACHE_DIR
 
 
-# Compile ledger: how many XLA programs this process built (compiled,
-# loaded from the persistent cache after a trace, or loaded whole from a
-# program file: each a miss that stalls its caller), the seconds spent
-# tracing / lowering / building them (`build_seconds` holds a program
-# file's read + deserialize + load too: what the caller waited), the
-# persistent cache's hits and misses, how many of `programs_built` came
-# from a program file without any trace (`programs_from_file`) and how
-# many program files were found and not used (`program_files_rejected`;
-# each is then built and written anew).  Read by the node's ready line,
-# the `crypto.verify.device` snapshot detail and chip_smoke.py: "no
-# compile after warm-up" and "the second process traced nothing" are
-# counted, not inferred.
+# Compile ledger: how many XLA programs this process built (compiled, or
+# loaded whole from a program file: each a miss that stalls its caller),
+# the seconds spent tracing / lowering / building them (`build_seconds`
+# holds a program file's read + deserialize + load too: what the caller
+# waited), how many of `programs_built` came from a program file without
+# any trace (`programs_from_file`) and how many program files were found
+# and not used (`program_files_rejected`; each is then built and written
+# anew).  Read by the node's ready line, the `crypto.verify.device`
+# snapshot detail and chip_smoke.py: "no compile after warm-up" and "the
+# second process traced nothing" are counted, not inferred.
 _DURATION_KEYS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace_seconds",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_seconds",
     "/jax/core/compile/backend_compile_duration": "build_seconds",
-}
-_EVENT_KEYS = {
-    "/jax/compilation_cache/cache_hits": "cache_hits",
-    "/jax/compilation_cache/cache_misses": "cache_misses",
 }
 _compile_stats = {
     "programs_built": 0,
     "trace_seconds": 0.0,
     "lower_seconds": 0.0,
     "build_seconds": 0.0,
-    "cache_hits": 0,
-    "cache_misses": 0,
     "programs_from_file": 0,
     "program_files_rejected": 0,
 }
@@ -81,14 +74,7 @@ def _on_duration(event: str, duration: float, **_kw) -> None:
             _compile_stats["programs_built"] += 1
 
 
-def _on_event(event: str, **_kw) -> None:
-    key = _EVENT_KEYS.get(event)
-    if key is not None:
-        _compile_stats[key] += 1
-
-
 _jax.monitoring.register_event_duration_secs_listener(_on_duration)
-_jax.monitoring.register_event_listener(_on_event)
 
 
 def count_program_file(seconds: float, used: bool) -> None:

@@ -40,7 +40,6 @@ import jax.numpy as jnp
 
 from ..utils.clock import wall_now
 from ..utils.devtrace import annotate, current_burst
-from ..utils.env import env_flag
 from . import compile_stats, device_identity, programs
 from . import field25519 as F
 
@@ -414,69 +413,18 @@ def prepare_batch(
     )
 
 
-# -- multi-device mesh (stretch, NARWHAL_VERIFY_MESH) -------------------------
-#
-# The kernel is elementwise over the batch axis, so sharding is trivial:
-# a 1-D Mesh over every visible device, shard_map splitting the batch.
-# Every rung of the pad ladder is multiplied by the device count, so each
-# shard always holds exactly one single-device rung of rows.  Off by
-# default and never run on chips; ROADMAP D1 decides its fate.
-
-_mesh_kernel_cache: dict = {}
-
-
-def _mesh_verify_kernel(n_dev: int):
-    """shard_map-wrapped _verify_kernel over an ``n_dev``-device mesh;
-    built once per device count (the wrapped fn keeps the jit cache)."""
-    fn = _mesh_kernel_cache.get(n_dev)
-    if fn is None:
-        from jax.sharding import Mesh, PartitionSpec as P_
-
-        mesh = Mesh(np.array(jax.devices()), ("batch",))
-        spec = P_("batch")
-
-        def _mesh_verify(*args):
-            with jax.named_scope("verify_mesh"):
-                return _verify_kernel.__wrapped__(*args)  # un-jitted
-
-        fn = jax.jit(
-            jax.shard_map(
-                _mesh_verify,
-                mesh=mesh,
-                in_specs=(spec,) * 9,
-                out_specs=spec,
-                # No collectives and every value is per-row, so there is
-                # no replication to check; the checker would only make
-                # the field code cast its scan carries to "varying".
-                check_vma=False,
-            )
-        )
-        _mesh_kernel_cache[n_dev] = fn
-    return fn
-
-
-def mesh_devices() -> int:
-    """How many devices a mesh-sharded verify would span: >1 only when
-    the NARWHAL_VERIFY_MESH flag is on and JAX sees several devices."""
-    if not env_flag("NARWHAL_VERIFY_MESH"):
-        return 1
-    return len(jax.devices())
-
-
 # -- the pad ladder -----------------------------------------------------------
 #
 # XLA compiles one program per padded batch shape.  On the chip's host one
 # shape traces and lowers in ~5.6 s and builds cold in 17-25 s (PERF.md,
-# PR 27; before that PR the program was four times the size: ~24 s and
-# 110-145 s); a process that finds the shape's executable whole in its
-# program file (ops/programs.py, PR 30) pays its load alone, ~2 s, which
-# until then it paid for a hit in JAX's persistent cache AFTER the trace
-# and the lowering.  So the shapes are a short fixed ladder, not every
-# power of two up to the committee's worst burst: a batch pads to the
-# smallest rung that holds it, and a batch above the top rung is split
-# into top-rung chunks.  The pad policy and the warm-up read the SAME
-# ladder, so no live burst — however large a late joiner's catch-up makes
-# it — can reach a shape that was not built before the node joined.
+# PR 27); a process that finds the shape's executable whole in its program
+# file (ops/programs.py) pays its load alone, ~2 s (PERF.md, PR 30).  So
+# the shapes are a short fixed ladder, not every power of two up to the
+# committee's worst burst: a batch pads to the smallest rung that holds
+# it, and a batch above the top rung is split into top-rung chunks.  The
+# pad policy and the warm-up read the SAME ladder, so no live burst —
+# however large a late joiner's catch-up makes it — can reach a shape
+# that was not built before the node joined.
 #
 # The chip's rungs were chosen from one reading of the old program on a
 # v5e (ms per call, prepared arrays in, mask fetched; PERF.md, PR 22): 16
@@ -580,19 +528,9 @@ def verify_program(rung: int) -> Callable:
     return program
 
 
-def dispatch_plan() -> Tuple[Callable[[int], Callable], Tuple[int, ...]]:
-    """(program for a padded shape, pad ladder) for the platform JAX runs
-    on: the ladder ascending, each rung scaled by the mesh's device count.
-    A backend resolves this once, at construction."""
-    on_chip = jax.devices()[0].platform == "tpu"
-    n_dev = mesh_devices()
-    base = CHIP_RUNGS if on_chip else CPU_RUNGS
-    if n_dev > 1:
-        # A different program (never run on chips, D1), on the path it
-        # had: the jit object looks the shape up.
-        mesh_kernel = _mesh_verify_kernel(n_dev)
-        return (lambda pad: mesh_kernel), tuple(r * n_dev for r in base)
-    return verify_program, base
+def pad_ladder() -> Tuple[int, ...]:
+    """The pad ladder, ascending, for the platform JAX runs on."""
+    return CHIP_RUNGS if jax.devices()[0].platform == "tpu" else CPU_RUNGS
 
 
 def chunk_plan(n: int, ladder: Sequence[int]) -> List[Tuple[int, int, int]]:
@@ -611,26 +549,25 @@ def verify_batch_arrays(
     keys,
     sigs,
     dispatched: Optional[dict] = None,
-    plan: Optional[Tuple[Callable, Sequence[int]]] = None,
+    ladder: Optional[Sequence[int]] = None,
     stamps: Optional[dict] = None,
     dispatch: int = 0,
 ) -> np.ndarray:
     """Bool mask for a batch of (message, key, signature) triples, padded
-    and chunked by the pad ladder above (``plan``: a ``dispatch_plan()``
+    and chunked by the pad ladder above (``ladder``: the ``pad_ladder()``
     the caller resolved once; default: resolved here).  Chunks are
     dispatched back to back and fetched afterwards, so host prep of chunk
-    k+1 overlaps the device's work on chunk k.  With NARWHAL_VERIFY_MESH
-    and several visible devices each padded chunk is sharded across the
-    device mesh.  ``dispatched`` (padded shape -> count) is incremented
-    per dispatch.  ``stamps`` (verify-stage trace, metrics.VERIFY_STAGES)
-    receives ``enqueued`` when the last chunk's kernel call has returned
-    (host preparation, transfer in and launch done) and ``fetched`` when
-    the last mask is on the host, with ``pad`` and ``chunks``;
-    ``dispatch`` is the burst number the profiler annotations carry."""
+    k+1 overlaps the device's work on chunk k.  ``dispatched`` (padded
+    shape -> count) is incremented per dispatch.  ``stamps`` (verify-stage
+    trace, metrics.VERIFY_STAGES) receives ``enqueued`` when the last
+    chunk's kernel call has returned (host preparation, transfer in and
+    launch done) and ``fetched`` when the last mask is on the host, with
+    ``pad`` and ``chunks``; ``dispatch`` is the burst number the profiler
+    annotations carry."""
     n = len(messages)
     if n == 0:
         return np.zeros(0, dtype=bool)
-    program_for, ladder = plan or dispatch_plan()
+    ladder = ladder or pad_ladder()
     pending = []
     chunks = chunk_plan(n, ladder)
     with annotate("verify.dispatch", dispatch=dispatch):
@@ -640,7 +577,7 @@ def verify_batch_arrays(
                     messages[lo:hi], keys[lo:hi], sigs[lo:hi], pad
                 )
             with annotate("verify.launch", dispatch=dispatch):
-                out = program_for(pad)(*(jnp.asarray(a) for a in args))
+                out = verify_program(pad)(*(jnp.asarray(a) for a in args))
             pending.append((out, hi - lo))
             if dispatched is not None:
                 dispatched[pad] = dispatched.get(pad, 0) + 1
@@ -687,10 +624,9 @@ class TpuBackend:
         from concurrent.futures import ThreadPoolExecutor
 
         self.name = name
-        # Program per rung and pad ladder, resolved once: live dispatch
-        # and warm-up read the same one.
-        self._plan = dispatch_plan()
-        self.rungs: Tuple[int, ...] = self._plan[1]
+        # The pad ladder, resolved once: live dispatch and warm-up read
+        # the same one.
+        self.rungs: Tuple[int, ...] = pad_ladder()
         self._executor = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="tpu-verify"
         )
@@ -706,7 +642,7 @@ class TpuBackend:
     ) -> List[bool]:
         return list(
             verify_batch_arrays(
-                messages, keys, sigs, self._dispatched, self._plan
+                messages, keys, sigs, self._dispatched, self.rungs
             )
         )
 
@@ -740,7 +676,7 @@ class TpuBackend:
             t0 = time.perf_counter()
             mask = list(
                 verify_batch_arrays(
-                    messages, keys, sigs, self._dispatched, self._plan,
+                    messages, keys, sigs, self._dispatched, self.rungs,
                     stamps=stamps, dispatch=seq,
                 )
             )
@@ -764,7 +700,7 @@ class TpuBackend:
 
         ladder = self.rungs
         for n in ladder:
-            wrong = wrong_answers(self._plan[0](n), n)
+            wrong = wrong_answers(verify_program(n), n)
             if wrong is not None:
                 raise RuntimeError(f"verify kernel at rung {n}: {wrong}")
         stats = compile_stats()
@@ -772,8 +708,7 @@ class TpuBackend:
         metrics.detail_fn("crypto.verify.device", self.device_report)
         return (
             "rungs {}, {} programs built in {:.1f} s (trace {:.1f} s), "
-            "{} of them loaded from program files ({} files rejected), "
-            "persistent cache {} hits / {} misses".format(
+            "{} of them loaded from program files ({} files rejected)".format(
                 ",".join(map(str, ladder)),
                 stats["programs_built"],
                 stats["trace_seconds"] + stats["lower_seconds"]
@@ -781,8 +716,6 @@ class TpuBackend:
                 stats["trace_seconds"],
                 stats["programs_from_file"],
                 stats["program_files_rejected"],
-                stats["cache_hits"],
-                stats["cache_misses"],
             )
         )
 

@@ -1,13 +1,13 @@
-"""Compiled programs kept as files beside the persistent compile cache.
+"""Compiled programs kept as files: this package's one compile cache.
 
 JAX's persistent cache is keyed by the LOWERED module, so a process has to
 trace and lower a program again (for the verify kernel ~5.6 s a rung on
 the chip's host, PERF.md, PR 27) only to compute the key of an executable
-that is already on disk.  Here a compiled program is also kept whole, as
+that is already on disk; it is off for the process (ops/__init__.py).
+Here a compiled program is kept whole, as
 `jax.experimental.serialize_executable` writes it, under a name and a key
 that a process can compute WITHOUT tracing: `resolve()` loads that file
-where it is sound and only otherwise traces, lowers and builds (beside
-the persistent cache, see `_compile_fresh`) and then writes it.
+where it is sound and only otherwise traces, lowers, builds and writes it.
 
 One file per program and device: the NAME says which program for which
 device (program, variant, platform, device kind, device id), the KEY in
@@ -17,7 +17,7 @@ stale build is overwritten where it lies and builds for different devices
 lie side by side.  The key is compared before a byte of the payload is
 read; the payload is unpickled only after its length and digest matched
 what the writer recorded, and only ever comes from the directory this
-program writes its own compile cache to.
+program writes its own program files to (`ops.program_dir()`).
 
 Every way a file can be wrong (unreadable, another key, cut short, not
 loadable, wrong answers) is counted in the compile ledger
@@ -118,8 +118,8 @@ def store(path: str, key: dict, compiled) -> None:
         "payload_sha256": hashlib.sha256(packed).hexdigest(),
     }
     os.makedirs(os.path.dirname(path), exist_ok=True)
-    # A name of this writer's own, and the mode the umask gives (as the
-    # persistent cache's entries have; mkstemp would make it 0600).
+    # A name of this writer's own, and the mode the umask gives (mkstemp
+    # would make it 0600).
     tmp = f"{path}.{os.getpid()}-{threading.get_ident()}.writing"
     try:
         with open(tmp, "wb") as f:
@@ -201,32 +201,9 @@ def resolve(
     if why_not is not None:
         count_program_file(load_s, used=False)
         log.warning("Program file %s not used (%s): building it anew", path, why_not)
-    program = _compile_fresh(jitted.lower(*abstract_args))
+    program = jitted.lower(*abstract_args).compile()
     try:
         store(path, key, program)
     except OSError as e:
         log.warning("Program file %s not written: %s", path, e)
     return program
-
-
-def _compile_fresh(lowered):
-    """Compile ``lowered`` with JAX's persistent cache out of the way: a
-    program file is only ever written from an executable this process
-    compiled itself.  One that the persistent cache handed back cannot be
-    written out again whole: XLA:CPU serializes such an executable
-    without its kernels (the 16-row verify program: 124.8 MB against
-    130.6, and the file then loads and fails its first call with "Function
-    concatenate.6_kernel not found"; sandbox, PR 30).  The program file is
-    this program's cache from here on, so nothing is lost but a hit in
-    the one start that finds JAX's cache warm and no sound file."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    # JAX decides once whether it uses the cache and keeps the answer.
-    compilation_cache.reset_cache()
-    try:
-        return lowered.compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", enabled)
-        compilation_cache.reset_cache()

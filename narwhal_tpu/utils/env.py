@@ -349,13 +349,6 @@ _VARS = [
         "batch window is enabled (bounds device batch shape and the "
         "latency added ahead of the first message's replay).",
     ),
-    EnvVar(
-        "NARWHAL_VERIFY_MESH", "flag", False,
-        "EXPERIMENTAL: shard the batched verify across every visible "
-        "JAX device (jax.sharding.Mesh + shard_map over the batch axis) "
-        "so crypto throughput scales with chips; single-device hosts "
-        "fall back to the plain vmapped kernel.",
-    ),
     # -- device plane ---------------------------------------------------------
     EnvVar(
         "NARWHAL_FIELD_DTYPE", "str", "int32",

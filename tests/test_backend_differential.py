@@ -277,32 +277,6 @@ def test_batch_positions_and_padding_boundaries():
     assert t == [i % 3 != 0 for i in range(19)]
 
 
-def test_mesh_sharded_verify_matches_single_device(monkeypatch):
-    """NARWHAL_VERIFY_MESH=1 (stretch): the shard_map-sharded kernel
-    over the conftest's 8-device virtual CPU mesh must produce the
-    exact mask the single-device kernel does, across a mixed
-    valid/invalid batch that exercises the raised pad floor
-    (16 x devices)."""
-    kp = KeyPair.generate(rng.randbytes(32))
-    cases = []
-    for i in range(21):
-        m = rng.randbytes(32)
-        s = sign(kp, m)
-        if i % 4 == 0:
-            s = s[:32] + (E.L_ORDER + 5).to_bytes(32, "little")
-        cases.append((m, bytes(kp.name), s))
-    plain = tpu_mask(cases)
-    monkeypatch.setenv("NARWHAL_VERIFY_MESH", "1")
-    assert E.mesh_devices() == len(jax.devices()) > 1
-    sharded = tpu_mask(cases)
-    assert sharded == plain == [i % 4 != 0 for i in range(21)]
-
-
-def test_mesh_flag_off_is_single_device(monkeypatch):
-    monkeypatch.delenv("NARWHAL_VERIFY_MESH", raising=False)
-    assert E.mesh_devices() == 1
-
-
 def test_backend_seam_masks_match_cpu_backend():
     """The crypto.backend seam itself: TpuBackend.verify_batch_mask ==
     CpuBackend.verify_batch_mask over a mixed valid/hostile batch of

@@ -13,7 +13,6 @@ shape only — it costs half a minute and the suite's time limit is shared.
 
 import os
 
-import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
@@ -25,7 +24,6 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 @pytest.fixture(scope="module")
 def one_chip():
     from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -34,25 +32,21 @@ def one_chip():
         )
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # A compile for a described device is written to the persistent cache
-    # but cannot be read back without the chip: the next run would warn
-    # and compile again.  Off around these tests.
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", True)
-    compilation_cache.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture(scope="module")
 def lowered_verify(one_chip):
-    """`_verify_kernel` lowered once for the bottom rung on the described
-    chip (~7 s of Python tracing and lowering): one test compiles it
-    (~20 s), one reads it."""
-    from narwhal_tpu.ops import ed25519 as E
+    """The driver's `entry()` (the un-jitted `_verify_kernel` at the
+    bottom rung) lowered once on the described chip (~7 s of Python
+    tracing and lowering): one test compiles it (~20 s), two read it."""
+    from __graft_entry__ import entry
 
-    b = E.CHIP_RUNGS[0]
-    return b, E._verify_kernel.lower(*E.kernel_args(b, sharding=one_chip))
+    fn, args = entry()
+    abstract = [
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip) for a in args
+    ]
+    return len(args[0]), jax.jit(fn).lower(*abstract)
 
 
 def test_verify_kernel_compiles_for_v5e_at_bottom_rung(lowered_verify):
@@ -86,16 +80,16 @@ def test_verify_kernel_names_its_phases_and_keeps_its_name(lowered_verify):
     )
 
 
-def test_commit_step_compiles_for_v5e_at_n50(one_chip):
-    from __graft_entry__ import commit_fixture, make_commit_step
+def test_entry_lowers_to_the_program_a_backend_builds(lowered_verify):
+    """What the hook lowers is what `verify_program` builds for the
+    bottom chip rung: the same function at the same nine abstract
+    arrays, so the module above IS the node's program."""
+    from narwhal_tpu.ops import ed25519 as E
 
-    window, n = 64, 50
-    args = [
-        jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype, sharding=one_chip)
-        for a in commit_fixture(0, window, n)
+    b, lowered = lowered_verify
+    assert b == E.CHIP_RUNGS[0]
+    got, _ = lowered.in_avals
+    assert [(a.shape, a.dtype) for a in got] == [
+        (w.shape, w.dtype) for w in E.kernel_args(b)
     ]
-    args[5] = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    compiled = jax.jit(make_commit_step(window)).lower(*args).compile()
-    support, committed, reach = compiled.out_info
-    assert reach.shape == (window, n)
-    assert compiled.memory_analysis() is not None
+    assert "module @jit__verify_kernel" in lowered.as_text()

@@ -428,30 +428,32 @@ def test_commit_with_crash_fault(run):
     run(go())
 
 
+def test_prewarm_cli_needs_a_backend(capsys):
+    """There is nothing to warm without one: no `--crypto-backend`, no run."""
+    from narwhal_tpu.node.main import main as node_main
+
+    with pytest.raises(SystemExit) as refused:
+        node_main(["prewarm"])
+    assert refused.value.code == 2
+    assert "--crypto-backend" in capsys.readouterr().err
+
+
 def test_prewarm_cli(tmp_path, monkeypatch):
     """`node prewarm --crypto-backend jax` builds the verify kernel for
-    every rung of the pad ladder (and optionally the consensus kernel)
-    and exits 0 — the step the bench harness runs before spawning
-    SEVERAL device-backed nodes so their boot warmup is a load of the
-    program files it wrote.
+    every rung of the pad ladder and exits 0 — the step the bench harness
+    runs before spawning SEVERAL device-backed nodes so their boot warmup
+    is a load of the program files it wrote.
     Runs on the CPU jax backend here, on the tests' one-rung ladder."""
     from narwhal_tpu.node.main import main as node_main
     from narwhal_tpu.ops import programs
-    from tests.common import committee
 
     # The program file goes to this test's directory, not the checkout's.
     monkeypatch.setattr(programs, "program_dir", lambda: str(tmp_path / "programs"))
 
-    c = committee(base_port=15200)
-    path = str(tmp_path / "committee.json")
-    c.export(path)
     from narwhal_tpu.crypto import backend as crypto_backend
 
     try:
-        rc = node_main(
-            ["prewarm", "--committee", path, "--crypto-backend", "jax",
-             "--experimental-consensus-kernel", "--gc-depth", "4"]
-        )
+        rc = node_main(["prewarm", "--crypto-backend", "jax"])
     finally:
         # prewarm selects the jax backend process-globally; put the
         # default back so later tests in this session see cpu.

@@ -284,7 +284,7 @@ def test_ladder_follows_the_platform_not_a_knob(monkeypatch):
     resolves its ladder once, at construction."""
     from narwhal_tpu.ops.ed25519 import TpuBackend
 
-    assert E.dispatch_plan() == (E.verify_program, E.CPU_RUNGS)
+    assert E.pad_ladder() == E.CPU_RUNGS
     assert E.CPU_RUNGS == (16,) and E.CHIP_RUNGS == (128, 512)
     backend = TpuBackend("jax")
 
@@ -292,7 +292,7 @@ def test_ladder_follows_the_platform_not_a_knob(monkeypatch):
         platform = "tpu"
 
     monkeypatch.setattr(E.jax, "devices", lambda: [Chip()])
-    assert E.dispatch_plan() == (E.verify_program, (128, 512))
+    assert E.pad_ladder() == (128, 512)
     assert backend.rungs == (16,)  # resolved before the platform "changed"
     assert TpuBackend("jax").rungs == (128, 512)
 
@@ -317,6 +317,21 @@ def test_warmup_builds_the_ladder_and_reports_it():
     # Peak device memory as the platform reports it (jax-cpu keeps no
     # allocator statistics: 0, never a missing key).
     assert report["memory_peak_bytes"] == E.memory_peak_bytes() >= 0
+
+
+def test_compile_ledger_counts_builds_and_program_files_only():
+    """The ledger's keys are what the ready line, the
+    `crypto.verify.device` detail and the benchmark's
+    `verifier.program_build_s` read: seconds of trace, lowering and
+    build, and how the programs came.  JAX's persistent cache is off
+    (`test_program_directory_and_no_persistent_cache`) and nothing of it
+    is counted."""
+    from narwhal_tpu import ops
+
+    assert set(ops.compile_stats()) == {
+        "programs_built", "trace_seconds", "lower_seconds", "build_seconds",
+        "programs_from_file", "program_files_rejected",
+    }
 
 
 def adversarial_batch():
@@ -434,11 +449,12 @@ def test_dispatch_thread_hands_back_its_stamps(n, pad, chunks):
 
 
 @pytest.mark.parametrize("placed_from_outside", [True, False])
-def test_compile_cache_directory(tmp_path, placed_from_outside):
-    """JAX_COMPILATION_CACHE_DIR set: the package sets NO cache directory
-    (JAX reads the variable itself).  Unset: one fixed path inside the
-    checkout — never $HOME, a temp name, a pid or a time — so every
-    process of a run shares it."""
+def test_program_directory_and_no_persistent_cache(tmp_path, placed_from_outside):
+    """JAX_COMPILATION_CACHE_DIR set: that directory holds the program
+    files.  Unset: one fixed path inside the checkout — never $HOME, a
+    temp name, a pid or a time — so every process of a run shares it.
+    Either way JAX's persistent cache is off once the package is
+    imported: the program files are the one compile cache."""
     import os
     import subprocess
     import sys
@@ -452,11 +468,12 @@ def test_compile_cache_directory(tmp_path, placed_from_outside):
     out = subprocess.run(
         [sys.executable, "-c",
          "import jax, narwhal_tpu.ops; "
-         "print(jax.config.jax_compilation_cache_dir)"],
+         "print(narwhal_tpu.ops.program_dir()); "
+         "print(jax.config.jax_enable_compilation_cache)"],
         env=env, capture_output=True, text=True, timeout=120, cwd=str(tmp_path),
     )
     assert out.returncode == 0, out.stderr
-    assert out.stdout.strip() == expected
+    assert out.stdout.split() == [expected, "False"]
 
 
 def test_chip_parents_and_cpu_entry_points_stay_off_jax():

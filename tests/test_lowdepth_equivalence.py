@@ -308,15 +308,6 @@ def test_classic_default_and_env_selection(tmp_path, monkeypatch):
     assert resolve_commit_rule("lowdepth") == "lowdepth"
 
 
-def test_kernel_refuses_lowdepth(tmp_path):
-    with pytest.raises(ValueError, match="classic walk only"):
-        Consensus(
-            committee(), 50,
-            asyncio.Queue(), asyncio.Queue(), asyncio.Queue(),
-            use_kernel=True, commit_rule="lowdepth",
-        )
-
-
 def test_checkpoint_refuses_cross_rule_restore(tmp_path):
     """A checkpoint written under one rule must refuse — loudly, at boot,
     NOT via the torn-file fresh-frontier fallback — to restore under the

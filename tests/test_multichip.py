@@ -1,10 +1,9 @@
-"""Multi-chip sharding correctness: the committee-sharded commit step on the
-8-device virtual CPU mesh (tests/conftest.py) must produce bit-identical
-results to the unsharded single-device run.
+"""The driver's hooks (``__graft_entry__``) and the multi-chip sharding of
+the verify kernel: the batch axis sharded over the 8-device virtual CPU
+mesh (tests/conftest.py) must give the mask of the known answers.
 
-This exercises the SAME program the driver runs (``__graft_entry__``'s
-commit-step builder) — the driver validates that the path compiles+runs;
-this test validates that the sharded numerics match.
+Hook and test are one program: the in-process case calls the hook's own
+body, the subprocess case the hook as the driver calls it.
 """
 
 import numpy as np
@@ -12,85 +11,31 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import Mesh  # noqa: E402
 
-from __graft_entry__ import (  # noqa: E402
-    commit_fixture,
-    make_commit_step,
-    shard_commit_args,
-)
-
-
-def test_sharded_commit_step_matches_unsharded():
-    n_devices = 8
-    assert len(jax.devices()) >= n_devices, (
-        "conftest must provision the 8-device CPU mesh"
-    )
-    window, n = 16, 4 * n_devices
-    fixture = commit_fixture(1, window, n)
-    commit_step = make_commit_step(window)
-
-    # Unsharded ground truth on one device.
-    (parent, exists, leader_onehot, is_leader_slot, stake,
-     anchor_slot, anchor_onehot) = fixture
-    ref = commit_step(
-        jnp.asarray(parent), jnp.asarray(exists), jnp.asarray(leader_onehot),
-        jnp.asarray(is_leader_slot), jnp.asarray(stake),
-        jnp.int32(anchor_slot), jnp.asarray(anchor_onehot),
-    )
-
-    # Committee-axis sharded run over the mesh.
-    mesh = Mesh(np.array(jax.devices()[:n_devices]), ("committee",))
-    args = shard_commit_args(mesh, fixture)
-    with mesh:
-        got = jax.jit(commit_step)(*args)
-        jax.block_until_ready(got)
-
-    for r, g in zip(ref, got):
-        np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
-
-
-def test_sharded_verify_batch_matches_unsharded():
+def test_sharded_verify_batch_matches_known_answers():
     """The ed25519 batch verifier data-parallel over the mesh: the batch
-    axis sharded across 8 devices must produce the same accept/reject mask
-    as the single-device run — the multi-chip scaling story for the
+    axis sharded across 8 devices accepts the valid rows and rejects the
+    forged and the malleable one — the multi-chip scaling story for the
     per-round crypto (one chip per primary today; batch-sharded chips per
     primary is the same program with a different mesh)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from __graft_entry__ import _dryrun_multichip_impl
 
-    from narwhal_tpu.crypto import KeyPair
-    from narwhal_tpu.crypto.digest import Digest
+    assert len(jax.devices()) >= 8, "conftest must provision the 8-device CPU mesh"
+    _dryrun_multichip_impl(8)
+
+
+def test_entry_hands_over_the_bottom_chip_rung_as_host_arrays():
+    """``entry()``: the un-jitted verify kernel and, as numpy arrays (no
+    device array among them: the caller decides where they go), exactly
+    what `prepare_batch` hands the kernel at the chip's bottom rung."""
+    from __graft_entry__ import entry
     from narwhal_tpu.ops import ed25519 as E
 
-    n_devices = 8
-    assert len(jax.devices()) >= n_devices, (
-        "conftest must provision the 8-device CPU mesh"
-    )
-    batch = 16  # one pad shape: divisible by the mesh, tiny for CPU compile
-    kp = KeyPair.generate(b"\x07" * 32)
-    msgs, keys, sigs = [], [], []
-    for i in range(batch):
-        m = bytes(Digest(bytes([i]) * 32))
-        msgs.append(m)
-        keys.append(kp.name)
-        sigs.append(kp.sign(Digest(m)))
-    sigs[3] = type(sigs[3])(bytes(64))  # one forgery: mask must reject it
-
-    args = E.prepare_batch(msgs, keys, sigs, batch)
-    ref = np.asarray(E._verify_kernel(*(jnp.asarray(a) for a in args)))
-    assert ref.tolist() == [i != 3 for i in range(batch)]
-
-    mesh = Mesh(np.array(jax.devices()[:n_devices]), ("batch",))
-    # Every per-signature array is sharded on its batch axis (axis 0 for
-    # all of prepare_batch's outputs).
-    sharded = [
-        jax.device_put(jnp.asarray(a), NamedSharding(mesh, P("batch")))
-        for a in args
-    ]
-    with mesh:
-        got = np.asarray(E._verify_kernel(*sharded))
-    np.testing.assert_array_equal(ref, got)
+    fn, args = entry()
+    assert fn is E._verify_kernel.__wrapped__
+    assert all(type(a) is np.ndarray for a in args)
+    want = E.kernel_args(E.CHIP_RUNGS[0])
+    assert [(a.shape, a.dtype) for a in args] == [(w.shape, w.dtype) for w in want]
 
 
 def test_dryrun_multichip_subprocess_green():

@@ -442,15 +442,6 @@ def test_env_and_arg_select_multileader(tmp_path, monkeypatch):
     assert resolve_commit_rule("multileader") == "multileader"
 
 
-def test_kernel_refuses_multileader(tmp_path):
-    with pytest.raises(ValueError, match="classic walk only"):
-        Consensus(
-            committee(), 50,
-            asyncio.Queue(), asyncio.Queue(), asyncio.Queue(),
-            use_kernel=True, commit_rule="multileader",
-        )
-
-
 def test_checkpoint_refuses_cross_rule_restore_all_six(tmp_path):
     """A checkpoint written under any rule must refuse — loudly, naming
     BOTH rules, NOT via the torn-file fresh-frontier fallback — to

@@ -14,6 +14,8 @@ index membership == DAG membership, and the incremental support counter
 import asyncio
 import random
 
+import pytest
+
 from narwhal_tpu import metrics
 from narwhal_tpu.consensus import Consensus, Tusk
 from narwhal_tpu.consensus.golden import GoldenTusk
@@ -58,29 +60,58 @@ def _random_dag_certs(rng, rounds):
     return certs
 
 
-def test_reference_scenarios_equivalence():
-    """The four reference consensus_tests.rs scenarios, golden vs indexed."""
-    c = committee()
-    names = sorted_names()
-
-    # commit_one
+def _commit_one(c, names):
     certs, next_parents = make_certificates(1, 4, genesis_digests(c), names)
     _, trigger = mock_certificate(names[0], 5, next_parents)
-    committed = both_walks(certs + [trigger])
-    assert [x.round for x in committed] == [1, 1, 1, 1, 2]
+    return certs + [trigger], lambda out: [x.round for x in out] == [1, 1, 1, 1, 2]
 
-    # dead_node
+
+def _dead_node(c, names):
     certs, _ = make_certificates(1, 9, genesis_digests(c), names[:3])
-    assert len(both_walks(certs)) == 16
+    return certs, lambda out: len(out) == 16
 
-    # missing_leader
-    certs = []
-    out, parents = make_certificates(1, 2, genesis_digests(c), names[1:])
+
+def _not_enough_support(c, names):
+    """The round-2 leader has one supporter in round 3 (f+1 = 2 needed),
+    so it commits only later, through the round-4 leader's chain."""
+    certs, parents = make_certificates(1, 1, genesis_digests(c), names[:3])
+    leader_2_digest, cert = mock_certificate(names[0], 2, parents)
+    certs.append(cert)
+    out, parents = make_certificates(2, 2, parents, names[1:])
     certs.extend(out)
-    out, parents = make_certificates(3, 6, parents, names)
+    next_parents = set()
+    for name, cited in (
+        (names[1], parents),
+        (names[2], parents),
+        (names[0], parents | {leader_2_digest}),
+    ):
+        d, cert = mock_certificate(name, 3, cited)
+        certs.append(cert)
+        next_parents.add(d)
+    out, parents = make_certificates(4, 6, next_parents, names[:3])
     certs.extend(out)
     _, trigger = mock_certificate(names[0], 7, parents)
-    both_walks(certs + [trigger])
+    return certs + [trigger], lambda out: [
+        x.round for x in out if x.origin == names[0] and x.round % 2 == 0
+    ] == [2, 4]
+
+
+def _missing_leader(c, names):
+    certs, parents = make_certificates(1, 2, genesis_digests(c), names[1:])
+    out, parents = make_certificates(3, 6, parents, names)
+    _, trigger = mock_certificate(names[0], 7, parents)
+    return certs + out + [trigger], lambda out: bool(out)
+
+
+@pytest.mark.parametrize(
+    "scenario",
+    [_commit_one, _dead_node, _not_enough_support, _missing_leader],
+    ids=lambda f: f.__name__.lstrip("_"),
+)
+def test_reference_scenarios_equivalence(scenario):
+    """The four reference consensus_tests.rs scenarios, golden vs indexed."""
+    certs, holds = scenario(committee(), sorted_names())
+    assert holds(both_walks(certs))
 
 
 def test_multi_leader_burst_equivalence():
@@ -131,29 +162,39 @@ def test_gc_window_wrap_equivalence():
     } == {r: set(v) for r, v in golden.state.dag.items()}
 
 
-def test_checkpoint_restore_equivalence():
+@pytest.mark.parametrize(
+    "gc_depth, rounds",
+    [(50, 4), (6, 20)],
+    ids=["near_frontier", "far_frontier"],
+)
+def test_checkpoint_restore_equivalence(gc_depth, rounds):
     """Both walks restored from the same frontier blob must ignore a full
     catch-up replay of pre-crash history and then commit new rounds
-    byte-identically."""
+    byte-identically.  `far_frontier`: the blob's frontier lies more than
+    a whole gc window above the restored (empty) DAG."""
     c = committee()
     names = sorted_names()
-    certs, next_parents = make_certificates(1, 4, genesis_digests(c), names)
-    _, trigger = mock_certificate(names[0], 5, next_parents)
+    certs, next_parents = make_certificates(1, rounds, genesis_digests(c), names)
+    _, trigger = mock_certificate(names[0], rounds + 1, next_parents)
 
-    first = GoldenTusk(c, gc_depth=50, fixed_coin=True)
+    first = GoldenTusk(c, gc_depth=gc_depth, fixed_coin=True)
     assert feed(first, certs + [trigger])
     blob = first.state.snapshot_bytes()
+    if rounds > gc_depth:
+        assert first.state.last_committed_round > gc_depth + 1
 
-    golden = GoldenTusk(c, gc_depth=50, fixed_coin=True)
+    golden = GoldenTusk(c, gc_depth=gc_depth, fixed_coin=True)
     golden.state.restore(blob)
-    indexed = Tusk(c, gc_depth=50, fixed_coin=True)
+    indexed = Tusk(c, gc_depth=gc_depth, fixed_coin=True)
     indexed.state.restore(blob)
     assert feed(golden, certs + [trigger]) == []
     assert feed(indexed, certs + [trigger]) == []
 
-    more, tail_parents = make_certificates(5, 8, next_parents, names)
-    more = more[1:]  # round-5 leader already exists as `trigger`
-    _, trigger2 = mock_certificate(names[0], 9, tail_parents)
+    more, tail_parents = make_certificates(
+        rounds + 1, rounds + 6, next_parents, names
+    )
+    more = more[1:]  # that round's leader already exists as `trigger`
+    _, trigger2 = mock_certificate(names[0], rounds + 7, tail_parents)
     got = feed(indexed, more + [trigger2])
     want = feed(golden, more + [trigger2])
     assert [bytes(x.digest()) for x in got] == [
@@ -162,25 +203,31 @@ def test_checkpoint_restore_equivalence():
     assert got, "the restored instances must keep committing"
 
 
-def test_fuzz_equivalence_in_and_out_of_order():
-    rng = random.Random(0x1D5)
-    for trial in range(6):
+@pytest.mark.parametrize(
+    "order, seed",
+    [("in_order", 0x1D5 + i) for i in range(6)]
+    + [("out_of_order", 0xBEEF + i) for i in range(4)],
+)
+def test_fuzz_equivalence_in_and_out_of_order(order, seed):
+    """One random DAG a case, each from its own seed, so a failing DAG is
+    named by its id."""
+    rng = random.Random(seed)
+    if order == "in_order":
         certs = _random_dag_certs(rng, rounds=rng.randint(6, 20))
-        order = list(certs)
-        order.sort(key=lambda x: (x.round, rng.random()))
-        both_walks(order)
-    for trial in range(4):
+        certs.sort(key=lambda x: (x.round, rng.random()))
+    else:
         certs = _random_dag_certs(rng, rounds=rng.randint(6, 16))
-        order = list(certs)
         # Children ahead of their parents in delivery order.
-        order.sort(key=lambda x: x.round + rng.uniform(-2.2, 0.0))
-        both_walks(order)
+        certs.sort(key=lambda x: x.round + rng.uniform(-2.2, 0.0))
+        assert any(
+            a.round > b.round for a, b in zip(certs, certs[1:])
+        ), "fixture produced no out-of-order pair"
+    both_walks(certs)
 
 
-def test_fuzz_small_gc_depth_equivalence():
-    rng = random.Random(0x6C)
-    for _ in range(3):
-        both_walks(_random_dag_certs(rng, rounds=14), gc_depth=4)
+@pytest.mark.parametrize("seed", [0x6C, 0x6D, 0x6E])
+def test_fuzz_small_gc_depth_equivalence(seed):
+    both_walks(_random_dag_certs(random.Random(seed), rounds=14), gc_depth=4)
 
 
 # -- white-box: the two new indexed structures --------------------------------
