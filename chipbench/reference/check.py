@@ -28,7 +28,11 @@ Layer by layer:
 - Tusk: each replica's recorded commit sequence is what the plain rule
   makes of the certificates it was given, in the order it was given
   them, and every sequence is a prefix of the longest
-  (``replica_order_mismatches``).
+  (``replica_order_mismatches``).  WHICH plain rule is the replica's own
+  statement: the ``M`` record that follows its segment's ``R`` names it
+  (``tusk.RULES``), and every replica of a run has to name the same one
+  (``declared_rule``).  The guarantee is the one sequence; a rule is how
+  a replica gets there, and it is held to the one it says it runs.
 """
 
 from __future__ import annotations
@@ -42,7 +46,7 @@ import numpy as np
 from cryptography.exceptions import InvalidSignature
 from cryptography.hazmat.primitives.asymmetric.ed25519 import Ed25519PublicKey
 
-from .tusk import PlainTusk
+from .tusk import RULES, PlainTusk
 from .wire import decode_certificate, sha
 
 LIMITS = {
@@ -142,6 +146,22 @@ def certificate_valid(cert, keys: set, quorum: int) -> bool:
     return all(openssl_verify(digest, name, sig) for name, sig in cert.votes)
 
 
+def declared_rule(records: List[Tuple[bytes, bytes]]) -> Optional[str]:
+    """The commit rule one replica's audit segment declares: the payload
+    of the ``M`` record that stands right after its ``R``.  None where
+    the segment does not start so, or names no rule the reference has."""
+    if len(records) < 2 or records[0] != (b"R", b"") or records[1][0] != b"M":
+        return None
+    rule = records[1][1].decode("ascii", "replace")
+    return rule if rule in RULES else None
+
+
+def commit_rule(art: Artifacts) -> str:
+    """What a run's verdict line says its replicas were held to: the one
+    rule they all declared (anything else reads ``correct`` false)."""
+    return "+".join(sorted({declared_rule(r) or "undeclared" for r in art.audits}))
+
+
 def replay(art: Artifacts) -> Tuple[int, int, List[List[bytes]], List[set]]:
     """(order mismatches, invalid certificates, each replica's committed
     certificate digests, each replica's committed batch digests)."""
@@ -149,14 +169,15 @@ def replay(art: Artifacts) -> Tuple[int, int, List[List[bytes]], List[set]]:
     mismatches = invalid = 0
     judged: Dict[bytes, bool] = {}
     sequences, batches = [], []
-    for records in art.audits:
-        tusk = PlainTusk(art.sorted_keys, art.gc_depth)
+    rules = [declared_rule(records) for records in art.audits]
+    for records, rule in zip(art.audits, rules):
+        # A segment that declares no rule is broken whatever it holds; it
+        # is still read (as classic) for what it says it committed.
+        tusk = PlainTusk(art.sorted_keys, art.gc_depth, rule or "classic")
         inserted, recorded, expected = {}, [], []
-        broken = not records or records[0][0] != b"R" or records[0][1] != b""
-        for tag, payload in records[1:]:
-            if tag == b"M":
-                broken |= payload != b"classic"
-            elif tag == b"C":
+        broken = rule is None
+        for tag, payload in records[2:]:
+            if tag == b"C":
                 recorded.append(payload)
             elif tag == b"I":
                 try:
@@ -169,7 +190,7 @@ def replay(art: Artifacts) -> Tuple[int, int, List[List[bytes]], List[set]]:
                     invalid += not judged[payload]
                 inserted[cert.digest()] = cert
                 expected.extend(c.digest() for c in tusk.process_certificate(cert))
-            else:
+            else:  # a second marker among them: one segment, one rule
                 broken = True
         # A replica is cut off between a burst's records at worst, so what
         # it recorded is the head of what the plain rule commits.
@@ -182,6 +203,9 @@ def replay(art: Artifacts) -> Tuple[int, int, List[List[bytes]], List[set]]:
         })
     longest = max(sequences, key=len) if sequences else []
     mismatches += sum(1 for s in sequences if s != longest[:len(s)])
+    # Two rules in one run: every replica that declares another than
+    # replica 0 counts once, whether or not the sequences have parted yet.
+    mismatches += sum(1 for rule in rules if rule != rules[0])
     return mismatches, invalid, sequences, batches
 
 

@@ -16,6 +16,11 @@ would have produced the wrong answer:
   reject theirs, and the one has to show);
 - ``order_swapped``: two neighbouring commits of the last replica change
   places (guarantee: every replica the same order);
+- ``rule_mislabelled``: the last replica's segment declares the other
+  commit rule than the one it ran (guarantee: every replica of a run
+  commits by one rule, the one its segment declares; on a DAG in which
+  both rules decide every leader the sequences are the same and one is
+  a prefix of the other, so only the declaration shows);
 - ``commit_withheld``: the last replica's commit sequence stops before
   the window's last batches (an answer that never comes);
 - ``batch_dropped``: a committed batch that holds a due sample is gone
@@ -87,6 +92,14 @@ def order_swapped(art, rng):
     return out
 
 
+def rule_mislabelled(art, rng):
+    out = _copy(art)
+    ran = check.declared_rule(art.audits[-1])
+    other = check.RULES[1 - check.RULES.index(ran)]
+    out.audits[-1][1] = (b"M", other.encode("ascii"))
+    return out
+
+
 def commit_withheld(art, rng):
     out = _copy(art)
     records = out.audits[-1]
@@ -123,7 +136,7 @@ def sample_altered(art, rng):
 CONTROLS = {
     f.__name__: f
     for f in (quorum_short, forged_vote, verifier_accepts_all, order_swapped,
-              commit_withheld, batch_dropped, sample_altered)
+              rule_mislabelled, commit_withheld, batch_dropped, sample_altered)
 }
 
 

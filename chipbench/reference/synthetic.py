@@ -1,9 +1,9 @@
 """The reference put in the program's place: a whole run's artifacts made
 by the plain reference alone (keys from the seed, signed headers and
-votes, PlainTusk's order, batches with the clients' sample bytes), in the
-files and shapes a real run leaves behind.  The tests hold the comparison
-to it: untouched it has to read all zeros, and under every control of
-``control.py`` it has to fail.
+votes, PlainTusk's order under the rule asked for, batches with the
+clients' sample bytes), in the files and shapes a real run leaves behind.
+The tests hold the comparison to it: untouched it has to read all zeros,
+and under every control of ``control.py`` it has to fail.
 """
 
 from __future__ import annotations
@@ -32,7 +32,8 @@ def make_batch(sample_id: int, tx_size: int, n_tx: int, rng: random.Random) -> b
 
 
 def make_run(tmpdir: str, seed: int, config: dict, rounds: int = 24,
-             tx_size: int = 64, forged: int = 5) -> check.Artifacts:
+             tx_size: int = 64, forged: int = 5,
+             rule: str = "classic") -> check.Artifacts:
     rng = random.Random(seed)
     ids = make_identities(seed, config)
     alive = config["nodes"] - config["faults"]
@@ -65,8 +66,8 @@ def make_run(tmpdir: str, seed: int, config: dict, rounds: int = 24,
 
     audits = []
     for _ in live:
-        tusk = PlainTusk(keys, gc_depth)
-        records = [(b"R", b""), (b"M", b"classic")]
+        tusk = PlainTusk(keys, gc_depth, rule)
+        records = [(b"R", b""), (b"M", rule.encode("ascii"))]
         for cert in stream:
             records.append((b"I", encode_certificate(cert, keys)))
             records.extend((b"C", c.digest()) for c in tusk.process_certificate(cert))

@@ -482,12 +482,15 @@ def main(argv=None) -> int:
         else:
             line["metrics"] = e2e
         line["device"] = device
+        # The rule the replicas' audit segments declared, and so the plain
+        # rule each was replayed through (reference/check.py).
+        line["commit_rule"] = check.commit_rule(art)
         line["compared"] = {
             k: {"value": v, "limit": check.LIMITS[k]} for k, v in numbers.items()
         }
         for k, v in numbers.items():
             say(f"compared {k} = {v} (limit {check.LIMITS[k]})")
-        say(f"correct = {correct}")
+        say(f"correct = {correct} (commit_rule {line['commit_rule']})")
         print(json.dumps(line), flush=True)
         return 0
     except RunFailure as e:

@@ -75,6 +75,33 @@ def test_configurations_keep_apart():
     assert all(c["guarantees"] == cfgs[0]["guarantees"] for c in cfgs)
 
 
+@pytest.mark.parametrize("path", files("configs"), ids=os.path.basename)
+def test_guarantees_name_the_property_and_no_setting_names_a_rule(path):
+    """The commit rule is admitted, not set: the guarantee names the
+    rules a replica may declare, `protocol_settings` names none (the
+    product's default is the program's to change), and a batch handed to
+    a primary is promised committed, as `samples_unanswered` holds."""
+    c = load(path)
+    rule = c["guarantees"]["commit_rule"]
+    assert "audit segment declares" in rule and "same on every replica" in rule
+    assert "classic" in rule and "lowdepth" in rule and "limit 0" in rule
+    settings = c["protocol_settings"].lower()
+    assert not any(w in settings for w in ("classic", "lowdepth", "commit rule"))
+    assert "handed to a primary is committed" in c["guarantees"]["batch_committed"]
+    assert "commit_rule" not in c["parameters"]
+
+
+def test_the_harness_has_no_key_for_the_commit_rule():
+    """No key, flag or variable: a rule left to a setting is what the
+    program's next PR is there to remove (ROADMAP, D2)."""
+    for name in ("run.py", "committee.py", "device_node.py", "harness.json"):
+        with open(os.path.join(CHIPBENCH, name)) as f:
+            text = f.read().lower()
+        assert "commit-rule" not in text and "narwhal_commit_rule" not in text
+    for path in files("workloads") + files("configs"):
+        assert not any("rule" in k and k != "commit_rule" for k in load(path))
+
+
 @pytest.mark.parametrize("path", files("layer_metrics"), ids=os.path.basename)
 def test_layer_metric_file(path):
     spec = load(path)

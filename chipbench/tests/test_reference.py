@@ -2,6 +2,7 @@
 run: sound artifacts read all zeros, every control fails, and the
 reference's byte formats are the program's."""
 
+import dataclasses
 import json
 import os
 import random
@@ -27,19 +28,25 @@ def config(name):
     return cfg
 
 
-@pytest.fixture(scope="module", params=["local-4n-f1", "10n-f3", "local-4n"])
+@pytest.fixture(scope="module", params=[
+    (name, rule) for name in ("local-4n-f1", "10n-f3", "local-4n")
+    for rule in check.RULES
+], ids="-".join)
 def art(request, tmp_path_factory):
+    name, rule = request.param
     return synthetic.make_run(
-        str(tmp_path_factory.mktemp(request.param)), 2147483659, config(request.param)
+        str(tmp_path_factory.mktemp(f"{name}-{rule}")), 2147483659, config(name),
+        rule=rule,
     )
 
 
-def test_sound_run_is_correct(art):
+def test_sound_run_is_correct(art, request):
     numbers = check.compare(art)
     assert art.due, "the synthetic run committed nothing"
     assert set(numbers) == set(check.LIMITS)
     assert all(v == 0 for v in numbers.values()), numbers
     assert check.verdict(numbers)
+    assert check.commit_rule(art) in request.node.callspec.id
 
 
 @pytest.mark.parametrize("name", sorted(control.CONTROLS))
@@ -54,20 +61,64 @@ EXPECTED = {
     "forged_vote": "certificates_invalid",
     "verifier_accepts_all": "verifier_reject_gap",
     "order_swapped": "replica_order_mismatches",
+    "rule_mislabelled": "replica_order_mismatches",
     "commit_withheld": "samples_unanswered",
     "batch_dropped": "samples_misread",
     "sample_altered": "samples_misread",
 }
 
 
-def test_each_control_fails_the_number_it_is_about(art):
-    for name, caught in control.report(art, 7).items():
-        assert EXPECTED[name] in caught, (name, caught)
+def test_there_are_eight_controls():
+    assert set(control.CONTROLS) == set(EXPECTED) and len(EXPECTED) == 8
+
+
+@pytest.mark.parametrize("name", sorted(EXPECTED))
+def test_each_control_fails_the_number_it_is_about_and_no_other(art, name):
+    numbers = check.compare(control.CONTROLS[name](art, random.Random(7)))
+    assert {k for k, v in numbers.items() if v > check.LIMITS[k]} == {EXPECTED[name]}
+
+
+def with_marker(art, replica, *markers):
+    """``art`` with replica's 'M' record replaced by ``markers``."""
+    audits = [list(r) for r in art.audits]
+    audits[replica][1:2] = [(b"M", m) for m in markers]
+    return dataclasses.replace(art, audits=audits)
+
+
+def test_replicas_that_declare_two_rules_are_refused(art):
+    """One count for every replica that declares another rule than
+    replica 0, even where its sequence is still a prefix."""
+    mixed = control.rule_mislabelled(art, None)
+    other = mixed.audits[-1][1][1]
+    assert check.commit_rule(mixed) == "classic+lowdepth"
+    numbers = check.compare(mixed)
+    assert numbers["replica_order_mismatches"] >= 1
+    assert all(v == 0 for k, v in numbers.items() if k != "replica_order_mismatches")
+    # Replica 0 the odd one out: every other replica differs from it.
+    first = check.compare(with_marker(art, 0, other))
+    assert first["replica_order_mismatches"] >= len(art.audits) - 1
+
+
+@pytest.mark.parametrize("markers", [
+    [b"multileader"], [b"Classic"], [b""], [b"\xff\xfe"], [],
+    [b"classic", b"classic"], [b"classic", b"lowdepth"],
+], ids=["multileader", "case", "empty", "not-ascii", "missing", "twice", "both"])
+def test_a_segment_that_declares_no_one_plain_rule_is_broken(art, markers):
+    numbers = check.compare(with_marker(art, 0, *markers))
+    assert numbers["replica_order_mismatches"] >= 1
+    assert not check.verdict(numbers)
+
+
+def test_a_marker_after_the_first_insert_is_broken(art):
+    audits = [list(r) for r in art.audits]
+    marker = audits[0].pop(1)
+    first_insert = next(i for i, (tag, _) in enumerate(audits[0]) if tag == b"I")
+    audits[0].insert(first_insert + 1, marker)
+    numbers = check.compare(dataclasses.replace(art, audits=audits))
+    assert numbers["replica_order_mismatches"] >= 1
 
 
 def test_device_numbers(art):
-    import dataclasses
-
     rest = art.device[1:]
     late = dataclasses.replace(
         art, device=[dict(art.device[0], programs_built=3)] + rest)
