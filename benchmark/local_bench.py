@@ -698,7 +698,7 @@ def main():
         "lowdepth = Mysticeti-style direct commits one round after the "
         "leader, multileader = 3 leader slots per even round anchoring "
         "on the lowest supported slot; unset inherits the environment "
-        "(default classic)",
+        "(unset there too: the product's default, lowdepth)",
     )
     parser.add_argument(
         "--tpu-primaries",

@@ -93,7 +93,7 @@ def capture(nodes: int, duration: int, rate: int, seed: int,
         ),
         "nodes": nodes,
         "quorum": quorum,
-        "commit_rule": commit_rule or "classic",
+        "commit_rule": art["commit_rule"],
         "verdicts_ok": art["ok"],
         "schedule": art["schedule"],
         "wall": art["wall"],
@@ -148,7 +148,8 @@ def main(argv=None) -> int:
         "--commit-rule",
         choices=["classic", "lowdepth", "multileader"],
         default=None,
-        help="consensus commit rule for the committee (default: classic)",
+        help="consensus commit rule for the committee (default: the "
+        "product's, NARWHAL_COMMIT_RULE's registry default)",
     )
     ap.add_argument("--artifact", default="artifacts/wire_n20_r19.json")
     args = ap.parse_args(argv)

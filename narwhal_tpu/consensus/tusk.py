@@ -173,13 +173,15 @@ class CheckpointRuleMismatch(ValueError):
 
 def resolve_commit_rule(explicit: Optional[str] = None) -> str:
     """Effective commit rule: the explicit (CLI/constructor) value wins,
-    else the NARWHAL_COMMIT_RULE env knob, else classic.  Garbage raises
-    — a bench arm must never silently measure the wrong rule (the
+    else the NARWHAL_COMMIT_RULE env knob, else the product's default —
+    the registry default of that knob (utils/env.py), the ONE place it
+    is stated: `lowdepth`, the direct rule.  Garbage raises — a bench
+    arm must never silently measure the wrong rule (the
     NARWHAL_CRYPTO_BACKEND_STRICT precedent)."""
-    from ..utils.env import env_str
+    from ..utils.env import REGISTRY, env_str
 
     rule = explicit if explicit is not None else env_str("NARWHAL_COMMIT_RULE")
-    rule = (rule or "classic").strip().lower()
+    rule = (rule or REGISTRY["NARWHAL_COMMIT_RULE"].default).strip().lower()
     if rule not in COMMIT_RULES:
         raise ValueError(
             f"unknown commit rule {rule!r}; expected one of {COMMIT_RULES}"
@@ -244,9 +246,14 @@ class State:
                     raise CheckpointRuleMismatch(
                         f"checkpoint was written by the {rule!r} commit "
                         f"rule but this node runs {self.commit_rule!r}; "
-                        "refusing to restore — wipe the checkpoint (and "
-                        "accept re-delivery) or run the matching "
-                        "--commit-rule"
+                        "refusing to restore — one rule's frontier is "
+                        "not reinterpreted under another.  A committee "
+                        "changes its rule together (mixed-rule "
+                        "committees diverge): until it has, `--commit-"
+                        f"rule {rule}` keeps this node on the "
+                        "checkpoint's rule; once it has, wipe the "
+                        "checkpoint and accept re-delivery of what was "
+                        "already committed"
                     )
         if len(blob) < 18 or blob[:6] != self._CKPT_MAGIC:
             raise ValueError("checkpoint: bad magic")
@@ -376,6 +383,11 @@ class Tusk:
         self.support_observer: Optional[
             Callable[[Round, int, int, PublicKey], None]
         ] = None
+        # Optional hook fired once per commit decision with (direct,
+        # indirect, skipped) leader counts — see _note_decision.
+        self.decision_observer: Optional[
+            Callable[[int, int, int], None]
+        ] = None
 
     def leader(self, round: Round, dag: Dag) -> Optional[Tuple[Digest, Certificate]]:
         """Round-robin leader (a common coin in the full protocol —
@@ -446,6 +458,23 @@ class Tusk:
             if leader_digest in cert.header.parents
         )
 
+    def _note_decision(self, chain: List[Certificate]) -> None:
+        """Account for one commit decision, BEFORE its chain moves the
+        frontier: the leader that crossed this rule's gate (direct: 1),
+        the earlier leaders ``order_leaders`` linked to it (indirect),
+        and the even rounds between the previous frontier and the
+        decided leader that have no leader in the chain — dead, or
+        arrived and unlinked (skipped).  Every even round below the
+        frontier lands in exactly one of the three.  O(1) a decision;
+        a certificate that decides nothing never gets here."""
+        if self.decision_observer is not None:
+            rounds = (
+                chain[0].round // 2 - self.state.last_committed_round // 2
+            )
+            self.decision_observer(
+                1, len(chain) - 1, rounds - len(chain)
+            )
+
     def process_certificate(self, certificate: Certificate) -> List[Certificate]:
         """Insert a certificate; return the newly committed sequence
         (possibly empty).  Reference lib.rs:105-201."""
@@ -478,7 +507,9 @@ class Tusk:
         # sweep runs ONCE for the whole burst.
         log.debug("Leader %r has enough support", leader)
         sequence: List[Certificate] = []
-        for past_leader in reversed(self.order_leaders(leader)):
+        chain = self.order_leaders(leader)
+        self._note_decision(chain)
+        for past_leader in reversed(chain):
             for x in self.order_dag(past_leader):
                 state.note_committed(x)
                 sequence.append(x)
@@ -660,7 +691,9 @@ class LowDepthTusk(Tusk):
 
         log.debug("Leader %r has direct 2f+1 support", leader)
         sequence: List[Certificate] = []
-        for past_leader in reversed(self.order_leaders(leader)):
+        chain = self.order_leaders(leader)
+        self._note_decision(chain)
+        for past_leader in reversed(chain):
             for x in self.order_dag(past_leader):
                 state.note_committed(x)
                 sequence.append(x)
@@ -944,7 +977,9 @@ class MultiLeaderTusk(Tusk):
             "Slot %d leader %r has direct 2f+1 support", slot, leader
         )
         sequence: List[Certificate] = []
-        for past_leader in reversed(self.order_leaders(leader)):
+        chain = self.order_leaders(leader)
+        self._note_decision(chain)
+        for past_leader in reversed(chain):
             for x in self.order_dag(past_leader):
                 state.note_committed(x)
                 sequence.append(x)
@@ -1000,8 +1035,9 @@ class Consensus:
         commit_rule: Optional[str] = None,
     ) -> None:
         # Commit-rule selection (constructor arg > NARWHAL_COMMIT_RULE >
-        # classic) happens HERE so every harness that builds a Consensus
-        # rides the same resolution the node CLI does.
+        # the registry's default, lowdepth) happens HERE so every harness
+        # that builds a Consensus rides the same resolution the node CLI
+        # does.
         rule = resolve_commit_rule(commit_rule)
         self.commit_rule = rule
         if rule == "lowdepth":
@@ -1047,6 +1083,22 @@ class Consensus:
         self._c2c_cap = 2 * gc_depth * len(committee.authorities)
         self._m_round = metrics.gauge("consensus.last_committed_round")
         self._m_lag = metrics.gauge("consensus.commit_lag_rounds")
+        # How often each road to a commit is taken (Tusk._note_decision):
+        # leaders that crossed the rule's own gate (2f+1 citations under
+        # the default; classic's f+1 trigger), earlier leaders the chain
+        # walk added to such a decision, and even rounds passed over.
+        _m_direct = metrics.counter("consensus.leaders_direct")
+        _m_indirect = metrics.counter("consensus.leaders_indirect")
+        _m_skipped = metrics.counter("consensus.leaders_skipped")
+
+        def _observe_decision(
+            direct: int, indirect: int, skipped: int
+        ) -> None:
+            _m_direct.inc(direct)
+            _m_indirect.inc(indirect)
+            _m_skipped.inc(skipped)
+
+        self.tusk.decision_observer = _observe_decision
         self._mtrace = metrics.trace()
         # Support-arrival spread: per leader round, the loop-clock span
         # from the FIRST direct supporter landing to the arrival that
@@ -1140,12 +1192,14 @@ class Consensus:
             except CheckpointRuleMismatch:
                 # The ONE restore failure that must not fall back to a
                 # fresh frontier: the file is a healthy checkpoint from
-                # the OTHER commit rule (operator flipped the flag on a
-                # live store).  Booting fresh would silently replay and
-                # re-commit everything the other rule already delivered
-                # — refuse instead, naming the fix.
+                # ANOTHER commit rule (the operator flipped the flag on a
+                # live store, or upgraded across the change of default:
+                # classic before, lowdepth since).  Booting fresh would
+                # silently replay and re-commit everything the other rule
+                # already delivered — refuse instead; the exception's
+                # message names both rules and the way out.
                 log.exception(
-                    "Checkpoint %s belongs to the other commit rule; "
+                    "Checkpoint %s belongs to another commit rule; "
                     "REFUSING to boot (this node runs %r)",
                     checkpoint_path, rule,
                 )

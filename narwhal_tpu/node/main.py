@@ -19,7 +19,7 @@ import sys
 from ..analysis.watchdog import install_from_env as install_loop_watchdog
 from ..config import Committee, Parameters, export_keypair, load_keypair
 from ..crypto import KeyPair
-from ..utils.env import env_flag, env_float, env_str
+from ..utils.env import REGISTRY, env_flag, env_float, env_str
 from ..utils.tasks import spawn
 from .node import spawn_primary_node, spawn_worker_node
 
@@ -149,13 +149,15 @@ def main(argv=None) -> int:
         "--commit-rule",
         choices=["classic", "lowdepth", "multileader"],
         default=None,
-        help="Consensus commit rule: classic (Tusk, depth-3 commits on "
-        "f+1 support), lowdepth (Mysticeti-style direct commit on "
-        "2f+1 support one round after the leader), or multileader "
+        help="Consensus commit rule: lowdepth (the direct rule: a "
+        "leader commits on 2f+1 support one round above it), classic "
+        "(upstream Tusk, depth-3 commits on f+1 support; for a "
+        "committee that has not switched yet), or multileader "
         "(Mysticeti multi-slot: 3 round-salted leader slots per even "
         "round, the commit anchors on the lowest supported slot) — each "
-        "non-classic rule judged against its own golden oracle.  "
-        "Default: the NARWHAL_COMMIT_RULE env knob, else classic.  "
+        "rule judged against its own golden oracle.  "
+        "Default: the NARWHAL_COMMIT_RULE env knob, else "
+        f"{REGISTRY['NARWHAL_COMMIT_RULE'].default}.  "
         "Committee-wide — every node must run the same rule, and a "
         "checkpoint written under one rule refuses to restore under "
         "another.",
@@ -279,8 +281,9 @@ def main(argv=None) -> int:
         crypto_backend.describe_backend(), requested,
     )
     # Commit rule resolves the same way (CLI > NARWHAL_COMMIT_RULE >
-    # classic) and is logged at boot so a bench arm's logs prove which
-    # rule actually ran; garbage raises HERE, before any socket binds.
+    # the registry's default) and is logged at boot so a bench arm's
+    # logs prove which rule actually ran; garbage raises HERE, before
+    # any socket binds.
     from ..consensus import resolve_commit_rule
 
     logging.getLogger("narwhal.node").info(

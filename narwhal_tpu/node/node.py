@@ -131,7 +131,8 @@ async def spawn_primary_node(
             store_path + ".consensus.ckpt" if store_path else None
         ),
         audit_path=audit_path,
-        # None defers to NARWHAL_COMMIT_RULE inside Consensus; the CLI
+        # None defers to NARWHAL_COMMIT_RULE (unset: the registry's
+        # default, lowdepth) inside Consensus; the CLI
         # value (node run --commit-rule) arrives here already resolved.
         commit_rule=commit_rule,
     )

@@ -113,16 +113,19 @@ _VARS = [
         "the golden-oracle safety replay; unset = no audit log.",
     ),
     EnvVar(
-        "NARWHAL_COMMIT_RULE", "str", "classic",
-        "Commit rule (equivalent of `node run --commit-rule`): `classic` "
-        "(Tusk — leader commits at depth 3 on f+1 support), `lowdepth` "
-        "(Mysticeti-style — leader commits the moment 2f+1 round-(L+1) "
-        "certificates cite it), or `multileader` (Mysticeti multi-slot "
-        "— 3 round-salted leader slots per even round, the commit "
-        "anchors on the lowest 2f+1-supported slot); each non-classic "
-        "rule is judged against its own frozen oracle. Committee-wide: "
-        "mixed-rule committees diverge by design and fail the safety "
-        "replay; checkpoints refuse a cross-rule restore.",
+        "NARWHAL_COMMIT_RULE", "str", "lowdepth",
+        "Commit rule (equivalent of `node run --commit-rule`): `lowdepth` "
+        "(the direct rule, Mysticeti-style — a leader commits the moment "
+        "2f+1 round-(L+1) certificates cite it; the default since PR 33, "
+        "because on the chip it takes two rounds off every commit), "
+        "`classic` (upstream Tusk — a leader commits at depth 3 on f+1 "
+        "support; kept selectable for a committee that has not switched "
+        "yet), or `multileader` (Mysticeti multi-slot — 3 round-salted "
+        "leader slots per even round, the commit anchors on the lowest "
+        "2f+1-supported slot); each rule is judged against its own "
+        "frozen oracle. Committee-wide: mixed-rule committees diverge by "
+        "design and fail the safety replay, so a committee changes its "
+        "rule together; checkpoints refuse a cross-rule restore.",
     ),
     EnvVar(
         "NARWHAL_CERT_SIG_SCHEME", "str", "individual",

@@ -34,9 +34,18 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from narwhal_tpu.analysis.schedule import run_with_seed  # noqa: E402
-from narwhal_tpu.consensus import Consensus  # noqa: E402
-from narwhal_tpu.consensus.golden import GoldenTusk  # noqa: E402
-from narwhal_tpu.consensus.replay import replay_segments  # noqa: E402
+import pytest  # noqa: E402
+
+from narwhal_tpu.consensus import Consensus, Tusk  # noqa: E402
+from narwhal_tpu.consensus.replay import (  # noqa: E402
+    _oracle_for,
+    replay_segments,
+)
+from narwhal_tpu.consensus.tusk import (  # noqa: E402
+    RULE_MAGICS,
+    CheckpointRuleMismatch,
+    resolve_commit_rule,
+)
 from tests.common import committee  # noqa: E402
 from tests.test_consensus import (  # noqa: E402
     feed,
@@ -53,6 +62,12 @@ GC_DEPTH = 50
 SEED_FIRST_RUN = 11
 SEED_SECOND_RUN = 22
 SEED_TORN_BOOT = 33
+
+
+def default_oracle(*args, **kwargs):
+    """The frozen oracle of the rule a Consensus runs when nobody names
+    one (every Consensus below): the uncrashed reference walk."""
+    return _oracle_for(resolve_commit_rule())(*args, **kwargs)
 
 
 def _stream(rounds=24):
@@ -74,7 +89,7 @@ def test_restart_mid_burst_with_concurrent_inserts_agrees_with_oracle(
     # The uncrashed reference: one golden walk over the whole stream.
     full = [
         bytes(x.digest())
-        for x in feed(GoldenTusk(c, GC_DEPTH, fixed_coin=True), list(stream))
+        for x in feed(default_oracle(c, GC_DEPTH, fixed_coin=True), list(stream))
     ]
     assert len(full) > 20, "fixture must commit substantially"
 
@@ -86,7 +101,7 @@ def test_restart_mid_burst_with_concurrent_inserts_agrees_with_oracle(
     prefix = [
         bytes(x.digest())
         for x in feed(
-            GoldenTusk(c, GC_DEPTH, fixed_coin=True), list(stream[:cut])
+            default_oracle(c, GC_DEPTH, fixed_coin=True), list(stream[:cut])
         )
     ]
     target = len(full) // 3
@@ -228,11 +243,12 @@ def test_restart_from_torn_checkpoint_falls_back_fresh_and_stays_safe(
     ckpt = str(tmp_path / "consensus.ckpt")
     seg = str(tmp_path / "audit.seg0.bin")
     with open(ckpt, "wb") as f:
-        f.write(b"NCKPT1\x03")  # torn: magic + truncated body
+        # Torn: the running rule's magic + a truncated body.
+        f.write(RULE_MAGICS[resolve_commit_rule()] + b"\x03")
     # The fresh boot re-commits the full prefix, in the oracle's order.
     full = [
         bytes(x.digest())
-        for x in feed(GoldenTusk(c, GC_DEPTH, fixed_coin=True), list(stream))
+        for x in feed(default_oracle(c, GC_DEPTH, fixed_coin=True), list(stream))
     ]
     full_count = len(full)
 
@@ -275,6 +291,42 @@ def test_restart_from_torn_checkpoint_falls_back_fresh_and_stays_safe(
     assert committed == full
 
 
+def test_restart_on_the_default_over_a_classic_checkpoint_is_refused(
+    tmp_path, monkeypatch
+):
+    """The upgrade across PR 33's change of default: a validator that
+    committed under classic (upstream's rule) and restarts with no rule
+    named must not boot — neither from the classic frontier nor fresh —
+    and the refusal names both rules and `--commit-rule classic`; with
+    that flag the same checkpoint restores and the node carries on."""
+    monkeypatch.delenv("NARWHAL_COMMIT_RULE", raising=False)
+    c, stream = _stream(rounds=12)
+    ckpt = str(tmp_path / "consensus.ckpt")
+    before = Tusk(c, GC_DEPTH, fixed_coin=True)
+    assert feed(before, list(stream))
+    with open(ckpt, "wb") as f:
+        f.write(before.state.snapshot_bytes())
+
+    def boot(**kwargs):
+        return Consensus(
+            c, GC_DEPTH, rx_primary=asyncio.Queue(),
+            tx_primary=asyncio.Queue(), tx_output=asyncio.Queue(),
+            fixed_coin=True, checkpoint_path=ckpt, **kwargs,
+        )
+
+    with pytest.raises(CheckpointRuleMismatch) as refused:
+        boot()
+    message = str(refused.value)
+    assert "'classic'" in message and "'lowdepth'" in message
+    assert "--commit-rule classic" in message
+    stayed = boot(commit_rule="classic")
+    assert (
+        stayed.tusk.state.last_committed_round
+        == before.state.last_committed_round
+        > 0
+    )
+
+
 def test_consensus_survives_checkpoint_write_failure(tmp_path):
     """The race the narwhal-race harness caught (ISSUE 10): under the
     seeded loop, the crash/restart pair intermittently lost the SAME 40
@@ -296,7 +348,7 @@ def test_consensus_survives_checkpoint_write_failure(tmp_path):
     seg = str(tmp_path / "audit.seg0.bin")
     full = [
         bytes(x.digest())
-        for x in feed(GoldenTusk(c, GC_DEPTH, fixed_coin=True), list(stream))
+        for x in feed(default_oracle(c, GC_DEPTH, fixed_coin=True), list(stream))
     ]
 
     async def go():

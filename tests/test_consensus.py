@@ -4,9 +4,16 @@ authority 0, exact commit sequences asserted."""
 
 import asyncio
 
+import pytest
+
 from narwhal_tpu.crypto import Digest
 from narwhal_tpu.primary.messages import Certificate, Header, genesis
 from narwhal_tpu.consensus import Consensus, Tusk
+from narwhal_tpu.consensus.tusk import (
+    RULE_MAGICS,
+    CheckpointRuleMismatch,
+    resolve_commit_rule,
+)
 from tests.common import committee, keys
 
 
@@ -189,7 +196,6 @@ def test_restore_torn_blob_raises_without_mutation():
     the caller's fallback is the fresh frontier, which must be intact
     (ADVICE.md r05 — the old code assigned last_committed_round before
     validating the length)."""
-    import pytest
 
     c = committee()
     names = sorted_names()
@@ -217,7 +223,9 @@ def test_corrupt_checkpoint_boots_fresh_and_commits(tmp_path):
     async def go():
         ckpt = str(tmp_path / "consensus.ckpt")
         with open(ckpt, "wb") as f:
-            f.write(b"NCKPT1\x00\x01")  # torn mid-write
+            # Torn mid-write, under the magic of the rule that runs when
+            # nobody names one (another rule's magic is refused, below).
+            f.write(RULE_MAGICS[resolve_commit_rule()] + b"\x00\x01")
 
         c = committee()
         names = sorted_names()
@@ -245,7 +253,7 @@ def test_corrupt_checkpoint_boots_fresh_and_commits(tmp_path):
         # loop, PR 4), so poll for the write to land BEFORE cancelling
         # the runner — cancelling first could cancel a not-yet-started
         # executor job and the file would never appear.
-        state = Tusk(c, gc_depth=50, fixed_coin=True).state
+        state = type(consensus.tusk)(c, gc_depth=50, fixed_coin=True).state
         for _ in range(100):
             with open(ckpt, "rb") as f:
                 blob = f.read()
@@ -258,6 +266,45 @@ def test_corrupt_checkpoint_boots_fresh_and_commits(tmp_path):
         assert state.last_committed_round == 2
 
     asyncio.run(asyncio.wait_for(go(), 15))
+
+
+@pytest.mark.parametrize("torn", [True, False], ids=["torn", "whole"])
+def test_classic_checkpoint_refused_on_the_default_rule(
+    tmp_path, monkeypatch, torn
+):
+    """A validator that restarts on this version over a checkpoint the
+    classic rule wrote (upstream's rule, the default until PR 33) is
+    refused at boot, torn file or whole: never the fresh-frontier
+    fallback, never one rule's frontier read under the other.  The
+    message tells the upgrading operator both rules and the way out."""
+    monkeypatch.delenv("NARWHAL_COMMIT_RULE", raising=False)
+    c = committee()
+    names = sorted_names()
+    certs, next_parents = make_certificates(1, 4, genesis_digests(c), names)
+    _, trigger = mock_certificate(names[0], 5, next_parents)
+    classic = Tusk(c, gc_depth=50, fixed_coin=True)
+    assert feed(classic, certs + [trigger])
+    blob = classic.state.snapshot_bytes()
+    ckpt = str(tmp_path / "consensus.ckpt")
+    with open(ckpt, "wb") as f:
+        f.write(blob[:8] if torn else blob)
+
+    def boot(**kwargs):
+        return Consensus(
+            c, 50, asyncio.Queue(), asyncio.Queue(), asyncio.Queue(),
+            fixed_coin=True, checkpoint_path=ckpt, **kwargs,
+        )
+
+    with pytest.raises(CheckpointRuleMismatch) as refused:
+        boot()
+    message = str(refused.value)
+    assert "'classic'" in message and "'lowdepth'" in message
+    assert "--commit-rule classic" in message
+    assert "wipe the checkpoint" in message
+    # The way out the message names works: the node stays on the old
+    # rule (a whole checkpoint restores, a torn one boots fresh).
+    stayed = boot(commit_rule="classic")
+    assert stayed.tusk.state.last_committed_round == (0 if torn else 2)
 
 
 def test_checkpoint_restore_resumes_without_redelivery():

@@ -275,37 +275,43 @@ def _stream():
 
 
 def test_classic_default_and_env_selection(tmp_path, monkeypatch):
-    """Unset flag → classic, byte-identical to GoldenTusk; the env knob
-    selects lowdepth; the constructor arg beats the env (CLI precedence
-    — node/main.py passes --commit-rule through as the arg)."""
+    """Unset flag → lowdepth (the product's default since PR 33),
+    byte-identical to GoldenLowDepthTusk; the env knob selects classic;
+    the constructor arg beats the env (CLI precedence — node/main.py
+    passes --commit-rule through as the arg); garbage raises."""
     certs = _stream()
     c = committee()
 
     monkeypatch.delenv("NARWHAL_COMMIT_RULE", raising=False)
-    want = feed(GoldenTusk(c, 50, fixed_coin=True), certs)
+    assert resolve_commit_rule() == "lowdepth"
+    want = feed(GoldenLowDepthTusk(c, 50, fixed_coin=True), certs)
     _, cons = run_consensus(tmp_path, certs, want, "default")
+    assert isinstance(cons.tusk, LowDepthTusk)
+    assert cons.commit_rule == "lowdepth"
+    # An empty value is unset, not garbage.
+    monkeypatch.setenv("NARWHAL_COMMIT_RULE", "")
+    assert resolve_commit_rule() == "lowdepth"
+
+    monkeypatch.setenv("NARWHAL_COMMIT_RULE", "classic")
+    assert resolve_commit_rule() == "classic"
+    want = feed(GoldenTusk(c, 50, fixed_coin=True), certs)
+    _, cons = run_consensus(tmp_path, certs, want, "env")
     assert isinstance(cons.tusk, Tusk) and not isinstance(
         cons.tusk, LowDepthTusk
     )
     assert cons.commit_rule == "classic"
 
-    monkeypatch.setenv("NARWHAL_COMMIT_RULE", "lowdepth")
-    assert resolve_commit_rule() == "lowdepth"
-    want = feed(GoldenLowDepthTusk(c, 50, fixed_coin=True), certs)
-    _, cons = run_consensus(tmp_path, certs, want, "env")
-    assert isinstance(cons.tusk, LowDepthTusk)
-
     # Explicit arg (the CLI path) wins over the env.
-    want = feed(GoldenTusk(c, 50, fixed_coin=True), certs)
+    want = feed(GoldenLowDepthTusk(c, 50, fixed_coin=True), certs)
     _, cons = run_consensus(
-        tmp_path, certs, want, "arg-wins", commit_rule="classic"
+        tmp_path, certs, want, "arg-wins", commit_rule="lowdepth"
     )
-    assert cons.commit_rule == "classic"
+    assert cons.commit_rule == "lowdepth"
 
     monkeypatch.setenv("NARWHAL_COMMIT_RULE", "sideways")
     with pytest.raises(ValueError, match="sideways"):
         resolve_commit_rule()
-    assert resolve_commit_rule("lowdepth") == "lowdepth"
+    assert resolve_commit_rule("classic") == "classic"
 
 
 def test_checkpoint_refuses_cross_rule_restore(tmp_path):
