@@ -270,6 +270,13 @@ def test_tpu_name_refuses_a_cpu_only_jax():
         (517, (128, 512), [(0, 512, 512), (512, 517, 128)]),
         (1100, (128, 512), [(0, 512, 512), (512, 1024, 512), (1024, 1100, 128)]),
         (19, (16,), [(0, 16, 16), (16, 19, 16)]),
+        # bench-10n-f3 (a certificate is 8 claims): one round at primary
+        # 0, two rounds and a half after a freeze, a DRAIN_LIMIT burst of
+        # certificates (128 x 8), which at this width no longer fits one
+        # dispatch.
+        (60, (128, 512), [(0, 60, 128)]),
+        (150, (128, 512), [(0, 150, 512)]),
+        (1024, (128, 512), [(0, 512, 512), (512, 1024, 512)]),
     ],
 )
 def test_chunk_plan_pads_to_a_rung_and_splits_above_the_top(n, ladder, plan):
