@@ -151,6 +151,12 @@ class Proposer:
             "primary.payload_reproposed", metrics.COUNT_BUCKETS
         )
         self._m_payload_digests = metrics.counter("primary.payload_digests")
+        # Batch digests per minted header: each is a batch one of this
+        # validator's workers sealed, so the mean grows with the workers
+        # a validator runs.
+        self._m_header_digests = metrics.histogram(
+            "primary.header_digests", metrics.COUNT_BUCKETS
+        )
         self._m_round = metrics.gauge("primary.round")
         # Round period: seconds between consecutive round advances.  The
         # cert→commit attribution (PR 4) shows commit latency is
@@ -263,6 +269,7 @@ class Proposer:
         self._m_headers.inc()
         self._m_header_parents.observe(len(parents))
         self._m_payload_digests.inc(len(payload))
+        self._m_header_digests.observe(len(payload))
         self._rtrace.mark(str(header.round), "header_proposed")
         for digest in payload:
             self._mtrace.mark(bytes(digest).hex(), "header")

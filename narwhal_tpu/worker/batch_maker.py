@@ -42,6 +42,11 @@ log = logging.getLogger("narwhal.worker")
 # lines (and the bench parser reads every one).
 _OVERFLOW_WARN_INTERVAL = 5.0
 
+# Sealed payload bytes over `batch_size`, in twentieths: a batch sealed by
+# its timer reads below 1.0, one sealed by size at or just above it (the
+# transaction that crossed the threshold rides along).
+FILL_BUCKETS: Tuple[float, ...] = tuple(round(0.05 * k, 2) for k in range(1, 22))
+
 
 class _TxProtocol(asyncio.Protocol):
     """One inbound client connection: feeds raw chunks to the shared
@@ -113,6 +118,7 @@ class BatchMaker:
         self._m_sealed = metrics.counter("worker.batches_sealed")
         self._m_tx_bytes = metrics.counter("worker.batch_bytes_sealed")
         self._m_txs = metrics.counter("worker.txs_sealed")
+        self._m_fill = metrics.histogram("worker.batch_fill", FILL_BUCKETS)
         self._m_overflow = metrics.counter("worker.ingress_overflow")
         self._m_malformed = metrics.counter("worker.malformed_tx_streams")
         self._trace = metrics.trace()
@@ -206,6 +212,7 @@ class BatchMaker:
         self._m_sealed.inc()
         self._m_tx_bytes.inc(sealed.tx_bytes)
         self._m_txs.inc(sealed.tx_count)
+        self._m_fill.observe(sealed.tx_bytes / self.batch_size)
         self._trace.mark(
             bytes(digest).hex(), "seal", bytes=sealed.tx_bytes,
             txs=sealed.tx_count,
