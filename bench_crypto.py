@@ -78,13 +78,13 @@ def bench_tpu(msgs, keys, sigs, batch: int, iters: int, pipeline_depth: int = 4)
 
     # Host prep cost (amortized per signature).
     t0 = time.perf_counter()
-    args = E.prepare_batch(m, k, s, batch)
+    packed = E.prepare_batch(m, k, s, batch)
     prep_s = time.perf_counter() - t0
-    jargs = [jnp.asarray(a) for a in args]
+    jpacked = jnp.asarray(packed)
 
     # Warmup / compile (persistent cache makes this fast on reruns).
     t0 = time.perf_counter()
-    mask = np.asarray(E._verify_kernel(*jargs))
+    mask = np.asarray(E._verify_kernel(jpacked))
     compile_s = time.perf_counter() - t0
     if not mask.all():
         raise AssertionError("kernel rejected valid signatures")
@@ -93,13 +93,13 @@ def bench_tpu(msgs, keys, sigs, batch: int, iters: int, pipeline_depth: int = 4)
     lat = []
     for _ in range(iters):
         t0 = time.perf_counter()
-        np.asarray(E._verify_kernel(*jargs))
+        np.asarray(E._verify_kernel(jpacked))
         lat.append(time.perf_counter() - t0)
     lat_s = statistics.median(lat)
 
     # Pipelined: dispatch K batches, block once at the end.
     t0 = time.perf_counter()
-    outs = [E._verify_kernel(*jargs) for _ in range(pipeline_depth)]
+    outs = [E._verify_kernel(jpacked) for _ in range(pipeline_depth)]
     for o in outs:
         o.block_until_ready()
     pipe_s = (time.perf_counter() - t0) / pipeline_depth
@@ -224,16 +224,15 @@ def bench_aggregate(quorum: int, iters: int, batched: bool = False) -> dict:
         from narwhal_tpu.ops import ed25519 as E
 
         msgs = [msg] * quorum
-        jargs = [
-            jnp.asarray(a)
-            for a in E.prepare_batch(msgs, ordered_keys, ordered_sigs, quorum)
-        ]
-        mask = np.asarray(E._verify_kernel(*jargs))  # warmup / compile
+        jpacked = jnp.asarray(
+            E.prepare_batch(msgs, ordered_keys, ordered_sigs, quorum)
+        )
+        mask = np.asarray(E._verify_kernel(jpacked))  # warmup / compile
         assert mask.all(), "batched kernel rejected valid quorum"
         batched = []
         for _ in range(iters):
             t0 = time.perf_counter()
-            np.asarray(E._verify_kernel(*jargs))
+            np.asarray(E._verify_kernel(jpacked))
             batched.append(time.perf_counter() - t0)
         out["batched_window_ms"] = round(1e3 * stats.median(batched), 3)
     except Exception as e:  # no jax / no device — ladder stays 2-leg

@@ -335,9 +335,9 @@ async def averify_batch_mask(
     Inside ``devtrace.burst(key)`` the call stamps the caller's entry of
     the verify-stage trace (metrics.VERIFY_STAGES): ``submitted`` and
     ``resumed`` here, on the loop, and a backend with a dispatch thread
-    hands back its own stamps (``prepare``, ``enqueued``, ``fetched`` and
-    the extras) as a third element of its result, which are marked here
-    too — the table is written from the loop only."""
+    hands back its own stamps (``prepare``, ``prepared``, ``enqueued``,
+    ``fetched`` and the extras) as a third element of its result, which
+    are marked here too — the table is written from the loop only."""
     if not (len(messages) == len(keys) == len(sigs)):
         raise ValueError("verify_batch: length mismatch")
     if not messages:
@@ -375,7 +375,7 @@ async def averify_batch_mask(
         if trace_key is not None:
             resumed = wall_now()
             stamps = dict(thread[0]) if thread else {}
-            for stage in ("prepare", "enqueued", "fetched"):
+            for stage in ("prepare", "prepared", "enqueued", "fetched"):
                 if stage in stamps:
                     verify_trace.mark(trace_key, stage, stamps.pop(stage))
             verify_trace.mark(trace_key, "resumed", resumed, **stamps)

@@ -142,14 +142,16 @@ ROUND_STAGES: Tuple[str, ...] = (
 #   collected  Core closed the batch (_verify_loop; inline: burst entry)
 #   submitted  claims extracted, batch about to go to the backend (loop)
 #   prepare    the backend's dispatch thread picked it up
-#   enqueued   last chunk's kernel call returned: host preparation,
-#              transfer in and launch are done
+#   prepared   last chunk's host preparation returned: the arithmetic
+#              (hashing, limbs, windows) into its packed array is done
+#   enqueued   last chunk's kernel call returned: its transfer in and
+#              launch are done
 #   fetched    last chunk's mask is on the host: the device is done
 #   resumed    the await returned on the event loop
 #   replayed   in-order replay, log flush and GC sweep done: the stage is
 #              free for the next burst
 #
-# The three middle stamps are taken ON the dispatch thread, come back with
+# The four middle stamps are taken ON the dispatch thread, come back with
 # the result and are marked by the seam (crypto/backend.py), so the table
 # is written from the loop only.  A backend without a dispatch thread
 # marks the loop stages alone, and a burst whose claims were all stale or
@@ -162,6 +164,7 @@ VERIFY_STAGES: Tuple[str, ...] = (
     "collected",
     "submitted",
     "prepare",
+    "prepared",
     "enqueued",
     "fetched",
     "resumed",

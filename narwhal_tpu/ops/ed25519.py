@@ -248,24 +248,50 @@ def _build_neg_a_table(neg_a: Point) -> jnp.ndarray:
 
 
 # ------------------------------------------------------------ verification
+#
+# A dispatch hands the device ONE array, uint8[pad, PACKED_WIDTH], a row
+# per claim, in these columns: A's and R's compressed y as 8-bit limbs
+# (the raw 255 bits), its sign bit and whether y < p; S's 4-bit windows,
+# MSB-first, and whether S < L; the windows of k = H(R || A || M) mod L.
+# Every value fits a byte (limbs are 8-bit, windows 4-bit, the rest
+# flags), so a row is 197 bytes and a chunk is one transfer.
+
+PACKED_LAYOUT = (
+    ("a_y", F.LIMBS), ("a_sign", 1), ("a_canon", 1),
+    ("r_y", F.LIMBS), ("r_sign", 1), ("r_canon", 1),
+    ("s_windows", 64), ("s_ok", 1), ("k_windows", 64),
+)
+
+
+def _columns(layout) -> Tuple[dict, int]:
+    """name -> column index (width 1) or slice, and the row's width."""
+    columns, at = {}, 0
+    for name, width in layout:
+        columns[name] = at if width == 1 else slice(at, at + width)
+        at += width
+    return columns, at
+
+
+COLUMNS, PACKED_WIDTH = _columns(PACKED_LAYOUT)
 
 
 @jax.jit
-def _verify_kernel(
-    a_y: jnp.ndarray,       # int32[B, LIMBS] — A's y limbs (raw 255 bits)
-    a_sign: jnp.ndarray,    # int32[B]
-    a_canon: jnp.ndarray,   # bool[B] — A's y < p
-    r_y: jnp.ndarray,       # int32[B, LIMBS]
-    r_sign: jnp.ndarray,    # int32[B]
-    r_canon: jnp.ndarray,   # bool[B]
-    s_windows: jnp.ndarray,  # int32[B, 64] MSB-first 4-bit windows of S
-    s_ok: jnp.ndarray,      # bool[B] — S < L
-    k_windows: jnp.ndarray,  # int32[B, 64] MSB-first windows of k mod L
-) -> jnp.ndarray:
-    # Host prep always hands int32 limb rows; the field module's lane
-    # dtype may be float32 (NARWHAL_FIELD_DTYPE) — cast once at entry.
-    a_y = a_y.astype(F.DTYPE)
-    r_y = r_y.astype(F.DTYPE)
+def _verify_kernel(packed: jnp.ndarray) -> jnp.ndarray:
+    """The mask of a padded batch: ``packed`` is `prepare_batch`'s one
+    array, uint8[B, PACKED_WIDTH], a row per claim (columns: COLUMNS)."""
+
+    def column(name, dtype):
+        return packed[:, COLUMNS[name]].astype(dtype)
+
+    # Sliced and cast once at entry: limbs to the field module's lane
+    # dtype (int32, or float32 under NARWHAL_FIELD_DTYPE), windows and
+    # signs to int32, flags to bool; the body below reads only these.
+    a_y, r_y = column("a_y", F.DTYPE), column("r_y", F.DTYPE)
+    a_sign, r_sign = column("a_sign", jnp.int32), column("r_sign", jnp.int32)
+    a_canon, r_canon = column("a_canon", jnp.bool_), column("r_canon", jnp.bool_)
+    s_windows = column("s_windows", jnp.int32)  # [B, 64] MSB-first, of S
+    s_ok = column("s_ok", jnp.bool_)  # S < L
+    k_windows = column("k_windows", jnp.int32)  # [B, 64], of k mod L
     # The named scopes group the device operations of a call by phase in
     # a profile, whatever a refactor does to the operations' own names.
     # They are location metadata: not part of the compile cache's key,
@@ -308,35 +334,23 @@ def _verify_kernel(
 
 # ----------------------------------------------------------- host-side prep
 #
-# Fully vectorized with numpy (the kernel's feed must not become a Python
-# loop): bytes → bit matrix → 8-bit limbs / 4-bit windows via one matmul
-# each.  Only SHA-512 (hashlib, C speed) and the 512→mod-L reduction touch
-# Python objects per signature.
+# Vectorized with numpy over the claims' rows only (the kernel's feed must
+# not become a Python loop, nor grow with the rung): the padded rows stay
+# zero.  The field's limbs are 8-bit (F.BITS), so a y-field's limbs ARE its
+# bytes and a window is a nibble.  Only SHA-512 (hashlib, C speed) and the
+# 512 -> mod-L reduction touch Python objects per signature.
 
-_NIBBLE_W = np.array([1, 2, 4, 8], dtype=np.int32)
-_LIMB_W = (1 << np.arange(F.BITS, dtype=np.int32)).astype(np.int32)
 _P_BYTES_BE = np.frombuffer(P.to_bytes(32, "big"), np.uint8)
 _L_BYTES_BE = np.frombuffer(L_ORDER.to_bytes(32, "big"), np.uint8)
 
 
-def _bits_le(raw: np.ndarray) -> np.ndarray:
-    """uint8[B, 32] → bit matrix bool[B, 256], bit i = value bit i."""
-    return np.unpackbits(raw, axis=1, bitorder="little")
-
-
-def _field_limbs(bits: np.ndarray) -> np.ndarray:
-    """bit matrix [B, 256] (low 255 bits used) → int32[B, LIMBS] limbs."""
-    pad = F.LIMBS * F.BITS - 255
-    padded = np.concatenate(
-        [bits[:, :255], np.zeros((bits.shape[0], pad), bits.dtype)], axis=1
-    )
-    return padded.reshape(-1, F.LIMBS, F.BITS).astype(np.int32) @ _LIMB_W
-
-
-def _msb_windows(bits: np.ndarray) -> np.ndarray:
-    """bit matrix [B, 256] → int32[B, 64] 4-bit windows, MSB-first."""
-    nib = bits.reshape(-1, 64, 4).astype(np.int32) @ _NIBBLE_W
-    return nib[:, ::-1]
+def _msb_windows(raw: np.ndarray) -> np.ndarray:
+    """uint8[n, 32] little-endian values -> uint8[n, 64] 4-bit windows,
+    MSB-first."""
+    nibbles = np.empty((len(raw), 64), np.uint8)
+    nibbles[:, 0::2] = raw & 0x0F
+    nibbles[:, 1::2] = raw >> 4
+    return nibbles[:, ::-1]
 
 
 def _lt_be(raw_le: np.ndarray, bound_be: np.ndarray) -> np.ndarray:
@@ -350,13 +364,25 @@ def _lt_be(raw_le: np.ndarray, bound_be: np.ndarray) -> np.ndarray:
     return np.where(any_nz, picked < 0, False)
 
 
+def _put_point(claims: np.ndarray, name: str, raw: np.ndarray) -> None:
+    """A compressed point's columns: its y-field (the sign bit, bit 255,
+    cleared) as limbs, the sign bit, and the y-field's canonicality."""
+    field = raw.copy()
+    field[:, 31] &= 0x7F
+    claims[:, COLUMNS[name + "_y"]] = field
+    claims[:, COLUMNS[name + "_sign"]] = raw[:, 31] >> 7
+    claims[:, COLUMNS[name + "_canon"]] = _lt_be(field, _P_BYTES_BE)
+
+
 def prepare_batch(
     messages: Sequence[bytes],
     keys: Sequence[bytes],
     sigs: Sequence[bytes],
     pad_to: int,
-):
-    """Host prep: unpack encodings, hash-to-scalar, window-decompose.
+) -> np.ndarray:
+    """Host prep: unpack encodings, hash-to-scalar, window-decompose, into
+    the one array a dispatch hands the device (uint8[pad_to,
+    PACKED_WIDTH], columns COLUMNS; rows past the claims all zero).
 
     One join + reshape per field instead of a frombuffer per row: the
     per-signature Python loop is the host-side throughput cap once the
@@ -364,13 +390,6 @@ def prepare_batch(
     irreducible SHA-512 + mod-L), and host prep overlaps device compute
     only if it keeps up."""
     n = len(messages)
-
-    def rows(chunks) -> np.ndarray:
-        out = np.zeros((pad_to, 32), np.uint8)
-        if n:
-            out[:n] = np.frombuffer(b"".join(chunks), np.uint8).reshape(n, 32)
-        return out
-
     sig_bytes = [bytes(s) for s in sigs]
     key_bytes = [bytes(k) for k in keys]
     # Fail loud on malformed lengths: the join+reshape below would
@@ -380,8 +399,13 @@ def prepare_batch(
         raise ValueError("prepare_batch: every key must be 32 bytes")
     if any(len(s) != 64 for s in sig_bytes):
         raise ValueError("prepare_batch: every signature must be 64 bytes")
-    akeys = rows(key_bytes)
-    r_raw = rows(s[:32] for s in sig_bytes)
+    packed = np.zeros((pad_to, PACKED_WIDTH), np.uint8)
+    if not n:
+        return packed
+
+    def rows(chunks) -> np.ndarray:
+        return np.frombuffer(b"".join(chunks), np.uint8).reshape(n, 32)
+
     s_raw = rows(s[32:64] for s in sig_bytes)
     kb = bytearray()
     for akey, sig, msg in zip(key_bytes, sig_bytes, messages):
@@ -389,28 +413,13 @@ def prepare_batch(
             hashlib.sha512(sig[:32] + akey + bytes(msg)).digest(), "little"
         ) % L_ORDER
         kb += k.to_bytes(32, "little")
-    k_raw = rows((kb,))
-
-    a_bits = _bits_le(akeys)
-    r_bits = _bits_le(r_raw)
-    s_bits = _bits_le(s_raw)
-    k_bits = _bits_le(k_raw)
-    # Mask the sign bit off the y-field before the canonicality compare.
-    a_field = akeys.copy()
-    a_field[:, 31] &= 0x7F
-    r_field = r_raw.copy()
-    r_field[:, 31] &= 0x7F
-    return (
-        _field_limbs(a_bits),
-        a_bits[:, 255].astype(np.int32),
-        _lt_be(a_field, _P_BYTES_BE),
-        _field_limbs(r_bits),
-        r_bits[:, 255].astype(np.int32),
-        _lt_be(r_field, _P_BYTES_BE),
-        _msb_windows(s_bits),
-        _lt_be(s_raw, _L_BYTES_BE),
-        _msb_windows(k_bits),
-    )
+    claims = packed[:n]  # a view: the writes below fill ``packed``
+    _put_point(claims, "a", rows(key_bytes))
+    _put_point(claims, "r", rows(s[:32] for s in sig_bytes))
+    claims[:, COLUMNS["s_windows"]] = _msb_windows(s_raw)
+    claims[:, COLUMNS["s_ok"]] = _lt_be(s_raw, _L_BYTES_BE)
+    claims[:, COLUMNS["k_windows"]] = _msb_windows(rows((kb,)))
+    return packed
 
 
 # -- the pad ladder -----------------------------------------------------------
@@ -450,24 +459,16 @@ CPU_RUNGS = (16,)
 
 
 def kernel_args(n: int, sharding=None) -> Tuple[jax.ShapeDtypeStruct, ...]:
-    """`_verify_kernel`'s nine arrays at ``n`` rows, as shapes: what
-    `prepare_batch(..., pad_to=n)` hands over."""
-
-    def shape(dims, dtype):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
-
-    limbs, sign, flag, windows = (
-        shape((n, F.LIMBS), jnp.int32),
-        shape((n,), jnp.int32),
-        shape((n,), jnp.bool_),
-        shape((n, 64), jnp.int32),
+    """`_verify_kernel`'s arguments at ``n`` rows, as shapes: the one
+    packed array `prepare_batch(..., pad_to=n)` hands over."""
+    return (
+        jax.ShapeDtypeStruct((n, PACKED_WIDTH), jnp.uint8, sharding=sharding),
     )
-    return (limbs, sign, flag, limbs, sign, flag, windows, flag, windows)
 
 
-def known_answers(n: int):
-    """(`_verify_kernel`'s arrays, the mask it must give) for ``n`` rows
-    of one valid signature with a forged row (a signature of another
+def known_answers(n: int) -> Tuple[np.ndarray, np.ndarray]:
+    """(`_verify_kernel`'s packed array, the mask it must give) for ``n``
+    rows of one valid signature with a forged row (a signature of another
     message) and a malleable one (S + L, not canonical) among them: a
     verifier is held to saying no, row by row, not only to saying yes."""
     from ..crypto import KeyPair
@@ -488,8 +489,8 @@ def known_answers(n: int):
 def wrong_answers(program: Callable, n: int) -> Optional[str]:
     """None where ``program`` gives the known answers at ``n`` rows, else
     which rows it got wrong."""
-    args, expected = known_answers(n)
-    mask = np.asarray(program(*(jnp.asarray(a) for a in args)))
+    packed, expected = known_answers(n)
+    mask = np.asarray(program(jax.device_put(packed)))
     if mask.shape != expected.shape:
         return f"a mask of shape {mask.shape} for {n} rows"
     rows = np.flatnonzero(mask != expected)
@@ -552,18 +553,22 @@ def verify_batch_arrays(
     ladder: Optional[Sequence[int]] = None,
     stamps: Optional[dict] = None,
     dispatch: int = 0,
+    moved: Optional[dict] = None,
 ) -> np.ndarray:
     """Bool mask for a batch of (message, key, signature) triples, padded
     and chunked by the pad ladder above (``ladder``: the ``pad_ladder()``
-    the caller resolved once; default: resolved here).  Chunks are
-    dispatched back to back and fetched afterwards, so host prep of chunk
-    k+1 overlaps the device's work on chunk k.  ``dispatched`` (padded
-    shape -> count) is incremented per dispatch.  ``stamps`` (verify-stage
-    trace, metrics.VERIFY_STAGES) receives ``enqueued`` when the last
-    chunk's kernel call has returned (host preparation, transfer in and
-    launch done) and ``fetched`` when the last mask is on the host, with
-    ``pad`` and ``chunks``; ``dispatch`` is the burst number the profiler
-    annotations carry."""
+    the caller resolved once; default: resolved here).  A chunk goes to
+    the device as ONE host-to-device transfer of its packed array.
+    Chunks are dispatched back to back and fetched afterwards, so host
+    prep of chunk k+1 overlaps the device's work on chunk k.
+    ``dispatched`` (padded shape -> count) is incremented per dispatch,
+    ``moved`` (``h2d_transfers``, ``rows_prepared``) by one transfer and
+    the chunk's claims.  ``stamps`` (verify-stage trace,
+    metrics.VERIFY_STAGES) receives ``prepared`` when the last chunk's
+    host preparation has returned, ``enqueued`` when its kernel call has
+    returned (transfer in and launch done) and ``fetched`` when the last
+    mask is on the host, with ``pad`` and ``chunks``; ``dispatch`` is the
+    burst number the profiler annotations carry."""
     n = len(messages)
     if n == 0:
         return np.zeros(0, dtype=bool)
@@ -573,14 +578,19 @@ def verify_batch_arrays(
     with annotate("verify.dispatch", dispatch=dispatch):
         for lo, hi, pad in chunks:
             with annotate("verify.prepare", dispatch=dispatch):
-                args = prepare_batch(
+                packed = prepare_batch(
                     messages[lo:hi], keys[lo:hi], sigs[lo:hi], pad
                 )
+            if stamps is not None:
+                stamps["prepared"] = wall_now()
             with annotate("verify.launch", dispatch=dispatch):
-                out = verify_program(pad)(*(jnp.asarray(a) for a in args))
+                out = verify_program(pad)(jax.device_put(packed))
             pending.append((out, hi - lo))
             if dispatched is not None:
                 dispatched[pad] = dispatched.get(pad, 0) + 1
+            if moved is not None:
+                moved["h2d_transfers"] += 1
+                moved["rows_prepared"] += hi - lo
     if stamps is not None:
         stamps["enqueued"] = wall_now()
     with annotate("verify.fetch", dispatch=dispatch):
@@ -609,11 +619,11 @@ class TpuBackend:
     under: "tpu" (the chip, checked at selection) or "jax" (whatever
     platform JAX has — the CPU tests and A/B arms)."""
 
-    # A dispatch is one device round trip on the dispatch thread, ~7.5 ms
-    # on a v5e at the bottom rung (PERF.md, PR 27; 22 ms before), most of
-    # it host preparation and launch: the Core drives such a backend through
-    # its pipelined verify stage (primary/core.py) instead of awaiting
-    # each drained burst inline.
+    # A dispatch is one device round trip on the dispatch thread, ~4 ms on
+    # a v5e at the bottom rung: ~1 ms of host preparation, transfer and
+    # launch, ~2.9 until the mask is on the host (PERF.md section 5).  The
+    # Core drives such a backend through its pipelined verify stage
+    # (primary/core.py) instead of awaiting each drained burst inline.
     dispatches_off_loop = True
 
     def __init__(self, name: str = "jax") -> None:
@@ -633,6 +643,9 @@ class TpuBackend:
         self._programs_at_ready: Optional[int] = None
         # Live dispatches per padded shape: which rungs the node used.
         self._dispatched: dict = {}
+        # Host-to-device transfers (one a dispatch) and the claim rows
+        # prepared for them (not the padded rows).
+        self._moved = {"h2d_transfers": 0, "rows_prepared": 0}
 
     def verify(self, message: bytes, key, sig) -> bool:
         return bool(self.verify_batch_mask([message], [key], [sig])[0])
@@ -642,7 +655,8 @@ class TpuBackend:
     ) -> List[bool]:
         return list(
             verify_batch_arrays(
-                messages, keys, sigs, self._dispatched, self.rungs
+                messages, keys, sigs, self._dispatched, self.rungs,
+                moved=self._moved,
             )
         )
 
@@ -661,9 +675,9 @@ class TpuBackend:
         and the event-loop wakeup, which is pipelining headroom, not
         crypto cost (the `crypto.verify.device_seconds` split).
         ``stamps`` are the dispatch thread's stages of the verify-stage
-        trace (``prepare``, ``enqueued``, ``fetched``) and its extras
-        (``pad``, ``chunks``, ``cpu_s``: this thread's CPU time across
-        them); the seam marks them on the loop."""
+        trace (``prepare``, ``prepared``, ``enqueued``, ``fetched``) and
+        its extras (``pad``, ``chunks``, ``cpu_s``: this thread's CPU time
+        across them); the seam marks them on the loop."""
         import asyncio
 
         # The verify-stage burst this dispatch belongs to (read here, on
@@ -677,7 +691,7 @@ class TpuBackend:
             mask = list(
                 verify_batch_arrays(
                     messages, keys, sigs, self._dispatched, self.rungs,
-                    stamps=stamps, dispatch=seq,
+                    stamps=stamps, dispatch=seq, moved=self._moved,
                 )
             )
             compute_s = time.perf_counter() - t0
@@ -731,6 +745,7 @@ class TpuBackend:
                 # dict(): one atomic copy — the dispatch thread may insert
                 str(k): v for k, v in sorted(dict(self._dispatched).items())
             },
+            **dict(self._moved),
             "programs_at_ready": self._programs_at_ready,
             **compile_stats(),
         }

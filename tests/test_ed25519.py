@@ -321,6 +321,8 @@ def test_warmup_builds_the_ladder_and_reports_it():
     assert report["platform"] == "cpu" and report["rungs"] == [16]
     assert report["programs_built"] == report["programs_at_ready"]
     assert report["dispatched"] == {"16": 2}  # 21 claims: chunks of 16 + 5
+    # One transfer a chunk; the claims' rows prepared, not the padded 32.
+    assert report["h2d_transfers"] == 2 and report["rows_prepared"] == 21
     # Peak device memory as the platform reports it (jax-cpu keeps no
     # allocator statistics: 0, never a missing key).
     assert report["memory_peak_bytes"] == E.memory_peak_bytes() >= 0
@@ -380,9 +382,9 @@ def test_known_answers_catch_a_verifier_that_says_yes():
         "rows 0 rejected, 3 rejected, 4 rejected"
     )
     assert "shape (4,)" in E.wrong_answers(lambda *a: np.ones(4, bool), n)
-    args, expected = E.known_answers(n)
-    assert [a.shape for a in args] == [a.shape for a in E.kernel_args(n)]
-    assert [a.dtype for a in args] == [a.dtype for a in E.kernel_args(n)]
+    packed, expected = E.known_answers(n)
+    (want,) = E.kernel_args(n)
+    assert (packed.shape, packed.dtype) == (want.shape, want.dtype)
     assert expected.tolist() == [True, False, False] + [True] * (n - 3)
 
 
@@ -428,6 +430,7 @@ def test_the_next_backend_loads_the_program_file(program_files, monkeypatch):
     assert report["programs_from_file"] == after["programs_from_file"]
     assert report["program_files_rejected"] == before["program_files_rejected"]
     assert report["dispatched"] == {"16": 1}
+    assert report["h2d_transfers"] == 1 and report["rows_prepared"] == len(msgs)
 
 
 @pytest.mark.parametrize("n, pad, chunks", [(3, 16, 1), (21, [16, 16], 2)])
@@ -449,10 +452,219 @@ def test_dispatch_thread_hands_back_its_stamps(n, pad, chunks):
     )
     t1 = time.time()
     assert mask == [True] * n
-    assert t0 <= stamps["prepare"] <= stamps["enqueued"] <= stamps["fetched"] <= t1
+    assert t0 <= stamps["prepare"] <= stamps["prepared"] <= stamps["enqueued"]
+    assert stamps["enqueued"] <= stamps["fetched"] <= t1
     assert stamps["fetched"] - stamps["prepare"] <= compute_s + 0.01
     assert stamps["pad"] == pad and stamps["chunks"] == chunks
     assert stamps["cpu_s"] >= 0
+
+
+# -- the packed form: one array a dispatch ------------------------------------
+
+NINE = ("a_y", "a_sign", "a_canon", "r_y", "r_sign", "r_canon",
+        "s_windows", "s_ok", "k_windows")
+
+
+def nine_arrays(messages, keys, sigs, pad_to):
+    """The kernel's nine arrays as they were made before one packed array
+    took their place, by the same numpy steps over every padded row: bit
+    matrices, limb and window matmuls, canonicality compares.  The packed
+    layout is held to these, column for column."""
+    n = len(messages)
+
+    def rows(chunks):
+        out = np.zeros((pad_to, 32), np.uint8)
+        if n:
+            out[:n] = np.frombuffer(b"".join(chunks), np.uint8).reshape(n, 32)
+        return out
+
+    def bits(raw):
+        return np.unpackbits(raw, axis=1, bitorder="little")
+
+    def limbs(b):
+        padded = np.concatenate(
+            [b[:, :255], np.zeros((b.shape[0], F.LIMBS * F.BITS - 255), b.dtype)],
+            axis=1,
+        )
+        weights = (1 << np.arange(F.BITS, dtype=np.int32)).astype(np.int32)
+        return padded.reshape(-1, F.LIMBS, F.BITS).astype(np.int32) @ weights
+
+    def windows(b):
+        nibble = np.array([1, 2, 4, 8], dtype=np.int32)
+        return (b.reshape(-1, 64, 4).astype(np.int32) @ nibble)[:, ::-1]
+
+    akeys = rows(bytes(k) for k in keys)
+    r_raw = rows(bytes(s)[:32] for s in sigs)
+    s_raw = rows(bytes(s)[32:] for s in sigs)
+    k_raw = rows(
+        (int.from_bytes(hashlib.sha512(bytes(s)[:32] + bytes(k) + bytes(m)).digest(),
+                        "little") % E.L_ORDER).to_bytes(32, "little")
+        for m, k, s in zip(messages, keys, sigs)
+    )
+    a_bits, r_bits, s_bits, k_bits = map(bits, (akeys, r_raw, s_raw, k_raw))
+    a_field, r_field = akeys.copy(), r_raw.copy()
+    a_field[:, 31] &= 0x7F
+    r_field[:, 31] &= 0x7F
+    p_be = np.frombuffer(F.P.to_bytes(32, "big"), np.uint8)
+    l_be = np.frombuffer(E.L_ORDER.to_bytes(32, "big"), np.uint8)
+    return (
+        limbs(a_bits), a_bits[:, 255].astype(np.int32), E._lt_be(a_field, p_be),
+        limbs(r_bits), r_bits[:, 255].astype(np.int32), E._lt_be(r_field, p_be),
+        windows(s_bits), E._lt_be(s_raw, l_be), windows(k_bits),
+    )
+
+
+def varied_claims(n):
+    """``n`` claims whose every column varies: signed and unsigned keys,
+    y at and above p, S below, at and above L, valid signatures."""
+    sk, pk = keypair()
+    odd = [
+        (F.P + 3).to_bytes(32, "little"),  # y >= p
+        b"\xff" * 32,  # y = 2^255 - 1 >= p, sign 1
+        (F.P - 1).to_bytes(32, "little"),  # the largest canonical y
+    ]
+    s_odd = [E.L_ORDER, E.L_ORDER - 1, (1 << 256) - 1]
+    msgs, keys, sigs = [], [], []
+    for i in range(n):
+        m = rng.randbytes(32)
+        key, sig = pk, sk.sign(m)
+        if i % 4 == 1:
+            key = odd[(i // 4) % 3]
+        elif i % 4 == 2:
+            sig = sig[:32] + s_odd[(i // 4) % 3].to_bytes(32, "little")
+        elif i % 4 == 3:
+            key, sig = rng.randbytes(32), rng.randbytes(64)
+        msgs.append(m), keys.append(key), sigs.append(sig)
+    return msgs, keys, sigs
+
+
+@pytest.mark.parametrize("pad", [E.CPU_RUNGS[0], E.CHIP_RUNGS[0]])
+@pytest.mark.parametrize("n", [1, 3, 13, "pad"])
+def test_packed_columns_are_the_nine_arrays_row_for_row(n, pad):
+    """The one array a dispatch hands the device holds, in its columns,
+    exactly the nine arrays of before, row for row.  The padded rows,
+    which the host no longer prepares, are all zero; before, they held
+    the flags of an all-zero encoding (y = 0 < p, S = 0 < L).  The kernel
+    works row by row and their verdicts are cut off, so the claims' mask
+    cannot tell the two apart."""
+    n = pad if n == "pad" else n
+    msgs, keys, sigs = varied_claims(n)
+    packed = E.prepare_batch(msgs, keys, sigs, pad)
+    assert packed.shape == (pad, E.PACKED_WIDTH) == (pad, 197)
+    assert packed.dtype == np.uint8
+    old = nine_arrays(msgs, keys, sigs, pad)
+    for name, want in zip(NINE, old):
+        got = packed[:n, E.COLUMNS[name]]
+        assert got.shape == want[:n].shape, name
+        np.testing.assert_array_equal(
+            got.astype(np.int32), want[:n].astype(np.int32), name
+        )
+        flag = name in ("a_canon", "r_canon", "s_ok")
+        assert (want[n:] == flag).all(), name
+    assert not packed[n:].any()
+    assert [name for name, _ in E.PACKED_LAYOUT] == list(NINE)
+
+
+def rejected_row(case):
+    """One claim of ``case`` beside the key and message it would verify
+    under if it were valid."""
+    sk, pk = keypair()
+    m = rng.randbytes(32)
+    sig = sk.sign(m)
+    if case == "forged":
+        return m, pk, sk.sign(rng.randbytes(32))
+    if case == "s_plus_l":
+        s_int = int.from_bytes(sig[32:], "little") + E.L_ORDER
+        return m, pk, sig[:32] + s_int.to_bytes(32, "little")
+    if case == "non_canonical_y":
+        return m, (F.P + 3).to_bytes(32, "little"), sig
+    if case == "small_order_key":
+        rx, ry = E._ref_scalarmult(777)
+        r_bytes = (ry | ((rx & 1) << 255)).to_bytes(32, "little")
+        return m, (1).to_bytes(32, "little"), r_bytes + (777).to_bytes(32, "little")
+    assert case == "wrong_key"
+    return m, keypair()[1], sig
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["forged", "s_plus_l", "non_canonical_y", "small_order_key", "wrong_key"],
+)
+def test_packed_dispatch_rejects_what_the_nine_arrays_rejected(case):
+    """Through the one packed transfer the mask is unchanged: the bad
+    claim is rejected and the valid ones around it are accepted."""
+    sk, pk = keypair()
+    msgs = [rng.randbytes(32) for _ in range(3)]
+    claims = [(m, pk, sk.sign(m)) for m in msgs]
+    claims.insert(1, rejected_row(case))
+    mask = E.verify_batch_arrays(*map(list, zip(*claims)))
+    assert mask.tolist() == [True, False, True, True]
+
+
+@pytest.mark.parametrize("n, pads", [(3, [16]), (21, [16, 16])])
+def test_a_chunk_is_one_host_to_device_transfer(monkeypatch, n, pads):
+    """Each chunk reaches its program as ONE array already on the
+    device, a split above the top rung included, and the backend's
+    report counts one transfer a chunk and the claims' rows."""
+    from narwhal_tpu.ops.ed25519 import TpuBackend
+
+    calls = []
+    resolve = E.verify_program
+
+    def recording(rung):
+        program = resolve(rung)
+
+        def call(*args):
+            calls.append((rung, args))
+            return program(*args)
+
+        return call
+
+    monkeypatch.setattr(E, "verify_program", recording)
+    backend = TpuBackend("jax")
+    sk, pk = keypair()
+    msgs = [rng.randbytes(32) for _ in range(n)]
+    assert all(backend.verify_batch_mask(msgs, [pk] * n, [sk.sign(m) for m in msgs]))
+    assert [rung for rung, _ in calls] == pads
+    for rung, args in calls:
+        (arg,) = args
+        assert isinstance(arg, jax.Array)
+        assert (arg.shape, arg.dtype) == ((rung, E.PACKED_WIDTH), np.uint8)
+    report = backend.device_report()
+    assert report["h2d_transfers"] == len(pads) == sum(report["dispatched"].values())
+    assert report["rows_prepared"] == n
+
+
+def test_prepared_stamp_lies_between_prepare_and_enqueued_on_the_burst():
+    """A burst through the seam on the batched verifier: its entry of the
+    verify-stage trace carries ``prepared`` after ``prepare`` and before
+    ``enqueued``, so the host's leg splits into the arithmetic and the
+    transfer with the launch."""
+    import asyncio
+
+    from narwhal_tpu import metrics
+    from narwhal_tpu.crypto import backend as cb
+    from narwhal_tpu.utils import devtrace
+
+    sk, pk = keypair()
+    msgs = [rng.randbytes(32) for _ in range(3)]
+
+    async def burst():
+        with devtrace.burst("4101"):
+            return await cb.averify_batch_mask(
+                msgs, [pk] * 3, [sk.sign(m) for m in msgs]
+            )
+
+    cb.set_backend("jax")
+    try:
+        assert asyncio.run(burst()) == [True] * 3
+    finally:
+        cb.set_backend("cpu")
+    entry = metrics.verify_trace().entries["4101"]
+    stages = [s for s in metrics.VERIFY_STAGES if s in entry]
+    assert stages == ["submitted", "prepare", "prepared", "enqueued", "fetched", "resumed"]
+    stamps = [entry[s] for s in stages]
+    assert stamps == sorted(stamps), entry
 
 
 @pytest.mark.parametrize("placed_from_outside", [True, False])

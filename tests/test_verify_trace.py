@@ -3,7 +3,7 @@ INSIDE the verify path of a primary (``metrics.VERIFY_STAGES``, one
 entry per burst, stamped where the work happens on the wall clock the
 device trace is bounded with) and a cause beside each event-loop stall.
 
-Pinned here: the seven stamps of a dispatch are monotone and telescope
+Pinned here: the eight stamps of a dispatch are monotone and telescope
 to the wall time of the burst on the pipelined and the inline path, a
 backend without a dispatch thread marks the loop stages alone, the
 table rides the final snapshot flush and an explicit scrape but never a
@@ -56,6 +56,7 @@ class _ThreadedBackend:
             stamps = {"prepare": time.time()}
             cpu0 = time.thread_time()
             mask = cb.CpuBackend().verify_batch_mask(messages, keys_, sigs)
+            stamps["prepared"] = time.time()
             stamps["enqueued"] = time.time()
             time.sleep(HOLD_S)
             stamps["fetched"] = time.time()
