@@ -48,12 +48,41 @@ shorter hold cannot cost a round; ``0`` = off):
                       ``{"seq", "stage"}`` with the last stage it had
                       reached (metrics.VERIFY_STAGES), or None
 
+  ``host``            what the kernel counted across it
+                      (``utils/hoststat.py``): each host key's raw delta
+                      from the newest sample at or before the stall's last
+                      beat to the first sample after the late beat, a
+                      window ``host_window_s`` long (``{}`` and None where
+                      no sample brackets the stall)
+  ``cause``           ``program`` where ``cpu_s``, ``gc_s`` or
+                      ``snapshot_write_s`` covers half the stall; else the
+                      host key of :data:`HOST_CAUSES` that moved most, if
+                      it covers half (``cpu_throttled``, which the kernel
+                      sums over CPUs, divided by the CPUs the process may
+                      run on, and only where ``cpu_nr_throttled`` moved
+                      too); else ``unexplained``.  Each stall is observed
+                      into the one of ``runtime.loop_stall_{program,host,
+                      unexplained}_seconds`` its cause names: the three sum
+                      to ``runtime.loop_stall_seconds``.
+
   A stall of the dispatch thread or of the device with the loop alive
   shows instead in ``metrics.verify_trace()`` as one burst with a second
   between two stages.
 
+The thread samples the host counters at most ten times a second, never
+on the loop: every fourth poll at the default beat while the loop keeps
+time, none while it is held, and one at the first poll after a late beat
+(once four polls have passed since the last).  The late beat observes
+the stall into ``runtime.loop_stalls`` and ``runtime.loop_stall_seconds``
+at once; its record waits for that sample, then the loop observes the
+cause's histogram and publishes the flight event and the log entry.
+While the thread watches a stall it holds a ``runtime.loop_stall`` span
+open (``devtrace.annotate``), on the device trace's clock in a
+device-backed node.
+
 Cost: one trivial task wakeup per interval on the loop (40 a second at
-the default), one daemon thread, and two clock reads per collection.
+the default), one daemon thread, two clock reads per collection, and
+the host files read ten times a second.
 """
 
 from __future__ import annotations
@@ -62,6 +91,8 @@ import asyncio
 import collections
 import gc
 import logging
+import math
+import os
 import sys
 import threading
 import time
@@ -69,7 +100,9 @@ import traceback
 from typing import Optional
 
 from .. import metrics
+from ..utils import devtrace
 from ..utils.env import env_int
+from ..utils.hoststat import HostStat
 from ..utils.tasks import spawn
 
 log = logging.getLogger("narwhal.watchdog")
@@ -79,12 +112,40 @@ _STACK_LIMIT = 12  # frames kept in the excerpt
 # but its 512 events turn over in under a minute of round advances, and a
 # run has to keep every stall of its window until its final snapshot.
 _STALL_LOG = 32
+_SAMPLE_HZ = 10  # the most the thread samples the host counters
+_SAMPLES = 8  # kept: a stall is resolved within two polls of its end
+# Host keys that can name a stall, in the order that breaks a tie.
+HOST_CAUSES = (
+    "cpu_throttled", "cpu_steal", "memory_full", "io_full", "memory_some",
+    "cpu_some",
+)
+
+
+def stall_cause(stall: dict, host: dict, cpus: int = 1) -> str:
+    """``program``, the host key that covers half the stall, or
+    ``unexplained`` (the rule in the module's docstring); ``cpus``: the
+    CPUs the process may run on, over which the kernel sums throttled
+    time."""
+    half = stall["stall_s"] / 2
+    if max(stall["cpu_s"], stall["gc_s"], stall["snapshot_write_s"]) >= half:
+        return "program"
+    moved = {k: host.get(k, 0.0) for k in HOST_CAUSES}
+    moved["cpu_throttled"] /= cpus
+    if host.get("cpu_nr_throttled", 0) <= 0:
+        del moved["cpu_throttled"]
+    key = max(moved, key=moved.get)
+    return key if moved[key] >= half else "unexplained"
 
 
 class LoopWatchdog:
     """Watch one event loop for callbacks that hold it past ``threshold_s``."""
 
-    def __init__(self, threshold_s: float, interval_s: Optional[float] = None):
+    def __init__(
+        self,
+        threshold_s: float,
+        interval_s: Optional[float] = None,
+        host: Optional[HostStat] = None,
+    ):
         self.threshold_s = threshold_s
         # Beat fast enough that the measured overshoot approximates the
         # true stall length, slow enough to stay off the hot path.
@@ -108,7 +169,25 @@ class LoopWatchdog:
         self._m_stall_s = metrics.histogram("runtime.loop_stall_seconds")
         self._m_gc_s = metrics.histogram("runtime.gc_pause_seconds")
         self._m_write_s = metrics.histogram("runtime.snapshot_write_seconds")
+        self._m_cause_s = {
+            "program": metrics.histogram("runtime.loop_stall_program_seconds"),
+            "host": metrics.histogram("runtime.loop_stall_host_seconds"),
+            "unexplained": metrics.histogram(
+                "runtime.loop_stall_unexplained_seconds"
+            ),
+        }
         self._verify_trace = metrics.verify_trace()
+        self._loop: Optional[asyncio.AbstractEventLoop] = None
+        # Host counters: (monotonic, sample), appended by the thread alone;
+        # stalls wait in ``_pending`` as (beat, end, overshoot, record)
+        # until a sample after their late beat is in.
+        self._host = host if host is not None else HostStat()
+        self._cpus = len(os.sched_getaffinity(0))
+        self._sample_every = math.ceil(
+            round(1 / (_SAMPLE_HZ * self.interval_s), 6)
+        )
+        self._samples: collections.deque = collections.deque(maxlen=_SAMPLES)
+        self._pending: collections.deque = collections.deque()
         # Collector accounting (the gc hook; any thread may collect, and
         # a collection is never re-entered).
         self._gc_t0 = 0.0
@@ -123,6 +202,7 @@ class LoopWatchdog:
         # mode is on) with the watchdog threshold.
         loop.slow_callback_duration = self.threshold_s
         self._loop_thread_id = threading.get_ident()
+        self._loop = loop
         self._last_beat = time.monotonic()
         gc.callbacks.append(self._on_gc)
         self._task = spawn(self._beat(), name="loop-watchdog-beat")
@@ -145,6 +225,11 @@ class LoopWatchdog:
             await asyncio.gather(self._task, return_exceptions=True)
         if self._thread is not None:
             self._thread.join(timeout=2 * self.interval_s + 1)
+        # What the thread handed over lands first; a stall it never saw
+        # (it had died) is published without a host window.
+        await asyncio.sleep(0)
+        if self._thread is None or not self._thread.is_alive():
+            self._resolve(self._record, final=True)
 
     # -- loop side: measure ---------------------------------------------------
 
@@ -181,7 +266,8 @@ class LoopWatchdog:
             # The sleep was scheduled for interval_s; anything beyond it
             # is time some callback (or a CPU-bound stretch of one) held
             # the loop.
-            overshoot = time.monotonic() - self._last_beat - self.interval_s
+            end = time.monotonic()
+            overshoot = end - beat - self.interval_s
             if overshoot >= self.threshold_s:
                 self._m_stalls.inc()
                 self._m_stall_s.observe(overshoot)
@@ -204,27 +290,90 @@ class LoopWatchdog:
                     "dispatch": during.get(
                         "dispatch", self._dispatch_in_flight()
                     ),
+                    "stack": during.get("stack", ""),
                 }
-                # Stalls are flight-recorder landmarks: the ring shows
-                # what the committee was doing around the freeze.
-                stall["stack"] = during.get("stack", "")
-                metrics.flight_event("loop_stall", **stall)
-                self._stalls.append(stall)
-                self._last_stall.clear()
-                self._last_stall.update(stall)
+                self._pending.append((beat, end, overshoot, stall))
+
+    def _record(self, overshoot: float, stall: dict) -> None:
+        """Publish one stall, its host window and cause in hand (on the
+        loop)."""
+        cause = stall["cause"]
+        self._m_cause_s[
+            cause if cause in ("program", "unexplained") else "host"
+        ].observe(overshoot)
+        # Stalls are flight-recorder landmarks: the ring shows what the
+        # committee was doing around the freeze.
+        metrics.flight_event("loop_stall", **stall)
+        self._stalls.append(stall)
+        self._last_stall.clear()
+        self._last_stall.update(stall)
 
     # -- thread side: name the culprit ----------------------------------------
 
+    def _sample(self) -> None:
+        self._samples.append((time.monotonic(), self._host.sample()))
+
+    def _hand_over(self, overshoot: float, stall: dict) -> None:
+        try:
+            self._loop.call_soon_threadsafe(self._record, overshoot, stall)
+        except RuntimeError:  # the loop is closed: nobody reads it now
+            pass
+
+    def _resolve(self, publish, final: bool = False) -> None:
+        """Give each stall that ended before the newest sample its
+        ``host`` deltas and ``cause`` and ``publish`` it, in order;
+        ``final``: every stall still waiting, without a window where no
+        sample follows it."""
+        samples = list(self._samples)
+        while self._pending:
+            beat, end, overshoot, stall = self._pending[0]
+            after = samples[-1] if samples and samples[-1][0] > end else None
+            if after is None and not final:
+                return
+            self._pending.popleft()
+            before = next(
+                (s for s in reversed(samples) if s[0] <= beat), None
+            )
+            host, window = {}, None
+            if before is not None and after is not None:
+                host = {
+                    k: round(after[1][k] - v, 6)
+                    for k, v in before[1].items() if k in after[1]
+                }
+                window = round(after[0] - before[0], 4)
+            stall["host"] = host
+            stall["host_window_s"] = window
+            stall["cause"] = stall_cause(stall, host, self._cpus)
+            publish(overshoot, stall)
+
     def _watch(self) -> None:
+        since, span, span_beat = self._sample_every, None, None
         while not self._stop.wait(self.interval_s):
+            since += 1
             beat = self._last_beat
             gap = time.monotonic() - beat
+            # No sample while the loop is held: none could bracket a stall.
+            if since >= self._sample_every and (
+                self._pending or gap < 2 * self.interval_s
+            ):
+                self._sample()
+                since = 0
+                self._resolve(self._hand_over)
+            if span is not None and beat != span_beat:
+                span.__exit__(None, None, None)
+                span = None
             if gap - self.interval_s < self.threshold_s or self._stack_captured:
                 continue
             # The loop is stalled RIGHT NOW: its thread's stack names the
             # blocking callee. One capture per stall (flag reset by the
-            # next beat), so a long wedge logs once, not per tick.
+            # next beat), so a long wedge logs once, not per tick.  The
+            # span lies on the device trace's clock until the beat resumes.
             self._stack_captured = True
+            span_beat = beat
+            span = devtrace.annotate(
+                "runtime.loop_stall", beat=time.time() - gap
+            )
+            span.__enter__()
             frame = sys._current_frames().get(self._loop_thread_id)
             if frame is None:
                 continue
@@ -240,6 +389,12 @@ class LoopWatchdog:
                 "Event loop stalled > %.0f ms; loop thread stack:\n%s",
                 self.threshold_s * 1000, excerpt,
             )
+        if span is not None:
+            span.__exit__(None, None, None)
+        # A stall that ended just now still gets the sample after it.
+        if self._pending:
+            self._sample()
+            self._resolve(self._hand_over)
 
 
 def install_from_env() -> Optional[LoopWatchdog]:

@@ -424,6 +424,10 @@ def main(argv=None) -> int:
             if health_task is not None:
                 health_task.cancel()
                 await asyncio.gather(health_task, return_exceptions=True)
+            if loop_watchdog is not None:
+                # Before the final flush: the stalls it still holds are
+                # published into the snapshot on disk.
+                await loop_watchdog.shutdown()
             if snapshot_task is not None:
                 # Cancellation triggers the writer's final flush, so the
                 # snapshot on disk covers the whole run.
@@ -432,8 +436,6 @@ def main(argv=None) -> int:
             if flight_task is not None:
                 flight_task.cancel()
                 await asyncio.gather(flight_task, return_exceptions=True)
-            if loop_watchdog is not None:
-                await loop_watchdog.shutdown()
             if profiler_thread is not None:
                 profiler_thread.shutdown()
 

@@ -187,8 +187,9 @@ _VARS = [
         "timer). Stalls land in the `runtime.loop_stall_seconds` "
         "histogram, and each leaves a `loop_stall` flight event and "
         "`runtime.loop_stall_last` with the loop thread's stack taken "
-        "during it and its cause (CPU time, collector time, a snapshot "
-        "write, the verify burst in flight); `0` = off.",
+        "during it and what it saw (CPU time, collector time, a snapshot "
+        "write, the verify burst in flight, the host's kernel counters' "
+        "deltas) and its `cause`; `0` = off, the host sampler with it.",
     ),
     EnvVar(
         "NARWHAL_PROFILE_HZ", "float", 0.0,

@@ -9,21 +9,27 @@ backend without a dispatch thread marks the loop stages alone, the
 table rides the final snapshot flush and an explicit scrape but never a
 periodic rewrite, a held loop leaves ONE stall that tells collector,
 snapshot writer, CPU and in-flight burst apart, every collection lands
-in ``runtime.gc_pause_seconds``, and the node's own profiler hook keeps
-the ``verify.*`` annotations on the profiler's clock."""
+in ``runtime.gc_pause_seconds``, each stall carries the host counters'
+deltas across it and ONE cause (program, a host key, unexplained) whose
+histograms sum to ``runtime.loop_stall_seconds``, the watcher thread
+alone samples the host at most ten times a second and never while the
+loop is held, holds a ``runtime.loop_stall`` span over a stall, and the
+node's own profiler
+hook keeps the ``verify.*`` annotations on the profiler's clock."""
 
 import asyncio
 import gc
 import glob
 import json
 import os
+import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 
 import pytest
 
 from narwhal_tpu import metrics
-from narwhal_tpu.analysis.watchdog import LoopWatchdog
+from narwhal_tpu.analysis.watchdog import LoopWatchdog, stall_cause
 from narwhal_tpu.crypto import backend as cb
 from narwhal_tpu.utils import devtrace
 from tests.common import committee, keys, make_certificate, make_header
@@ -356,6 +362,284 @@ def test_forced_collection_lands_in_gc_pause_seconds():
     observed, seconds, still_hooked = run(go())
     assert observed >= 1 and seconds > 0
     assert not still_hooked, "shutdown must take the collector hook out"
+
+
+# -- the host side of a stall -------------------------------------------------
+
+
+class _Host:
+    """Stands in for ``HostStat``: counters that advance at ``rates`` a
+    second of wall time while ``on``, and which threads sampled them."""
+
+    def __init__(self, **rates):
+        self.keys = tuple(sorted(rates))
+        self.rates = rates
+        self.on = True
+        self.values = dict.fromkeys(rates, 0.0)
+        self.t = time.monotonic()
+        self.threads = set()
+        self.times = []
+
+    def sample(self):
+        now = time.monotonic()
+        if self.on:
+            for k, r in self.rates.items():
+                self.values[k] += r * (now - self.t)
+        self.t = now
+        self.threads.add(threading.get_ident())
+        self.times.append(now)
+        return dict(self.values)
+
+
+_PARTITION = ("program", "host", "unexplained")
+
+
+def _stall_hists():
+    reg = metrics.registry()
+    names = ["runtime.loop_stall_seconds"] + [
+        f"runtime.loop_stall_{p}_seconds" for p in _PARTITION
+    ]
+    return {
+        n: (reg.histograms[n].count, reg.histograms[n].sum)
+        if n in reg.histograms else (0, 0.0)
+        for n in names
+    }
+
+
+def reg_log_tail():
+    return metrics.registry().snapshot(include_trace=False)["detail"][
+        "runtime.loop_stall_log"
+    ][-1]
+
+
+def _hold_asleep():
+    time.sleep(0.15)
+
+
+def _hold_on_cpu():
+    t0 = time.monotonic()
+    while time.monotonic() - t0 < 0.15:
+        pass
+
+
+# The quota holds the whole cgroup: the kernel counts throttled time on
+# every CPU the process may run on.
+_CPUS = len(os.sched_getaffinity(0))
+
+
+@pytest.mark.parametrize("hold, rates, cause, part", [
+    # the quota holds the cgroup: throttled time and periods both move
+    (_hold_asleep, {"cpu_throttled": float(_CPUS), "cpu_nr_throttled": 10.0,
+                    "memory_some": 0.2}, "cpu_throttled", "host"),
+    (_hold_on_cpu, {"cpu_throttled": 0.0, "cpu_nr_throttled": 0.0},
+     "program", "program"),
+    (_hold_asleep, {"cpu_throttled": 0.0, "cpu_nr_throttled": 0.0,
+                    "memory_full": 0.0}, "unexplained", "unexplained"),
+], ids=["throttled", "cpu_bound", "nothing_moved"])
+def test_a_stall_gets_the_host_window_and_one_cause(hold, rates, cause, part):
+    host = _Host(**rates)
+    before = _stall_hists()
+
+    async def go():
+        t0 = time.monotonic()
+        dog = LoopWatchdog(threshold_s=0.1, interval_s=0.025, host=host)
+        dog.start()
+        try:
+            await asyncio.sleep(0.15)  # a sample before the stall
+            hold()
+            await asyncio.sleep(0.25)  # the late beat, a sample after it
+        finally:
+            await dog.shutdown()
+        watched = time.monotonic() - t0
+        return dict(dog._last_stall), threading.get_ident(), watched
+
+    last, loop_thread, watched = run(go())
+    after = _stall_hists()
+    assert last["stall_s"] >= 0.1 and last["cause"] == cause
+    assert set(last["host"]) == set(rates)
+    assert 0.15 <= last["host_window_s"] <= 0.6
+    if cause == "cpu_throttled":
+        # Kept raw: the window's length on every CPU.
+        assert last["host"]["cpu_throttled"] == pytest.approx(
+            _CPUS * last["host_window_s"], abs=0.02 * _CPUS
+        )
+    # Exactly one stall, observed into the one histogram its cause names;
+    # the three sum to runtime.loop_stall_seconds.
+    moved = {n: (after[n][0] - before[n][0], after[n][1] - before[n][1])
+             for n in after}
+    assert moved["runtime.loop_stall_seconds"][0] == 1
+    for p in _PARTITION:
+        assert moved[f"runtime.loop_stall_{p}_seconds"][0] == (p == part)
+    assert sum(
+        moved[f"runtime.loop_stall_{p}_seconds"][1] for p in _PARTITION
+    ) == pytest.approx(moved["runtime.loop_stall_seconds"][1])
+    assert reg_log_tail()["cause"] == cause
+    # Sampled from the watcher thread alone, at most ten times a second.
+    assert loop_thread not in host.threads and len(host.threads) == 1
+    assert 3 <= len(host.times) <= 10 * watched + 1
+    assert min(b - a for a, b in zip(host.times, host.times[1:])) >= 0.095
+
+
+@pytest.mark.parametrize("stall, host, cause", [
+    ({"cpu_s": 0.08}, {"cpu_throttled": 1.0, "cpu_nr_throttled": 3},
+     "program"),
+    ({}, {"cpu_throttled": 0.06, "cpu_nr_throttled": 1}, "cpu_throttled"),
+    ({"gc_s": 0.06}, {}, "program"),
+    ({"snapshot_write_s": 0.1}, {}, "program"),
+    ({}, {"cpu_throttled": 1.0, "cpu_nr_throttled": 0}, "unexplained"),
+    ({}, {"cpu_throttled": 1.0, "cpu_nr_throttled": 0, "memory_some": 0.07},
+     "memory_some"),
+    ({}, {"cpu_steal": 0.09, "memory_full": 0.08, "io_full": 0.2},
+     "io_full"),
+    ({}, {"cpu_steal": 0.06, "cpu_some": 0.06}, "cpu_steal"),
+    ({}, {"memory_full": 0.049, "major_faults": 1000}, "unexplained"),
+])
+def test_cause_rule(stall, host, cause):
+    """Over a 0.1 s stall: the program first where it covers half,
+    then the host key that moved most (a tie goes to the first in
+    HOST_CAUSES; throttled time counts only with throttled periods)."""
+    record = {"stall_s": 0.1, "cpu_s": 0.0, "gc_s": 0.0,
+              "snapshot_write_s": 0.0, **stall}
+    assert stall_cause(record, host) == cause
+
+
+@pytest.mark.parametrize("host, cause", [
+    # 13 CPUs held 10 ms each: 130 ms summed, 10 ms of wall
+    ({"cpu_throttled": 0.13, "cpu_nr_throttled": 1}, "unexplained"),
+    ({"cpu_throttled": 0.13, "cpu_nr_throttled": 1, "memory_full": 0.11},
+     "memory_full"),
+    # held for the whole stall on every CPU
+    ({"cpu_throttled": 13 * 0.2, "cpu_nr_throttled": 2, "memory_full": 0.11},
+     "cpu_throttled"),
+])
+def test_cause_rule_divides_throttled_time_over_the_cpus(host, cause):
+    """The kernel sums throttled time over CPUs: a brief throttle of many
+    CPUs inside a longer stall does not name it."""
+    record = {"stall_s": 0.2, "cpu_s": 0.0, "gc_s": 0.0,
+              "snapshot_write_s": 0.0}
+    assert stall_cause(record, host, cpus=13) == cause
+
+
+def test_a_stall_without_a_sample_after_it_is_published_at_shutdown():
+    """The thread is gone before the stall: no window, and the stall is
+    still counted once, at shutdown, in the partition."""
+    host = _Host(cpu_throttled=1.0, cpu_nr_throttled=10.0)
+    before = _stall_hists()
+
+    async def go():
+        dog = LoopWatchdog(threshold_s=0.05, interval_s=0.0125, host=host)
+        dog.start()
+        try:
+            await asyncio.sleep(0.05)
+            dog._stop.set()
+            dog._thread.join(2)
+            time.sleep(0.1)
+            await asyncio.sleep(0.05)
+        finally:
+            await dog.shutdown()
+        return dict(dog._last_stall)
+
+    last = run(go())
+    after = _stall_hists()
+    assert last["host"] == {} and last["host_window_s"] is None
+    assert last["cause"] == "unexplained"
+    assert (after["runtime.loop_stall_unexplained_seconds"][0]
+            - before["runtime.loop_stall_unexplained_seconds"][0]) == 1
+
+
+def test_a_stall_just_before_shutdown_is_published_with_its_window():
+    """Shutdown right after a late beat: the thread's last sample closes
+    the stall's window, and the record and the partition are in place
+    when shutdown returns (the node's final snapshot is written after)."""
+    host = _Host(memory_full=1.0)
+    before = _stall_hists()
+
+    async def go():
+        dog = LoopWatchdog(threshold_s=0.1, interval_s=0.025, host=host)
+        dog.start()
+        await asyncio.sleep(0.15)
+        count = dog._m_stall_s.count
+        _hold_asleep()
+        while dog._m_stall_s.count == count:  # the late beat
+            await asyncio.sleep(0)
+        await dog.shutdown()
+        return dict(dog._last_stall)
+
+    last = run(go())
+    after = _stall_hists()
+    assert last["cause"] == "memory_full"
+    assert last["host_window_s"] is not None
+    assert last["host"]["memory_full"] == pytest.approx(
+        last["host_window_s"], abs=0.02
+    )
+    moved = {n: after[n][0] - before[n][0] for n in after}
+    assert moved["runtime.loop_stall_seconds"] == 1
+    assert moved["runtime.loop_stall_host_seconds"] == 1
+
+
+def test_no_host_sample_while_the_loop_is_held():
+    """Samples stop while the loop is held (none could bracket a stall)
+    and the first after the late beat resolves it."""
+    host = _Host(io_full=1.0)
+
+    async def go():
+        dog = LoopWatchdog(threshold_s=0.1, interval_s=0.025, host=host)
+        dog.start()
+        try:
+            await asyncio.sleep(0.15)
+            held = time.monotonic()
+            time.sleep(0.4)
+            resumed = time.monotonic()
+            await asyncio.sleep(0.2)
+        finally:
+            await dog.shutdown()
+        return held, resumed, dict(dog._last_stall)
+
+    held, resumed, last = run(go())
+    assert not [t for t in host.times if held + 0.075 < t < resumed]
+    assert last["cause"] == "io_full"
+    # The window runs from a sample before the hold to the first after it.
+    assert 0.4 <= last["host_window_s"] <= 0.4 + 0.075 + 0.2
+
+
+def test_the_watcher_holds_a_stall_span_on_the_device_clock(monkeypatch):
+    """``runtime.loop_stall`` opens when the thread catches the stall,
+    stamped with the last beat's wall time, and closes at its first poll
+    after the beat resumes, on the thread that opened it."""
+    spans = []
+
+    class Span:
+        def __init__(self, name, **fields):
+            self.name, self.fields = name, fields
+
+        def __enter__(self):
+            self.opened = (time.time(), threading.get_ident())
+            spans.append(self)
+
+        def __exit__(self, *exc):
+            self.closed = (time.time(), threading.get_ident())
+
+    monkeypatch.setattr(devtrace, "annotate", Span)
+
+    async def go():
+        dog = LoopWatchdog(threshold_s=0.05, interval_s=0.0125,
+                           host=_Host()).start()
+        try:
+            await asyncio.sleep(0.05)
+            held = time.time()
+            time.sleep(0.15)
+            resumed = time.time()
+            await asyncio.sleep(0.1)
+        finally:
+            await dog.shutdown()
+        return held, resumed
+
+    held, resumed = run(go())
+    assert [s.name for s in spans] == ["runtime.loop_stall"]
+    span = spans[0]
+    assert held - 0.0125 - 0.01 <= span.fields["beat"] <= held + 0.001
+    assert held < span.opened[0] < resumed
+    assert span.closed[0] >= resumed and span.closed[1] == span.opened[1]
 
 
 # -- the node's own profiler hook ---------------------------------------------
